@@ -69,9 +69,8 @@ def generate_slice(path: str) -> str:
 
 
 def run_tier(tier: str, data_path: str) -> dict:
-    """One tier through the example CLI in its own subprocess (a d2h in one
-    tier must not degrade the next tier's dispatch latency on a
-    remote-attached chip)."""
+    """One tier through the example CLI in its own subprocess (one process
+    per chip: this parent imports JAX only after its children are done)."""
     cmd = [
         sys.executable, os.path.join(REPO, "examples", "criteo_dlrm", "train.py"),
         "--tier", tier, "--data-path", data_path,

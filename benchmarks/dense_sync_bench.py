@@ -1,4 +1,4 @@
-"""Dense-plane sync quality/memory bench → BENCH_r08.json.
+"""Dense-plane sync quality/memory bench on virtual CPU devices.
 
 Prices the ISSUE-13 dense plane end to end on whatever host runs it:
 
@@ -18,8 +18,9 @@ Prices the ISSUE-13 dense plane end to end on whatever host runs it:
 - wire: the ``dense_sync_wire_bytes`` rows (single source of truth shared
   with bench.py records, WIRE_BENCH.json and the telemetry counter).
 
-Usage: ``python benchmarks/dense_sync_bench.py [--write]`` (--write
-publishes BENCH_r08.json at the repo root; default prints JSON to stdout).
+Usage: ``python benchmarks/dense_sync_bench.py`` (prints JSON to stdout).
+Everything here is a count or a quality check from a CPU host; it says
+nothing about step time, and no collective here crosses a real wire.
 The id slots feed the dense tower through a FIXED seeded hash-projection
 table per slot (numpy host-side, not learnable) — identical for every
 mode, so mode-vs-mode AUC deltas isolate the sync arithmetic; absolute
@@ -340,37 +341,17 @@ def main():
 
     import jax
 
-    from bench import _link_class, bench_link
-
-    link = bench_link()
     out = {
-        "round": 8,
         "note": (
-            "No TPU was attached to the round-8 build host (CPU, JAX cpu "
-            "backend) — per the r06 precedent this artifact records the "
-            "post-change bench run on that host with link evidence; "
-            "CPU-host numbers are NOT chip numbers. This round lands the "
-            "byte-optimal dense plane: block-scaled int8 ring allreduce "
-            "(per-block scales + on-device error feedback inside each ring "
-            "hop) and the ZeRO-style cross-replica sharded optimizer "
-            "update. What a CPU host CAN prove is recorded here: the "
-            "quality gate (held-out AUC per sync mode on the shared "
-            "CriteoSynthetic stream, spread vs f32 < 0.02), the measured "
-            "per-replica optimizer-state bytes (~1/n sharded, real "
-            "addressable-shard sizes), dp-invariance of the sharded update "
-            "at n=8/32/64 virtual devices, and the wire model "
-            "(3.94x fewer dense-sync bytes/step for the int8 ring vs f32, "
-            "the same dense_sync_wire_bytes pricing WIRE_BENCH.json and "
-            "the persia_tpu_dense_wire_bytes counter use). What it CANNOT "
-            "prove is the wall-clock win — on one CPU host all 'replicas' "
-            "share the same memory bus, so no bytes cross a real wire; "
-            "pricing the step-time claim needs a chip window: loop "
-            "`python benchmarks/dense_sync_bench.py` until "
-            "link_class=good on a TPU-attached host."
+            "Virtual CPU devices: quality gate (held-out AUC per sync mode "
+            "on the shared CriteoSynthetic stream, spread vs f32 < 0.02), "
+            "per-replica optimizer-state bytes from real addressable-shard "
+            "sizes, dp-invariance of the sharded update at n=8/32/64, and "
+            "the dense_sync_wire_bytes model. No step time: all 'replicas' "
+            "share one memory bus, so no bytes cross a real wire; the "
+            "collectives' time on four chips is not measured."
         ),
         "platform": jax.default_backend(),
-        "link_class": _link_class(link),
-        "link": link,
         "quality": bench_quality(),
         "opt_state_memory": bench_opt_memory(),
         "dp_invariance": bench_dp_invariance(),
@@ -384,12 +365,7 @@ def main():
             "jax": jax.__version__,
         },
     }
-    text = json.dumps(out, indent=1)
-    if "--write" in sys.argv:
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        with open(os.path.join(root, "BENCH_r08.json"), "w") as f:
-            f.write(text + "\n")
-    print(text)
+    print(json.dumps(out, indent=1))
 
 
 if __name__ == "__main__":

@@ -19,6 +19,7 @@ import jax
 import numpy as np
 import optax
 
+from persia_tpu.compile_cache import enable_compile_cache
 from persia_tpu.config import EmbeddingConfig, HyperParameters, JobType
 from persia_tpu.data import PersiaBatch
 from persia_tpu.embedding.optim import SGD as SparseSGD
@@ -408,6 +409,7 @@ class TrainCtx(EmbeddingCtx):
         return state, metrics, emb_grads
 
     def __enter__(self):
+        enable_compile_cache()
         # register the sparse optimizer on every PS replica
         # (ref: embedding_optimizer.apply(), persia/ctx.py:854-858)
         self.worker.register_optimizer(self.embedding_optimizer.config)
@@ -621,11 +623,10 @@ class TrainCtx(EmbeddingCtx):
         backward.rs).
 
         ``fetch_metrics=False`` (static loss scale only — the dynamic scale
-        must be read every step) skips the per-step header fetch: on a
-        remote-attached chip that device→host read costs tens of ms and
-        permanently degrades dispatch latency, so metric-light loops fetch
-        once at the end via :meth:`last_prepared_metrics`. Returns ``None``
-        in that mode."""
+        must be read every step) skips the per-step header fetch: that
+        device→host read makes the host wait for the step it just
+        dispatched, so metric-light loops fetch once at the end via
+        :meth:`last_prepared_metrics`. Returns ``None`` in that mode."""
         device_batch = training_batch.device_batch
         if self.state is None:
             self.init_state(jax.random.PRNGKey(0), device_batch)
@@ -697,6 +698,7 @@ class InferCtx(EmbeddingCtx):
 
     def __init__(self, model, state: TrainState, worker, embedding_config, mesh=None):
         super().__init__(worker, embedding_config, mesh=mesh)
+        enable_compile_cache()
         self.model = model
         self.state = state
         self._eval_step = build_eval_step(model)

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from persia_tpu.compile_cache import enable_compile_cache
 from persia_tpu.config import EmbeddingConfig
 from persia_tpu.data import PersiaBatch
 from persia_tpu.embedding.optim import OPTIMIZER_ADAM, OptimizerConfig
@@ -240,6 +241,7 @@ class CachedTrainCtx:
         self.last_resume_info: Optional[Dict] = None
 
     def __enter__(self):
+        enable_compile_cache()
         self.worker.register_optimizer(self.sparse_cfg)
         return self
 
@@ -360,9 +362,10 @@ class CachedTrainCtx:
         out new arrays; see its docstring for the reuse-race history), so
         the asynchronous ``device_put``s need no completion barrier — the
         buffers stay alive via the queue items until consumed, and nothing
-        rewrites them. A barrier here costs ~180 ms/step on a
-        remote-attached chip (measured), so do not add one back without
-        re-proving the buffers' lifetime story."""
+        rewrites them. A barrier here would serialize the feeder behind
+        every transfer, so do not add one back without re-proving the
+        buffers' lifetime story (chip_smoke.py's two-run bit-identity
+        check is the on-chip test of it)."""
         if self.mesh is None:
             return (
                 jax.device_put(device_inputs), jax.device_put(miss_aux),
@@ -833,8 +836,7 @@ class CachedTrainCtx:
                 self.init_state(jax.random.PRNGKey(0), device_inputs, layout)
             # explicit async host→device staging: passing numpy leaves
             # straight into jit makes the arg conversion a synchronous
-            # per-leaf round-trip on remote-attached chips (measured 84 ms
-            # vs 1 ms for the same data)
+            # per-leaf transfer inside the dispatch
             device_inputs, miss_aux, cold_aux, evict_aux = self._stage(
                 device_inputs, miss_aux, cold_aux, evict_aux
             )

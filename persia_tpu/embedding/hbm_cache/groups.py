@@ -250,9 +250,8 @@ def _gather_entry_rows(table, state: Dict[str, jnp.ndarray], rows):
 @_partial(jax.jit, donate_argnums=(0, 1))
 def _restore_rows(table, state: Dict[str, jnp.ndarray], payload, src_idx, dst_rows):
     """Re-admit rows whose write-back is still in flight straight from the
-    DEVICE-resident eviction payload (device→host transfers on a
-    remote-attached chip cost ~60 ms latency each — the hazard path must
-    never wait on one)."""
+    DEVICE-resident eviction payload (the hazard path must never wait on
+    the write-back's device→host transfer)."""
     return _scatter_entry_block(table, state, dst_rows, payload[src_idx])
 
 
@@ -262,17 +261,17 @@ def _apply_aux(table, state: Dict[str, jnp.ndarray], ev_rows, m_rows,
     """Fused per-group per-step aux program: read the eviction payload (from
     the PRE-scatter table — a missed row may reuse an evicted one), then
     scatter warm entries and cold seeds. One dispatch instead of three:
-    after the first write-back d2h the runtime's per-dispatch latency
-    degrades ~200× (see ``train_stream``), so the steady-state eviction
-    regime pays per CALL, not per byte. Absent pieces ride as 0-row arrays.
+    these programs move a few thousand rows each, so the steady-state
+    eviction regime pays per CALL, not per byte (a trivial chained dispatch
+    measured ~0.19 ms on the v5e, PR 21 chip_smoke — the same before and
+    after the process's first d2h). Absent pieces ride as 0-row arrays.
 
     Compile-cache tradeoff: fusing keys the jit on the COMBINATION of the
     three piece-size buckets (worst case the cross-product, vs the per-piece
     sum for split jits). In practice the regimes are disjoint — fill phase
     is cold-only, steady state is (warm, evict) in one or two stable buckets
     each with cold decaying — so observed combinations stay within a few
-    dozen tiny programs; the per-call dispatch saving dominates once the
-    runtime is in the degraded-dispatch mode."""
+    dozen tiny programs."""
     parts = [table[ev_rows]]
     for key in ("acc", "m", "v"):
         if key in state:

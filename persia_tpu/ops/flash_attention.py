@@ -7,9 +7,10 @@ online-softmax state across k blocks and the output block is written once on
 the last k step. fp32 accumulation regardless of input dtype; MXU matmuls via
 ``preferred_element_type``.
 
-Off-TPU (tests, CPU dry runs) the kernel runs in interpret mode. The backward
-pass recomputes attention densely under XLA (``@jax.custom_vjp``) — exact
-gradients, O(L^2) memory on the backward only.
+The kernel is compiled by Mosaic unless the caller asks for
+``interpret=True`` (the CPU tests do); nothing picks the interpreter on the
+caller's behalf. The backward pass recomputes attention densely under XLA
+(``@jax.custom_vjp``) — exact gradients, O(L^2) memory on the backward only.
 
 Used by the model zoo for long user-behavior sequences (DIN-style attention)
 and usable as the local block of ring attention for L/n still too large for
@@ -157,17 +158,15 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = 256,
     block_k: int = 512,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Tiled attention: q, k, v [B, L, H, D] → [B, L, H, D].
 
-    ``interpret=None`` auto-selects interpret mode off-TPU so the same call
-    sites work in CPU tests and on hardware.
+    ``interpret=True`` runs the kernel in the Pallas interpreter (CPU
+    tests); the default compiles it, which needs a TPU.
     """
     if q.ndim != 4:
         raise ValueError(f"expected [B, L, H, D], got shape {q.shape}")
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return _flash(q, k, v, scale, causal, block_q, block_k, interpret)

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from persia_tpu.compile_cache import enable_compile_cache
 from persia_tpu.data import PersiaBatch
 from persia_tpu.logger import get_default_logger
 from persia_tpu.parallel.fused_step import (
@@ -141,6 +142,7 @@ class FusedTrainCtx:
     # lifecycle ------------------------------------------------------------
 
     def __enter__(self) -> "FusedTrainCtx":
+        enable_compile_cache()
         return self
 
     def __exit__(self, *exc) -> None:

@@ -11,7 +11,7 @@ the ENTIRE hybrid step into one XLA program:
 
 Host↔device traffic per step collapses to the raw batch (int32 ids + dense
 features + labels) in, one scalar loss out — no embedding or gradient ever
-crosses the PCIe/tunnel boundary. The host C++ PS tier
+crosses the host↔device link. The host C++ PS tier
 (`persia_tpu.embedding.native_store`) remains the capacity tier for vocab
 that exceeds HBM; `persia_tpu.interop` moves rows between the two tiers.
 
@@ -452,8 +452,7 @@ def build_fused_multi_step(
     advances ``k`` consecutive batches — ``multi(state, batches) -> (state,
     (losses, preds_list))`` with ``batches`` a length-``k`` tuple of the
     single-step batch dict. The per-dispatch Python/header overhead that
-    bounds small-step-time loops (and dominates on a remote-attached chip,
-    where every dispatch pays tunnel latency) is paid once per K steps; the
+    bounds small-step-time loops is paid once per K steps; the
     math is the single-step program iterated, so parity with
     ``build_fused_train_step`` is exact in program terms — but NOT bitwise:
     XLA compiles the step subgraph differently inside the larger program
@@ -531,8 +530,8 @@ def shard_fused_state(state: FusedTrainState, mesh, table_axis: str = "data"):
 
 def pack_ids(ids_np: Dict[str, np.ndarray], slot_order: Sequence[str]):
     """Host-side helper: one contiguous int32 buffer for all slots' ids so
-    staging is a single host→device transfer (per-leaf puts pay a full
-    round-trip each on a remote-attached chip)."""
+    staging is a single host→device transfer (one put per step instead of
+    one per slot)."""
     flat = np.concatenate(
         [np.ascontiguousarray(ids_np[n], dtype=np.int32).reshape(-1) for n in slot_order]
     )

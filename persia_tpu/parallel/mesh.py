@@ -16,18 +16,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions. The top-level alias and its
-    ``check_vma`` kwarg are newer than 0.4.x; older jax exposes
-    ``jax.experimental.shard_map.shard_map`` whose equivalent kwarg is
-    ``check_rep``. Every shard_map call site in the repo goes through here
-    so a version bump in either direction is a one-line change."""
-    if hasattr(jax, "shard_map"):  # deprecation __getattr__ => False on old jax
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map`` with ``check_vma`` off unless the caller asks for
+    it. Every shard_map call site in the repo goes through here."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def data_parallel_mesh(n_devices: Optional[int] = None) -> Mesh:

@@ -159,8 +159,8 @@ def build_train_step(
     embedding wire dtype (bf16 halves device→host bytes, matching the
     reference's f16 gradient wire) — the bulk transfer, fetched
     asynchronously by the BackwardEngine so it overlaps the next step
-    (per-array fetches pay a full round-trip each; on a remote-attached TPU
-    that latency dominated the step). ``unpack_step_output`` splits them
+    (one fetch per step instead of one round-trip per array).
+    ``unpack_step_output`` splits them
     using shapes derived from the batch. Emb grads align with
     ``batch['emb']``: (B, dim) for pooled slots, (P, dim) for raw slots
     (rows past the true distinct count are zero — the host slices them off
@@ -384,9 +384,8 @@ def build_eval_step(model):
 
 def _packed_put(batch: Dict) -> Dict:
     """Single-chip fast path: ship every float embedding leaf in ONE
-    device_put (host-side concat, device-side lazy slices). Per-leaf puts pay
-    a full host→device round-trip each — on a remote-attached chip that
-    latency dominated staging."""
+    device_put (host-side concat, device-side lazy slices): one transfer per
+    step instead of one host→device round-trip per leaf."""
     out: Dict = {
         "dense": [jnp.asarray(x) for x in batch["dense"]],
         "labels": [jnp.asarray(x) for x in batch["labels"]],

@@ -27,20 +27,25 @@ _LIB: Optional[ctypes.CDLL] = None
 _LOAD_FAILED = False
 
 
+def build_native(force: bool = False) -> str:
+    """Compile the codec core if missing or stale (see
+    ``_native_build.build_so``); returns the path to ``CDLL``."""
+    from persia_tpu.embedding._native_build import build_so
+
+    return build_so(
+        _SRC, _SO,
+        ["-O3", "-std=c++17", "-fPIC", "-shared", "-Wall"],
+        logger, force=force,
+    )
+
+
 def _load() -> Optional[ctypes.CDLL]:
     global _LIB, _LOAD_FAILED
     if _LIB is not None or _LOAD_FAILED:
         return _LIB
     try:
-        from persia_tpu.embedding._native_build import build_so
-
-        # CDLL the path build_so RETURNS (sanitizer-variant aware)
-        so_path = build_so(
-            _SRC, _SO,
-            ["-O3", "-std=c++17", "-fPIC", "-shared", "-Wall"],
-            logger,
-        )
-        lib = ctypes.CDLL(so_path)
+        # CDLL the path build_native RETURNS (sanitizer-variant aware)
+        lib = ctypes.CDLL(build_native())
         i64, u8p = ctypes.c_int64, ctypes.POINTER(ctypes.c_uint8)
         lib.lz4_compress_bound.restype = i64
         lib.lz4_compress_bound.argtypes = [i64]

@@ -44,6 +44,17 @@ _LIB: Optional[ctypes.CDLL] = None
 _LOAD_FAILED = False
 
 
+_FLAGS = ["-O3", "-std=c++17", "-fPIC", "-shared", "-Wall", "-pthread", "-ldl"]
+
+
+def build_native(force: bool = False) -> str:
+    """Compile the server core if missing or stale (see
+    ``_native_build.build_so``); returns the path to ``CDLL``."""
+    from persia_tpu.embedding._native_build import build_so
+
+    return build_so(_SRCS, _SO, _FLAGS, logger, force=force)
+
+
 def _load() -> Optional[ctypes.CDLL]:
     global _LIB, _LOAD_FAILED
     if _LIB is not None or _LOAD_FAILED:
@@ -57,12 +68,10 @@ def _load() -> Optional[ctypes.CDLL]:
         # sanitizer that must be the matching VARIANT ps artifact (mixed
         # sanitized/unsanitized cores in one process would miss reports)
         _PS_SO_PATH = build_ps()
-        # CDLL the path build_so RETURNS (sanitizer-variant aware)
-        so_path = build_so(
-            _SRCS, _SO,
-            ["-O3", "-std=c++17", "-fPIC", "-shared", "-Wall", "-pthread", "-ldl"],
-            logger,
-        )
+        # CDLL the path build_so RETURNS (sanitizer-variant aware); built
+        # here rather than through build_native() so persia-lint's ABI pass
+        # can tie the handle to _SO (this file names two libs)
+        so_path = build_so(_SRCS, _SO, _FLAGS, logger)
         lib = ctypes.CDLL(so_path)
         lib.net_server_start.restype = ctypes.c_void_p
         lib.net_server_start.argtypes = [

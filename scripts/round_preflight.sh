@@ -25,8 +25,8 @@
 #    -fsanitize=thread and costs ~2min.
 # 1. chaos suite, fast schedules (fault proxies, breakers, degraded mode)
 # 2. full test suite green
-# 3. bench.py rc=0 (real chip when attached; emits partial records on a
-#    degraded link rather than failing)
+# 3. bench.py rc=0 — needs a TPU: without one, or when any mode dies or
+#    blows its budget, bench.py exits non-zero and so does this script
 # 4. dryrun_multichip(8) on a virtual CPU mesh
 set -euo pipefail
 cd "$(dirname "$0")/.."

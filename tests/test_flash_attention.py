@@ -1,12 +1,18 @@
 """Pallas flash attention (interpret mode on CPU) vs the dense oracle."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from persia_tpu.ops import flash_attention
+from persia_tpu import ops
 from persia_tpu.parallel.sequence import reference_attention
+
+# the kernel compiles by default (that needs a TPU); on CPU the suite asks
+# for the Pallas interpreter itself
+flash_attention = functools.partial(ops.flash_attention, interpret=True)
 
 
 def _qkv(b=2, l=64, h=4, d=16, seed=0, dtype=jnp.float32):
@@ -85,3 +91,11 @@ def test_mismatched_blocks_cover_all_rows(bq, bk):
     out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
     ref = reference_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5)
+
+
+def test_default_is_compiled_not_interpreted():
+    """No caller gets the interpreter without asking: off-TPU the default
+    (compile with Mosaic) refuses instead of quietly interpreting."""
+    q, k, v = _qkv(l=8)
+    with pytest.raises(ValueError, match="interpret mode"):
+        ops.flash_attention(q, k, v)

@@ -36,7 +36,7 @@ from persia_tpu.parallel.train_step import (
     unpack_step_header,
 )
 
-# version-portable shard_map (check_vma vs check_rep kwarg)
+# the repo's shard_map entry point (jax.shard_map, check_vma off by default)
 from persia_tpu.parallel.mesh import shard_map_compat as shard_map
 
 B = 32
