@@ -9,15 +9,54 @@ the operator placed in ``JAX_COMPILATION_CACHE_DIR`` (JAX reads that
 variable itself; nothing is set here) or ``<checkout>/.jax_cache``, derived
 from this package's own location. This is the only place in the tree that
 names a cache directory. ``CompileMeter`` counts what a process compiled
-and what the cache gave it, from JAX's own monitoring events.
+and what the cache gave it, from JAX's own monitoring events; from the same
+events every backend compile becomes a flight event ``compile`` (seconds,
+whether the persistent cache served it, the program's name), whose trigger
+is the span around it (``stream.dispatch*``, ``ctx.apply_aux``,
+``fused.dispatch``).
 """
 
 from __future__ import annotations
 
 import os
+import threading
 from typing import Dict, Tuple
 
+from persia_tpu.tracing import record_event
+
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_HIT = "/jax/compilation_cache/cache_hits"
+_WRITE = "/jax/compilation_cache/cache_misses"
+# a persistent-cache hit is announced inside the compile call it serves, on
+# the thread that makes the call
+_hit = threading.local()
+_recording = False
+
+
+def _note_hit(event, **_kw) -> None:
+    if event == _HIT:
+        _hit.seen = True
+
+
+def _note_compile(event, secs, **kw) -> None:
+    if event == _BACKEND:
+        record_event("compile", secs=round(secs, 6),
+                     cached=getattr(_hit, "seen", False),
+                     program=kw.get("fun_name", ""))
+        _hit.seen = False
+
+
+def _record_compiles() -> None:
+    """Every backend compile of this process as a flight event; once."""
+    global _recording
+    if _recording:
+        return
+    _recording = True
+    import jax.monitoring as mon
+
+    mon.register_event_listener(_note_hit)
+    mon.register_event_duration_secs_listener(_note_compile)
 
 
 def enable_compile_cache() -> str:
@@ -30,6 +69,7 @@ def enable_compile_cache() -> str:
     mismatch ("could lead to SIGILL") per program."""
     import jax
 
+    _record_compiles()
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     path = placed or os.path.join(_CHECKOUT, ".jax_cache")
     if jax.default_backend() == "cpu":
@@ -48,9 +88,7 @@ class CompileMeter:
     its retrieval time there instead), how many programs, how many came
     from the persistent cache and how many were written to it."""
 
-    BACKEND = "/jax/core/compile/backend_compile_duration"
-    HIT = "/jax/compilation_cache/cache_hits"
-    WRITE = "/jax/compilation_cache/cache_misses"
+    BACKEND, HIT, WRITE = _BACKEND, _HIT, _WRITE
 
     def __init__(self):
         import jax.monitoring as mon

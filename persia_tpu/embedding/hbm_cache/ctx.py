@@ -29,7 +29,7 @@ from persia_tpu.logger import get_default_logger
 from persia_tpu.utils import round_up_pow2 as _round_up_pow2
 from persia_tpu.metrics import get_metrics
 from persia_tpu.ops.sparse_update import sparse_update
-from persia_tpu.tracing import span
+from persia_tpu.tracing import accumulate, span, stage_span, wait_span
 
 logger = get_default_logger("persia_tpu.hbm_cache")
 
@@ -482,17 +482,22 @@ class CachedTrainCtx:
             return evict_payload
         tables = dict(self.state.tables)
         emb_state = dict(self.state.emb_state)
-        with span("ctx.apply_aux", groups=len(touched)):
-            for gname in sorted(touched):
-                em = self._group_empties(gname)
-                ev_rows = evict_aux.get(gname, em["rows"])
-                m_rows, m_entries = miss_aux.get(
-                    gname, (em["rows"], em["entries"])
-                )
-                c_rows, c_emb = cold_aux.get(gname, (em["rows"], em["emb"]))
-                ring_pos = -1
-                if evict_meta and gname in evict_meta:
-                    ring_pos = evict_meta[gname][2]
+        for gname in sorted(touched):
+            em = self._group_empties(gname)
+            ev_rows = evict_aux.get(gname, em["rows"])
+            m_rows, m_entries = miss_aux.get(
+                gname, (em["rows"], em["entries"])
+            )
+            c_rows, c_emb = cold_aux.get(gname, (em["rows"], em["emb"]))
+            ring_pos = -1
+            if evict_meta and gname in evict_meta:
+                ring_pos = evict_meta[gname][2]
+            # one aux dispatch, named by the padded piece sizes that key its
+            # program: a size not seen before compiles inside this span
+            with stage_span(
+                "ctx.apply_aux", group=gname, ev=ev_rows.shape[0],
+                miss=m_rows.shape[0], cold=c_rows.shape[0], ring=ring_pos >= 0,
+            ):
                 if ring_pos >= 0:
                     (tables[gname], emb_state[gname],
                      self._ev_rings[gname], payload) = _apply_aux_ring(
@@ -507,8 +512,8 @@ class CachedTrainCtx:
                         m_rows, m_entries, c_rows, c_emb,
                         self._state_consts, self._wb_bf16,
                     )
-                if gname in evict_aux:
-                    evict_payload[gname] = payload
+            if gname in evict_aux:
+                evict_payload[gname] = payload
         self.state = self.state.replace(tables=tables, emb_state=emb_state)
         return evict_payload
 
@@ -697,7 +702,11 @@ class CachedTrainCtx:
         ``feeder_busy_s``, ``wall_s``, plus the dense-plane sync record
         (``sync_mode``, ``dense_wire_bytes_per_step``) — the artifact
         fields bench.py commits so hot-loop regressions are visible from
-        the JSON alone."""
+        the JSON alone. ``stages`` and ``waits`` hold the stream's one time
+        accounting (tracing.StageAccumulator): per work span ``{n, busy_s,
+        max_s}``, per wait span ``{n, wait_s, max_s}``; the closing
+        ``stream.drain`` waits, ``drain()``'s included, arrive after
+        ``wall_s`` is taken."""
         return self._stream_stats
 
     @property
@@ -933,10 +942,12 @@ class CachedTrainCtx:
         """Land any deferred write-back and return the last step's metrics
         (materializing a ``fetch_final=False`` stream's stashed header if
         that is the freshest result)."""
-        if self._pending is not None:
-            self._fetch_metrics()
-            self._land_pending()
-        return self.last_metrics()
+        graph = self._stage_graph  # the last stream's accounting takes the wait
+        with accumulate(graph.acc if graph else None), wait_span("stream.drain"):
+            if self._pending is not None:
+                self._fetch_metrics()
+                self._land_pending()
+            return self.last_metrics()
 
     # -------------------------------------------------------------- pipeline
 

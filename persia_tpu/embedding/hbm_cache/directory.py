@@ -599,10 +599,15 @@ class CacheDirectory:
                         f"({self.shards}), got {len(handles)}")
                 sk_arr = (ctypes.c_void_p * len(handles))(*handles)
                 n_sk = len(handles)
-            n_miss = self._lib.cache_feed_batch_sharded(
-                self._h, pending_h, *common,
-                sk_arr, n_sk, int(samples_per_slot), int(slot_base),
-            )
+            # the per-shard walks time themselves natively and are reported
+            # after the fact (tier._note_shard_walk: ``feed.shard``, ring
+            # only); this is the live span they lie inside
+            with span("feed.walk", shards=self.shards) as walk:
+                n_miss = self._lib.cache_feed_batch_sharded(
+                    self._h, pending_h, *common,
+                    sk_arr, n_sk, int(samples_per_slot), int(slot_base),
+                )
+                walk.set(slowest_ns=int(self.shard_busy_ns().max()))
         else:
             if sketches is not None:
                 raise ValueError("fused sketch observe needs shards= set")

@@ -252,7 +252,8 @@ def _restore_rows(table, state: Dict[str, jnp.ndarray], payload, src_idx, dst_ro
     """Re-admit rows whose write-back is still in flight straight from the
     DEVICE-resident eviction payload (the hazard path must never wait on
     the write-back's device→host transfer)."""
-    return _scatter_entry_block(table, state, dst_rows, payload[src_idx])
+    with jax.named_scope("restore"):
+        return _scatter_entry_block(table, state, dst_rows, payload[src_idx])
 
 
 @_partial(jax.jit, donate_argnums=(0, 1), static_argnums=(7, 8))
@@ -272,22 +273,24 @@ def _apply_aux(table, state: Dict[str, jnp.ndarray], ev_rows, m_rows,
     is cold-only, steady state is (warm, evict) in one or two stable buckets
     each with cold decaying — so observed combinations stay within a few
     dozen tiny programs."""
-    parts = [table[ev_rows]]
-    for key in ("acc", "m", "v"):
-        if key in state:
-            parts.append(state[key][ev_rows])
-    payload = jnp.concatenate(parts, axis=1)
-    if wb_bf16:
-        # bf16 write-back wire (the reference ships f16 lookup/grad wires,
-        # lib.rs:157-180): halves the d2h bytes that bound the eviction
-        # steady state; opt-in because the default tier is bit-exact
-        payload = payload.astype(jnp.bfloat16)
-    table, out_state = _scatter_entry_block(table, state, m_rows, m_entries)
-    table = table.at[c_rows].set(c_emb.astype(table.dtype), mode="drop")
-    for key, val in state_consts:
-        st = out_state[key]
-        fill = jnp.full((c_rows.shape[0], st.shape[1]), val, dtype=st.dtype)
-        out_state[key] = st.at[c_rows].set(fill, mode="drop")
+    with jax.named_scope("evict_gather"):
+        parts = [table[ev_rows]]
+        for key in ("acc", "m", "v"):
+            if key in state:
+                parts.append(state[key][ev_rows])
+        payload = jnp.concatenate(parts, axis=1)
+        if wb_bf16:
+            # bf16 write-back wire (the reference ships f16 lookup/grad wires,
+            # lib.rs:157-180): halves the d2h bytes that bound the eviction
+            # steady state; opt-in because the default tier is bit-exact
+            payload = payload.astype(jnp.bfloat16)
+    with jax.named_scope("aux_scatter"):
+        table, out_state = _scatter_entry_block(table, state, m_rows, m_entries)
+        table = table.at[c_rows].set(c_emb.astype(table.dtype), mode="drop")
+        for key, val in state_consts:
+            st = out_state[key]
+            fill = jnp.full((c_rows.shape[0], st.shape[1]), val, dtype=st.dtype)
+            out_state[key] = st.at[c_rows].set(fill, mode="drop")
     return table, out_state, payload
 
 
@@ -307,9 +310,10 @@ def _apply_aux_ring(table, state: Dict[str, jnp.ndarray], ring, ring_pos,
         table, state, ev_rows, m_rows, m_entries, c_rows, c_emb,
         state_consts, wb_bf16,
     )
-    ring = jax.lax.dynamic_update_slice(
-        ring, payload.astype(ring.dtype), (ring_pos, 0)
-    )
+    with jax.named_scope("evict_gather"):
+        ring = jax.lax.dynamic_update_slice(
+            ring, payload.astype(ring.dtype), (ring_pos, 0)
+        )
     return table, out_state, ring, payload
 
 

@@ -151,15 +151,19 @@ def build_cached_train_step(
         # ONE gather per group for all its stacked pooled slots, plus one
         # per raw slot; differentiate w.r.t. the GATHERED arrays (like the
         # fused path) so cotangents stay gather-shaped instead of dense
-        # table-shaped scatters
-        stacked_gathered = {
-            gname: tables[gname][rows]  # (S, B, L, dim)
-            for gname, rows in batch["stacked_rows"].items()
-        }
-        raw_gathered = {
-            name: tables[_slot_group_of(groups, name)][rows]
-            for name, rows in batch["raw_rows"].items()
-        }
+        # table-shaped scatters. The named scopes of this step (gather, pool,
+        # bottom_mlp, interaction, top_mlp, loss, grad_guard, dense_opt,
+        # sparse_update/...) are the fused step's too: a device trace names
+        # every operation by the part of the step it belongs to.
+        with jax.named_scope("gather"):
+            stacked_gathered = {
+                gname: tables[gname][rows]  # (S, B, L, dim)
+                for gname, rows in batch["stacked_rows"].items()
+            }
+            raw_gathered = {
+                name: tables[_slot_group_of(groups, name)][rows]
+                for name, rows in batch["raw_rows"].items()
+            }
         from persia_tpu.parallel.train_step import (
             _embedding_model_inputs, _split_emb,
         )
@@ -173,11 +177,12 @@ def build_cached_train_step(
         )
 
         def loss_wrapper(params, stacked_in, raw_in, ps_in):
-            model_emb = _model_emb_from_gathered(
-                groups, batch, layout, stacked_in, raw_in,
-                pad_row=lambda gname: by_name[gname].rows,
-                ps_model_inputs=_embedding_model_inputs(ps_in, ps_static),
-            )
+            with jax.named_scope("pool"):
+                model_emb = _model_emb_from_gathered(
+                    groups, batch, layout, stacked_in, raw_in,
+                    pad_row=lambda gname: by_name[gname].rows,
+                    ps_model_inputs=_embedding_model_inputs(ps_in, ps_static),
+                )
             variables = {"params": params}
             if state.batch_stats:
                 variables["batch_stats"] = state.batch_stats
@@ -189,8 +194,9 @@ def build_cached_train_step(
             else:
                 logits = model.apply(variables, batch["dense"], model_emb, train=True)
                 new_stats = state.batch_stats
-            loss = loss_fn(logits, batch["labels"][0])
-            return loss * scale.astype(loss.dtype), (loss, logits, new_stats)
+            with jax.named_scope("loss"):
+                loss = loss_fn(logits, batch["labels"][0])
+                return loss * scale.astype(loss.dtype), (loss, logits, new_stats)
 
         (_, (loss, logits, new_stats)), (param_grads, stacked_g, raw_g, ps_g) = (
             jax.value_and_grad(
@@ -199,86 +205,88 @@ def build_cached_train_step(
         )
 
         need_guard = dynamic_loss_scale or sentinel_probe
-        if need_guard:
-            leaves = (
-                jax.tree.leaves(param_grads)
-                + jax.tree.leaves(stacked_g) + jax.tree.leaves(raw_g)
-                + jax.tree.leaves(ps_g)
-            )
-            finite = jnp.all(
-                jnp.stack([jnp.all(jnp.isfinite(g)) for g in leaves])
-            )
-            inv = jnp.where(finite, 1.0 / scale, 0.0).astype(jnp.float32)
-        else:
-            finite = jnp.asarray(True)
-            inv = jnp.asarray(1.0, jnp.float32)
-
-        clip_f = jnp.asarray(1.0, jnp.float32)
-        probe_tail = None
-        if sentinel_probe:
-            # Norms of the UNSCALED gradients (inv divides the loss scale
-            # out; overflow steps report 0 and carry the finite flag).
-            def _gnorm(parts):
-                parts = list(parts)
-                if not parts:
-                    return jnp.asarray(0.0, jnp.float32)
-                return jnp.sqrt(
-                    sum(jnp.sum(jnp.square(p.astype(jnp.float32)))
-                        for p in parts)
+        with jax.named_scope("grad_guard"):
+            if need_guard:
+                leaves = (
+                    jax.tree.leaves(param_grads)
+                    + jax.tree.leaves(stacked_g) + jax.tree.leaves(raw_g)
+                    + jax.tree.leaves(ps_g)
                 )
-
-            dense_gnorm = _gnorm(jax.tree.leaves(param_grads)) * inv
-            group_gnorms = []
-            for g in groups:
-                parts = []
-                if g.name in batch["stacked_rows"]:
-                    parts.append(stacked_g[g.name])
-                for name in g.raw_slots:
-                    if name in batch["raw_rows"]:
-                        parts.append(raw_g[name])
-                group_gnorms.append(_gnorm(parts) * inv)
-            ps_gnorm = _gnorm(jax.tree.leaves(ps_g)) * inv
-            if guard_clip_norm is not None:
-                total = jnp.sqrt(
-                    jnp.square(dense_gnorm) + jnp.square(ps_gnorm)
-                    + sum(jnp.square(n) for n in group_gnorms)
+                finite = jnp.all(
+                    jnp.stack([jnp.all(jnp.isfinite(g)) for g in leaves])
                 )
-                clip_f = jnp.where(
-                    total > guard_clip_norm,
-                    guard_clip_norm / jnp.maximum(total, 1e-12),
-                    1.0,
-                ).astype(jnp.float32)
-            probe_tail = jnp.stack(
-                [dense_gnorm] + group_gnorms + [
-                    ps_gnorm,
-                    finite.astype(jnp.float32),
-                    (clip_f < 1.0).astype(jnp.float32),
-                ]
-            )
-            inv = inv * clip_f
+                inv = jnp.where(finite, 1.0 / scale, 0.0).astype(jnp.float32)
+            else:
+                finite = jnp.asarray(True)
+                inv = jnp.asarray(1.0, jnp.float32)
 
-        if need_guard:
-            param_grads = jax.tree.map(
-                lambda g: (g.astype(jnp.float32) * inv).astype(g.dtype),
-                param_grads,
-            )
+            clip_f = jnp.asarray(1.0, jnp.float32)
+            probe_tail = None
+            if sentinel_probe:
+                # Norms of the UNSCALED gradients (inv divides the loss scale
+                # out; overflow steps report 0 and carry the finite flag).
+                def _gnorm(parts):
+                    parts = list(parts)
+                    if not parts:
+                        return jnp.asarray(0.0, jnp.float32)
+                    return jnp.sqrt(
+                        sum(jnp.sum(jnp.square(p.astype(jnp.float32)))
+                            for p in parts)
+                    )
+
+                dense_gnorm = _gnorm(jax.tree.leaves(param_grads)) * inv
+                group_gnorms = []
+                for g in groups:
+                    parts = []
+                    if g.name in batch["stacked_rows"]:
+                        parts.append(stacked_g[g.name])
+                    for name in g.raw_slots:
+                        if name in batch["raw_rows"]:
+                            parts.append(raw_g[name])
+                    group_gnorms.append(_gnorm(parts) * inv)
+                ps_gnorm = _gnorm(jax.tree.leaves(ps_g)) * inv
+                if guard_clip_norm is not None:
+                    total = jnp.sqrt(
+                        jnp.square(dense_gnorm) + jnp.square(ps_gnorm)
+                        + sum(jnp.square(n) for n in group_gnorms)
+                    )
+                    clip_f = jnp.where(
+                        total > guard_clip_norm,
+                        guard_clip_norm / jnp.maximum(total, 1e-12),
+                        1.0,
+                    ).astype(jnp.float32)
+                probe_tail = jnp.stack(
+                    [dense_gnorm] + group_gnorms + [
+                        ps_gnorm,
+                        finite.astype(jnp.float32),
+                        (clip_f < 1.0).astype(jnp.float32),
+                    ]
+                )
+                inv = inv * clip_f
+
+            if need_guard:
+                param_grads = jax.tree.map(
+                    lambda g: (g.astype(jnp.float32) * inv).astype(g.dtype),
+                    param_grads,
+                )
 
         import optax as _optax
 
-        updates, new_opt_state = dense_optimizer.update(
-            param_grads, state.opt_state, state.params
-        )
-        new_params = _optax.apply_updates(state.params, updates)
-        if need_guard:
-            # overflow / non-finite grads: dense update skipped entirely
-            new_params = jax.tree.map(
-                lambda new, old: jnp.where(finite, new, old),
-                new_params, state.params,
+        with jax.named_scope("dense_opt"):
+            updates, new_opt_state = dense_optimizer.update(
+                param_grads, state.opt_state, state.params
             )
-            new_opt_state = jax.tree.map(
-                lambda new, old: jnp.where(finite, new, old),
-                new_opt_state, state.opt_state,
-            )
+            new_params = _optax.apply_updates(state.params, updates)
+            if need_guard:
+                # overflow / non-finite grads: dense update skipped entirely
+                new_params = jax.tree.map(
+                    lambda new, old: jnp.where(finite, new, old),
+                    new_params, state.params,
+                )
+                new_opt_state = jax.tree.map(
+                    lambda new, old: jnp.where(finite, new, old),
+                    new_opt_state, state.opt_state,
+                )
 
         # on-device sparse update of the cached rows — ONE duplicate-safe
         # scatter per group (dedup inside sparse_update merges the same row
@@ -288,35 +296,40 @@ def build_cached_train_step(
         )
         for g in groups:
             idp, gp, mp = [], [], []
-            if g.name in batch["stacked_rows"]:
-                rows = batch["stacked_rows"][g.name]
-                idp.append(rows.reshape(-1))
-                # unscale under dynamic loss scaling; on overflow every row
-                # is MASKED OUT below (sparse_update touches no row at all —
-                # exact skip for every optimizer incl. weight decay and
-                # Adam's state decay, at O(touched rows)); the grads are
-                # also selected to zero so inf*0 NaNs never enter the math
-                sg = stacked_g[g.name].astype(jnp.float32).reshape(-1, g.dim)
-                gp.append(jnp.where(finite, sg * inv, 0.0))
-                mp.append(((rows < g.rows) & finite).reshape(-1))
-            for name in g.raw_slots:
-                if name not in batch["raw_rows"]:
+            with jax.named_scope("sparse_prep"):
+                if g.name in batch["stacked_rows"]:
+                    rows = batch["stacked_rows"][g.name]
+                    idp.append(rows.reshape(-1))
+                    # unscale under dynamic loss scaling; on overflow every
+                    # row is MASKED OUT below (sparse_update touches no row
+                    # at all — exact skip for every optimizer incl. weight
+                    # decay and Adam's state decay, at O(touched rows)); the
+                    # grads are also selected to zero so inf*0 NaNs never
+                    # enter the math
+                    sg = stacked_g[g.name].astype(jnp.float32).reshape(-1, g.dim)
+                    gp.append(jnp.where(finite, sg * inv, 0.0))
+                    mp.append(((rows < g.rows) & finite).reshape(-1))
+                for name in g.raw_slots:
+                    if name not in batch["raw_rows"]:
+                        continue
+                    rows = batch["raw_rows"][name]
+                    idp.append(rows.reshape(-1))
+                    rg = raw_g[name].astype(jnp.float32).reshape(-1, g.dim)
+                    gp.append(jnp.where(finite, rg * inv, 0.0))
+                    mp.append(((rows < g.rows) & finite).reshape(-1))
+                if not idp:
                     continue
-                rows = batch["raw_rows"][name]
-                idp.append(rows.reshape(-1))
-                rg = raw_g[name].astype(jnp.float32).reshape(-1, g.dim)
-                gp.append(jnp.where(finite, rg * inv, 0.0))
-                mp.append(((rows < g.rows) & finite).reshape(-1))
-            if not idp:
-                continue
+                flat_ids = jnp.concatenate(idp) if len(idp) > 1 else idp[0]
+                flat_g = jnp.concatenate(gp) if len(gp) > 1 else gp[0]
+                flat_mask = jnp.concatenate(mp) if len(mp) > 1 else mp[0]
             tables[g.name], emb_state[g.name] = sparse_update(
                 sparse_cfg,
                 tables[g.name],
                 emb_state[g.name],
-                jnp.concatenate(idp) if len(idp) > 1 else idp[0],
-                jnp.concatenate(gp) if len(gp) > 1 else gp[0],
+                flat_ids,
+                flat_g,
                 batch_state,
-                mask=jnp.concatenate(mp) if len(mp) > 1 else mp[0],
+                mask=flat_mask,
             )
 
         new_ls = state.loss_scale

@@ -29,7 +29,7 @@ from persia_tpu.logger import get_default_logger
 from persia_tpu.utils import round_up_pow2 as _round_up_pow2
 from persia_tpu.metrics import get_metrics
 from persia_tpu.ops.sparse_update import sparse_update
-from persia_tpu.tracing import record_event, span, stage_span
+from persia_tpu.tracing import accumulate, record_event, span, stage_span, wait_span
 
 logger = get_default_logger("persia_tpu.hbm_cache")
 
@@ -204,6 +204,9 @@ def run_train_stream(
     PIPE = pipeline_depth > 1 and on_metrics is None
     graph = StageGraph(pipeline_depth if PIPE else 1)
     self._stage_graph = graph
+    # the stream's one time accounting: every stage and wait span closed on
+    # a thread of this stream adds to it (ctx.stream_stats()["stages"/"waits"])
+    timing = graph.acc
     for _hook in self._stage_rebuild_hooks:
         graph.on_rebuild(_hook)
     job_mgr = None
@@ -275,7 +278,7 @@ def run_train_stream(
                 # ring full: ask the write-back thread to flush early and
                 # wait for the tail to advance
                 flush_now.set()
-                with span("stream.ring_wait", group=gname):
+                with wait_span("stream.ring_wait", group=gname):
                     cv.wait(timeout=0.5)
             return -1  # unwinding — the step never dispatches
 
@@ -312,13 +315,21 @@ def run_train_stream(
 
     prep_q: "_queue.Queue" = _queue.Queue(maxsize=prefetch)
 
-    def _put(q, item) -> bool:
-        while not (stop.is_set() or errors):
-            try:
-                q.put(item, timeout=0.5)
-                return True
-            except _queue.Full:
-                continue
+    def _put(q, item, wait_name: str) -> bool:
+        if stop.is_set() or errors:
+            return False
+        try:
+            q.put_nowait(item)
+            return True
+        except _queue.Full:
+            pass
+        with wait_span(wait_name):  # blocked on the stage downstream
+            while not (stop.is_set() or errors):
+                try:
+                    q.put(item, timeout=0.5)
+                    return True
+                except _queue.Full:
+                    continue
         return False
 
     # dispatch/feeder accounting for the bench artifact (ctx.stream_stats):
@@ -328,6 +339,7 @@ def run_train_stream(
         "packs": 0, "packed_steps": 0, "single_steps": 0,
         "pipelined_feeds": 0,
         "feeder_busy_s": 0.0, "wall_s": 0.0,
+        "stages": timing.stages, "waits": timing.waits,
         "degraded_steps": 0, "degraded_lookup_frac_max": 0.0,
         "fences": 0, "quarantine_skips": 0,
     }
@@ -351,26 +363,6 @@ def run_train_stream(
         "persia_tpu_stream_degraded_lookup_frac",
         "per-step degraded lookup fraction of the cached stream",
     )
-    _m_feeder_util = get_metrics().gauge(
-        "persia_tpu_stream_feeder_util",
-        "fraction of stream wall time the feeder thread was busy",
-    )
-    _m_packed_frac = get_metrics().gauge(
-        "persia_tpu_stream_packed_step_frac",
-        "fraction of dispatched steps that rode a K-step pack",
-    )
-
-    def _publish_live_stats() -> None:
-        """Export the stream's headline ratios as live gauges so the
-        telemetry collector sees them mid-run, not just in the final
-        stats dict."""
-        elapsed = _time.perf_counter() - t_start
-        if elapsed > 0.0:
-            _m_feeder_util.set(stats["feeder_busy_s"] / elapsed)
-        done = stats["packed_steps"] + stats["single_steps"]
-        if done:
-            _m_packed_frac.set(stats["packed_steps"] / done)
-
     def _note_degraded(seq: int) -> None:
         """Per-step degraded accounting + the configurable abort: a step
         that had to synthesize more than ``max_degraded_frac`` of its
@@ -413,7 +405,8 @@ def run_train_stream(
                     # the dispatcher reaches it only after every earlier
                     # step dispatched; fence_done unparks us post-capture.
                     fence_done.clear()
-                    if not _put(prep_q, ("fence", start_step + seq)):
+                    if not _put(prep_q, ("fence", start_step + seq),
+                                "stream.prep_put_wait"):
                         return
                     while not fence_done.wait(0.25):
                         if stop.is_set() or errors:
@@ -429,46 +422,44 @@ def run_train_stream(
                     stats["quarantine_skips"] += 1
                     seq += 1
                     continue
-                t_prep = _time.perf_counter()
-                with stage_span("stream.prep"):
+                with stage_span("stream.prep", seq=seq):
                     item = self.tier.prepare_batch(
                         batch, hazard_gate=gate, ring_alloc=ring_alloc,
                         pending_map=sign_map,
                     )
-                with span("stream.ps_forward"):
-                    ps_item = self._ps_forward(batch)
-                try:
-                    _note_degraded(seq)
-                except BaseException:
-                    # abort threshold tripped with a PS forward in hand:
-                    # release its staleness slot before unwinding
+                    with span("stream.ps_forward"):
+                        ps_item = self._ps_forward(batch)
+                    try:
+                        _note_degraded(seq)
+                    except BaseException:
+                        # abort threshold tripped with a PS forward in hand:
+                        # release its staleness slot before unwinding
+                        if ps_item is not None:
+                            self.worker.abort_gradient(ps_item[0])
+                        raise
                     if ps_item is not None:
-                        self.worker.abort_gradient(ps_item[0])
-                    raise
-                if ps_item is not None:
-                    _ref, embs, _counts, entries = ps_item
-                    di0 = item[0]
-                    di0["ps_emb"] = entries
-                    layout0 = CacheLayout(
-                        stacked=item[1].stacked,
-                        ps=tuple(eb.name for eb in embs),
-                    )
-                    item = (di0, layout0) + item[2:]
-                evict_meta = item[6]
-                # evicted signs become hazard-gated HERE (admit time): a
-                # later batch's probe must not trust the PS for them
-                # until the write-back lands their rows. Map srcs are the
-                # STANDING-RING rows reserved by ring_alloc above.
-                if evict_meta:
-                    with cv:
-                        for gn, (ev, k, ring_pos) in evict_meta.items():
-                            if ring_pos < 0:  # unwinding ring_alloc
-                                continue
-                            sign_map.insert_range(
-                                ev[:k], ring_pos, seq, salt=salts[gn]
-                            )
-                stats["feeder_busy_s"] += _time.perf_counter() - t_prep
-                if not _put(prep_q, (seq, item, ps_item)):
+                        _ref, embs, _counts, entries = ps_item
+                        di0 = item[0]
+                        di0["ps_emb"] = entries
+                        layout0 = CacheLayout(
+                            stacked=item[1].stacked,
+                            ps=tuple(eb.name for eb in embs),
+                        )
+                        item = (di0, layout0) + item[2:]
+                    evict_meta = item[6]
+                    # evicted signs become hazard-gated HERE (admit time): a
+                    # later batch's probe must not trust the PS for them
+                    # until the write-back lands their rows. Map srcs are the
+                    # STANDING-RING rows reserved by ring_alloc above.
+                    if evict_meta:
+                        with cv:
+                            for gn, (ev, k, ring_pos) in evict_meta.items():
+                                if ring_pos < 0:  # unwinding ring_alloc
+                                    continue
+                                sign_map.insert_range(
+                                    ev[:k], ring_pos, seq, salt=salts[gn]
+                                )
+                if not _put(prep_q, (seq, item, ps_item), "stream.prep_put_wait"):
                     if ps_item is not None:
                         self.worker.abort_gradient(ps_item[0])
                     return
@@ -499,7 +490,8 @@ def run_train_stream(
                 if got is SENTINEL:
                     break
                 if isinstance(got, tuple) and got[0] == "fence":
-                    if not _put(staged_q, got):  # FIFO keeps fence ordering
+                    # FIFO keeps fence ordering
+                    if not _put(staged_q, got, "stream.stage_put_wait"):
                         return
                     continue
                 seq, item, ps_item = got
@@ -521,7 +513,7 @@ def run_train_stream(
                         {n: g.name for n, g in self.tier._slot_group.items()},
                     )
                 with graph.lane("feed"):
-                    with stage_span("stream.stage"):
+                    with stage_span("stream.stage", seq=seq):
                         di, miss_aux, cold_aux, evict_aux = self._stage(
                             di, miss_aux, cold_aux, evict_aux
                         )
@@ -545,22 +537,26 @@ def run_train_stream(
                 if pipelinable:
                     # stall time (reserve_feed) stays OUTSIDE the feed
                     # lane so stage_overlap_frac measures work, not waits
-                    if not graph.reserve_feed(
-                        seq, hazard[0], hazard[1], should_abort=_pipe_abort
-                    ):
+                    with wait_span("stream.reserve_feed_wait", seq=seq):
+                        reserved = graph.reserve_feed(
+                            seq, hazard[0], hazard[1], should_abort=_pipe_abort
+                        )
+                    if not reserved:
                         return
                     with graph.lane("feed"):
-                        with span("stream.feed_dispatch", step=seq):
+                        with stage_span("stream.feed_dispatch", seq=seq):
                             with self._state_lock:
                                 feed_payload = self._apply_feed(
                                     miss_aux, cold_aux, evict_aux, evict_meta
                                 )
                     feed_done = True
                 elif PIPE:
-                    if not graph.reserve_feed(
-                        seq, None, None, should_abort=_pipe_abort,
-                        barrier=True,
-                    ):
+                    with wait_span("stream.reserve_feed_wait", seq=seq):
+                        reserved = graph.reserve_feed(
+                            seq, None, None, should_abort=_pipe_abort,
+                            barrier=True,
+                        )
+                    if not reserved:
                         if ps_item is not None:
                             self.worker.abort_gradient(ps_item[0])
                         return
@@ -568,6 +564,7 @@ def run_train_stream(
                     staged_q,
                     (seq, di, layout, miss_aux, cold_aux, restore_aux,
                      evict_aux, evict_meta, ps_item, feed_done, feed_payload),
+                    "stream.stage_put_wait",
                 ):
                     if ps_item is not None:
                         self.worker.abort_gradient(ps_item[0])
@@ -592,7 +589,7 @@ def run_train_stream(
         # the d2h return lane is the stage graph's third stage: eviction
         # write-backs and PS gradient returns ride it
         with graph.lane("psgrad", steps=len(acc)):
-            with stage_span("stream.wb_flush", steps=len(acc)):
+            with stage_span("stream.wb_flush", seq=acc[0][0], steps=len(acc)):
                 _flush_acc_inner(acc)
 
     def _release_acc(acc) -> None:
@@ -624,10 +621,12 @@ def run_train_stream(
         def fetch(f):
             return np.asarray(f[4])[:f[3]].astype(np.float32)
 
-        hosts = list(pool.map(fetch, fetches)) if pool else [fetch(f) for f in fetches]
-        for (seq, gn, ev, k, _p), host in zip(fetches, hosts):
-            g = next(gr for gr in self.tier.groups if gr.name == gn)
-            self.tier._set_embedding(ev[:k], host[:k], dim=g.dim)
+        with stage_span("stream.wb_fetch", n=len(fetches)):
+            hosts = list(pool.map(fetch, fetches)) if pool else [fetch(f) for f in fetches]
+        with stage_span("stream.wb_store", rows=sum(f[3] for f in fetches)):
+            for (seq, gn, ev, k, _p), host in zip(fetches, hosts):
+                g = next(gr for gr in self.tier.groups if gr.name == gn)
+                self.tier._set_embedding(ev[:k], host[:k], dim=g.dim)
         _release_acc(acc)
 
     PS_BATCH = max(1, psgrad_batch)
@@ -740,9 +739,15 @@ def run_train_stream(
                 if item is SENTINEL:
                     return
 
-    feeder_t = threading.Thread(target=feeder_prep, daemon=True, name="cache-feeder")
-    dp_t = threading.Thread(target=feeder_dp, daemon=True, name="cache-stager")
-    wb_t = threading.Thread(target=writeback, daemon=True, name="cache-writeback")
+    def _bound(stage_fn):
+        def run():
+            with accumulate(timing):
+                stage_fn()
+        return run
+
+    feeder_t = threading.Thread(target=_bound(feeder_prep), daemon=True, name="cache-feeder")
+    dp_t = threading.Thread(target=_bound(feeder_dp), daemon=True, name="cache-stager")
+    wb_t = threading.Thread(target=_bound(writeback), daemon=True, name="cache-writeback")
     feeder_t.start()
     dp_t.start()
     wb_t.start()
@@ -818,7 +823,7 @@ def run_train_stream(
             else:
                 try:
                     if job_mgr is not None:
-                        with span("stream.fence", step=gstep):
+                        with stage_span("stream.fence", step=gstep):
                             self._fence_capture(job_mgr, gstep, occupancy)
                     stats["fences"] = stats.get("fences", 0) + 1
                     record_event("stream.fence_commit", step=gstep)
@@ -918,32 +923,28 @@ def run_train_stream(
             # gradient batch (same contract as the sync train_step)
             for grp in self._cached_groups:
                 self.tier.router.advance_batch_state(grp)
-        _publish_live_stats()
 
     def _dispatch_one(item):
         nonlocal header
         (seq, di, layout, miss_aux, cold_aux, restore_aux, evict_aux,
          evict_meta, ps_item, feed_done, feed_payload) = item
         try:
-            if self.state is None:
-                self.init_state(jax.random.PRNGKey(0), di, layout)
-            if feed_done:
-                # FEED already dispatched from the stager thread: dense
-                # stage only (the payload came back with the feed)
-                with graph.lane("dense"):
-                    with stage_span("stream.dispatch"):
-                        with self._state_lock:
-                            header = self._dispatch_dense(di, layout)
-                evict_payload, ps_gpacked = feed_payload, None
-                stats["pipelined_feeds"] += 1
-            else:
-                with graph.lane("dense"):
-                    with stage_span("stream.dispatch"):
-                        with self._state_lock:
-                            header, evict_payload, ps_gpacked = self._dispatch(
-                                di, layout, miss_aux, cold_aux, restore_aux,
-                                evict_aux, evict_meta,
-                            )
+            with graph.lane("dense"), stage_span("stream.dispatch", seq=seq):
+                if self.state is None:
+                    self.init_state(jax.random.PRNGKey(0), di, layout)
+                with self._state_lock:
+                    if feed_done:
+                        # FEED already dispatched from the stager thread:
+                        # dense stage only (the payload came back with the
+                        # feed)
+                        header = self._dispatch_dense(di, layout)
+                        evict_payload, ps_gpacked = feed_payload, None
+                        stats["pipelined_feeds"] += 1
+                    else:
+                        header, evict_payload, ps_gpacked = self._dispatch(
+                            di, layout, miss_aux, cold_aux, restore_aux,
+                            evict_aux, evict_meta,
+                        )
         except BaseException:
             # the in-hand item is already off the queue: the shutdown
             # drain in finally can't see it, so its staleness ref must
@@ -1041,7 +1042,7 @@ def run_train_stream(
     def _dispatch_pack():
         nonlocal header
         with graph.lane("dense"):
-            with stage_span("stream.dispatch_pack", k=len(pack)):
+            with stage_span("stream.dispatch_pack", seq=pack[0][0], k=len(pack)):
                 headers, payloads = self._dispatch_packed(
                     [(it[1], it[2], it[3], it[4], it[6], it[7]) for it in pack]
                 )
@@ -1057,12 +1058,25 @@ def run_train_stream(
             )
         pack.clear()
 
+    def _staged_get(timeout=None):
+        """The next staged item; None when ``timeout`` passed with nothing
+        staged. Time blocked on the empty queue is the dispatcher's wait."""
+        try:
+            return staged_q.get_nowait()
+        except _queue.Empty:
+            pass
+        with wait_span("stream.dispatch_get_wait"):
+            try:
+                return staged_q.get(timeout=timeout)
+            except _queue.Empty:
+                return None
+
     def _dispatch_pack_dense():
         """One dense-only K-step dispatch over feed-done items — a packed
         window is ONE dense stage of the graph."""
         nonlocal header
         with graph.lane("dense"):
-            with stage_span("stream.dispatch_pack", k=len(pack)):
+            with stage_span("stream.dispatch_pack", seq=pack[0][0], k=len(pack)):
                 with self._state_lock:
                     headers = self._dispatch_packed_dense(
                         [(it[1], it[2]) for it in pack]
@@ -1082,65 +1096,65 @@ def run_train_stream(
         pack.clear()
 
     try:
-        while True:
-            if pack:
-                # never hold a partial pack while the pipeline idles: the
-                # feeder may be parked on ring back-pressure waiting for
-                # write-backs that only exist once these steps dispatch
-                try:
-                    item = staged_q.get(timeout=0.05)
-                except _queue.Empty:
+        with accumulate(timing):
+            while True:
+                if pack:
+                    # never hold a partial pack while the pipeline idles: the
+                    # feeder may be parked on ring back-pressure waiting for
+                    # write-backs that only exist once these steps dispatch
+                    item = _staged_get(timeout=0.05)
+                    if item is None:
+                        _flush_pack_single()
+                        continue
+                else:
+                    item = _staged_get()
+                if item is SENTINEL:
                     _flush_pack_single()
+                    sentinel_drain(sentinel, sent_pending)
+                    if not errors:
+                        # end-of-stream drain: every feed's dense retired
+                        graph.drain_for_fence(self._global_step, reason="end")
+                    break
+                if errors:
+                    # buffered pack items carry no PS refs (_packable) — drop
+                    pack.clear()
+                    _abort_drained(item)
+                    break
+                if isinstance(item, tuple) and len(item) == 2 and item[0] == "fence":
+                    _flush_pack_single()
+                    # the sentinel must digest every pre-fence header BEFORE
+                    # the capture: a poisoned step must never become LAST_GOOD
+                    sentinel_drain(sentinel, sent_pending)
+                    # feeder parked + FIFO => the window is empty here; the
+                    # drain is asserted + recorded before the capture reads
+                    graph.drain_for_fence(item[1])
+                    _run_fence(item[1])
                     continue
-            else:
-                item = staged_q.get()
-            if item is SENTINEL:
+                if PIPE and K_eff > 1 and item[9]:  # feed_done: dense-only pack
+                    sig = _dense_sig(item)
+                    if pack and sig != pack_sig[0]:
+                        _flush_pack_single()
+                    if not pack:
+                        pack_sig[0] = sig
+                    pack.append(item)
+                    if len(pack) == K_eff:
+                        _dispatch_pack_dense()
+                    continue
+                if K > 1 and not PIPE and _packable(item):
+                    sig = _item_sig(item)
+                    if pack and sig != pack_sig[0]:
+                        _flush_pack_single()
+                    if not pack:
+                        pack_sig[0] = sig
+                    pack.append(item)
+                    if len(pack) == K:
+                        _dispatch_pack()
+                    continue
                 _flush_pack_single()
-                sentinel_drain(sentinel, sent_pending)
-                if not errors:
-                    # end-of-stream drain: every feed's dense retired
-                    graph.drain_for_fence(self._global_step, reason="end")
-                break
-            if errors:
-                # buffered pack items carry no PS refs (_packable) — drop
-                pack.clear()
-                _abort_drained(item)
-                break
-            if isinstance(item, tuple) and len(item) == 2 and item[0] == "fence":
-                _flush_pack_single()
-                # the sentinel must digest every pre-fence header BEFORE
-                # the capture: a poisoned step must never become LAST_GOOD
-                sentinel_drain(sentinel, sent_pending)
-                # feeder parked + FIFO => the window is empty here; the
-                # drain is asserted + recorded before the capture reads
-                graph.drain_for_fence(item[1])
-                _run_fence(item[1])
-                continue
-            if PIPE and K_eff > 1 and item[9]:  # feed_done: dense-only pack
-                sig = _dense_sig(item)
-                if pack and sig != pack_sig[0]:
-                    _flush_pack_single()
-                if not pack:
-                    pack_sig[0] = sig
-                pack.append(item)
-                if len(pack) == K_eff:
-                    _dispatch_pack_dense()
-                continue
-            if K > 1 and not PIPE and _packable(item):
-                sig = _item_sig(item)
-                if pack and sig != pack_sig[0]:
-                    _flush_pack_single()
-                if not pack:
-                    pack_sig[0] = sig
-                pack.append(item)
-                if len(pack) == K:
-                    _dispatch_pack()
-                continue
-            _flush_pack_single()
-            _dispatch_one(item)
+                _dispatch_one(item)
     finally:
         stats["wall_s"] = _time.perf_counter() - t_start
-        _publish_live_stats()
+        stats["feeder_busy_s"] = timing.busy_s("stream.prep")
         # per-tier layout + occupancy ride the stats dict so bench stream
         # records report EVERY tier, not just the active one's cache stats
         try:
@@ -1204,14 +1218,17 @@ def run_train_stream(
     if errors:
         raise RuntimeError("cached train pipeline failed") from errors[0]
     if header is not None:
-        if on_metrics is not None or fetch_final:
-            if on_metrics is None:
-                self._last_metrics = self._parse_header(
-                    np.asarray(header), label_shape
-                )
-            self._last_header_dev = None  # this stream is the freshest
-        else:
-            jax.block_until_ready(header)  # completion, no transfer
-            self._last_header_dev = (header, label_shape)
-            return None
+        # the device runs behind the dispatcher: what is left of its queue
+        # drains here
+        with accumulate(timing), wait_span("stream.drain"):
+            if on_metrics is not None or fetch_final:
+                if on_metrics is None:
+                    self._last_metrics = self._parse_header(
+                        np.asarray(header), label_shape
+                    )
+                self._last_header_dev = None  # this stream is the freshest
+            else:
+                jax.block_until_ready(header)  # completion, no transfer
+                self._last_header_dev = (header, label_shape)
+                return None
     return self._last_metrics

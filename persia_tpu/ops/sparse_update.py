@@ -142,19 +142,28 @@ def sparse_update(
     if batch_state is None:
         batch_state = jnp.ones((2,), dtype=jnp.float32)
     ids = ids.astype(jnp.int32)
-    uid, gsum, valid = dedup_gradients(ids, grads, mask)
-    w = table[uid]  # OOB sentinel rows clamp-gather; their deltas are dropped
-    st_rows = {k: v[uid] for k, v in state.items()}
-    new_w, new_st = _apply_rows(cfg, w, st_rows, gsum, batch_state)
-    vcol = valid[:, None]
-    table = table.at[uid].add(
-        jnp.where(vcol, new_w - w.astype(jnp.float32), 0.0).astype(table.dtype),
-        mode="drop",
-    )
-    out_state = {}
-    for k, full in state.items():
-        delta = jnp.where(vcol, new_st[k] - st_rows[k], 0.0)
-        out_state[k] = full.at[uid].add(delta.astype(full.dtype), mode="drop")
+    # named scopes: a device trace names each of this update's operations by
+    # the part it belongs to (PERF.md, "device ms by scope")
+    with jax.named_scope("sparse_update"):
+        with jax.named_scope("dedup"):
+            uid, gsum, valid = dedup_gradients(ids, grads, mask)
+        with jax.named_scope("row_update"):
+            with jax.named_scope("gather_rows"):
+                # OOB sentinel rows clamp-gather; their deltas are dropped
+                w = table[uid]
+                st_rows = {k: v[uid] for k, v in state.items()}
+            new_w, new_st = _apply_rows(cfg, w, st_rows, gsum, batch_state)
+            vcol = valid[:, None]
+            with jax.named_scope("scatter_table"):
+                table = table.at[uid].add(
+                    jnp.where(vcol, new_w - w.astype(jnp.float32), 0.0).astype(table.dtype),
+                    mode="drop",
+                )
+            out_state = {}
+            for k, full in state.items():
+                with jax.named_scope(f"scatter_{k}"):
+                    delta = jnp.where(vcol, new_st[k] - st_rows[k], 0.0)
+                    out_state[k] = full.at[uid].add(delta.astype(full.dtype), mode="drop")
     return table, out_state
 
 

@@ -33,6 +33,7 @@ from persia_tpu.parallel.fused_step import (
     init_fused_state,
 )
 from persia_tpu.parallel.train_step import _note_nonfinite_loss
+from persia_tpu.tracing import stage_span
 
 logger = get_default_logger("persia_tpu.fused_ctx")
 
@@ -138,6 +139,7 @@ class FusedTrainCtx:
             model, self.specs, self.slot_order, stack=stack
         )
         self.state: Optional[FusedTrainState] = None
+        self._steps = 0  # train_step calls so far: the ``seq`` its spans share
 
     # lifecycle ------------------------------------------------------------
 
@@ -160,9 +162,13 @@ class FusedTrainCtx:
     # training -------------------------------------------------------------
 
     def train_step(self, batch: PersiaBatch, fetch_metrics: bool = True) -> Dict:
-        fb = batch_to_fused(batch, self.specs, self.fold_ids)
+        seq = self._steps
+        self._steps += 1
+        with stage_span("fused.stage", seq=seq):
+            fb = batch_to_fused(batch, self.specs, self.fold_ids)
         self._ensure_state(fb)
-        self.state, (loss, preds) = self._step(self.state, fb)
+        with stage_span("fused.dispatch", seq=seq):
+            self.state, (loss, preds) = self._step(self.state, fb)
         self._last = (loss, preds)
         if not fetch_metrics:
             return {}

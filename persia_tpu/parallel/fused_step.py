@@ -39,6 +39,7 @@ from persia_tpu.ops.sparse_update import (
     sparse_update,
 )
 from persia_tpu.parallel.train_step import default_loss_fn
+from persia_tpu.tracing import stage_span
 
 
 @dataclass(frozen=True)
@@ -348,14 +349,18 @@ def build_fused_train_step(
 
     def step(state: FusedTrainState, batch: Dict):
         ids = batch["ids"]
-        gathered = (
-            _gather_all_stacked(state.tables, ids, groups)
-            if stack
-            else _gather_all(state.tables, ids)
-        )
+        # the named scopes are the cached step's (hbm_cache/step.py): one
+        # table of device time by scope compares the two
+        with jax.named_scope("gather"):
+            gathered = (
+                _gather_all_stacked(state.tables, ids, groups)
+                if stack
+                else _gather_all(state.tables, ids)
+            )
 
         def loss_wrapper(params, gathered):
-            model_emb = _model_inputs(specs, slot_order, gathered, ids)
+            with jax.named_scope("pool"):
+                model_emb = _model_inputs(specs, slot_order, gathered, ids)
             variables = {"params": params}
             if state.batch_stats:
                 variables["batch_stats"] = state.batch_stats
@@ -367,17 +372,19 @@ def build_fused_train_step(
             else:
                 logits = model.apply(variables, batch["dense"], model_emb, train=True)
                 new_stats = state.batch_stats
-            loss = loss_fn(logits, batch["labels"][0])
+            with jax.named_scope("loss"):
+                loss = loss_fn(logits, batch["labels"][0])
             return loss, (logits, new_stats)
 
         (loss, (logits, new_stats)), (param_grads, emb_grads) = jax.value_and_grad(
             loss_wrapper, argnums=(0, 1), has_aux=True
         )(state.params, gathered)
 
-        updates, new_opt_state = dense_optimizer.update(
-            param_grads, state.opt_state, state.params
-        )
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("dense_opt"):
+            updates, new_opt_state = dense_optimizer.update(
+                param_grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
 
         batch_state = state.emb_batch_state * jnp.array(
             [sparse_cfg.beta1, sparse_cfg.beta2], dtype=jnp.float32
@@ -386,32 +393,37 @@ def build_fused_train_step(
         if stack:
             for grp in groups:
                 idp, gp, mp = [], [], []
-                for name, off in zip(grp.slots, grp.offsets):
-                    i = ids[name]
-                    # ids outside the slot's own [0, vocab) are masked out,
-                    # matching the unstacked scatter's mode="drop" — they
-                    # must not write a neighboring slot's rows
-                    in_range = (i >= 0) & (i < specs[name].vocab)
-                    fi, fg, fm = masked_flat_ids_grads(
-                        jnp.where(in_range, i + off, -1),
-                        emb_grads[name].astype(jnp.float32),
-                    )
-                    idp.append(fi)
-                    gp.append(fg)
-                    mp.append(fm)
+                with jax.named_scope("sparse_prep"):
+                    for name, off in zip(grp.slots, grp.offsets):
+                        i = ids[name]
+                        # ids outside the slot's own [0, vocab) are masked
+                        # out, matching the unstacked scatter's mode="drop"
+                        # — they must not write a neighboring slot's rows
+                        in_range = (i >= 0) & (i < specs[name].vocab)
+                        fi, fg, fm = masked_flat_ids_grads(
+                            jnp.where(in_range, i + off, -1),
+                            emb_grads[name].astype(jnp.float32),
+                        )
+                        idp.append(fi)
+                        gp.append(fg)
+                        mp.append(fm)
+                    flat_ids = jnp.concatenate(idp) if len(idp) > 1 else idp[0]
+                    flat_g = jnp.concatenate(gp) if len(gp) > 1 else gp[0]
+                    flat_mask = jnp.concatenate(mp) if len(mp) > 1 else mp[0]
                 new_tables[grp.name], new_emb_state[grp.name] = sparse_update(
                     sparse_cfg,
                     state.tables[grp.name],
                     state.emb_state[grp.name],
-                    jnp.concatenate(idp) if len(idp) > 1 else idp[0],
-                    jnp.concatenate(gp) if len(gp) > 1 else gp[0],
+                    flat_ids,
+                    flat_g,
                     batch_state,
-                    mask=jnp.concatenate(mp) if len(mp) > 1 else mp[0],
+                    mask=flat_mask,
                 )
         else:
             for name in slot_order:
-                g = emb_grads[name].astype(jnp.float32)
-                flat_ids, flat_g, flat_mask = masked_flat_ids_grads(ids[name], g)
+                with jax.named_scope("sparse_prep"):
+                    g = emb_grads[name].astype(jnp.float32)
+                    flat_ids, flat_g, flat_mask = masked_flat_ids_grads(ids[name], g)
                 new_tables[name], new_emb_state[name] = sparse_update(
                     sparse_cfg,
                     state.tables[name],
@@ -614,7 +626,7 @@ class FusedPipeline:
                         seq, {}, {}, should_abort=lambda: bool(errors)
                     ):
                         break
-                    with graph.lane("feed"):
+                    with graph.lane("feed"), stage_span("fused.stage", seq=seq):
                         staged = stage(b)
                     q.put((seq, staged))
             except BaseException as e:  # noqa: BLE001 — reraised on the caller
@@ -633,14 +645,17 @@ class FusedPipeline:
                 nonlocal state
                 if not pack:
                     return
+                seq = pack[0][0]
                 if len(pack) > 1:
-                    with self.graph.lane("dense", k=len(pack)):
+                    with self.graph.lane("dense", k=len(pack)), stage_span(
+                        "fused.dispatch", seq=seq, k=len(pack)
+                    ):
                         state, (ls, _preds) = self._multi(
                             state, tuple(b for _, b in pack)
                         )
                     losses.extend(ls[i] for i in range(len(pack)))
                 else:
-                    with self.graph.lane("dense"):
+                    with self.graph.lane("dense"), stage_span("fused.dispatch", seq=seq):
                         state, (loss, _preds) = self._step(state, pack[0][1])
                     losses.append(loss)
                 graph.note_dense(pack[-1][0])

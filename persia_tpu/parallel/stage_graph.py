@@ -48,7 +48,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from persia_tpu.metrics import get_metrics
-from persia_tpu.tracing import record_event, stage_span
+from persia_tpu.tracing import StageAccumulator, accumulate, record_event, stage_span
 
 #: stage lanes of the hybrid step, in dataflow order
 STAGES = ("feed", "dense", "psgrad")
@@ -122,15 +122,16 @@ class StageGraph:
     dense stage, and ``depth == 1`` degenerates to the fully in-order
     pipeline. The stager thread appends via :meth:`reserve_feed` /
     barrier entries; the dispatch thread pops via :meth:`note_dense` after
-    each dense dispatch. Per-lane busy seconds (:meth:`lane`) feed the
-    ``stage.*`` span histograms and the ``stage_overlap_frac`` stat the
-    bench artifact records.
+    each dense dispatch. Lanes (:meth:`lane`) are ``stage.*`` spans; their
+    busy seconds, and those of every stage span opened inside one, are kept
+    by ``acc``, which the ``stage_overlap_frac`` stat the bench artifact
+    records is derived from.
     """
 
     def __init__(self, depth: int, clock=time.perf_counter):
         self.depth = max(1, int(depth))
-        self._clock = clock
-        # guards the window, the lane accounting, and the abort flag; a
+        self.acc = StageAccumulator(clock)
+        # guards the window and the abort flag; a
         # leaf-ish condition — nothing ranked is ever taken under it
         # (analysis/lock_order.py rank 1)
         self._pipe_cv = threading.Condition()
@@ -138,7 +139,6 @@ class StageGraph:
         self._aborted = False
         self.stalls = 0
         self.drains = 0
-        self._lane_busy: Dict[str, float] = {s: 0.0 for s in STAGES}
         self._rebuild_hooks: List[Callable[[int], None]] = []
         m = get_metrics()
         self._m_stalls = m.counter(
@@ -253,17 +253,11 @@ class StageGraph:
 
     @contextmanager
     def lane(self, stage: str, **attrs):
-        """Time a stage-lane occupancy: feeds the always-on ``stage.*``
-        histogram (tracing.stage_span) and the per-lane busy accounting
-        behind ``stage_overlap_frac``."""
-        t0 = self._clock()
-        try:
-            with stage_span(f"stage.{stage}", **attrs):
-                yield
-        finally:
-            dt = self._clock() - t0
-            with self._pipe_cv:
-                self._lane_busy[stage] = self._lane_busy.get(stage, 0.0) + dt
+        """Time a stage-lane occupancy: a ``stage.*`` span (tracing.
+        stage_span) accounted to this graph's accumulator, which is what
+        ``stage_wall_s`` and ``stage_overlap_frac`` are read from."""
+        with accumulate(self.acc), stage_span(f"stage.{stage}", **attrs):
+            yield
 
     def stats(self, wall_s: float) -> Dict:
         """Pipeline stats for the stream's stats dict / bench record.
@@ -271,8 +265,7 @@ class StageGraph:
         under other lanes: ``max(0, (sum(busy) - wall) / sum(busy))`` —
         0 when the lanes ran strictly serially, approaching 1 - 1/n_lanes
         at perfect overlap."""
-        with self._pipe_cv:
-            busy = dict(self._lane_busy)
+        busy = {s: self.acc.busy_s(f"stage.{s}") for s in STAGES}
         total = sum(busy.values())
         overlap = max(0.0, (total - wall_s) / total) if total > 0.0 else 0.0
         return {

@@ -32,9 +32,20 @@ Usage::
 
 Spans nest via a thread-local context stack; duration is also pushed to the
 metrics Histogram ``persia_stage_duration_seconds`` when metrics are enabled.
-A span on a disabled tracer is a strict no-op — hot paths pay ~nothing by
-default. The flight recorder is always on (its events are rare by
-construction); only the dump path needs arming.
+A span on a disabled tracer records nothing in the ring — hot paths pay
+~nothing by default. The flight recorder is always on (its events are rare
+by construction); only the dump path needs arming.
+
+Every span also opens a ``jax.profiler.TraceAnnotation`` of its name and
+attributes, so that a profiler session in this process (``jax.profiler.
+start_trace``) finds the program's stages as ``/host:CPU`` events on the
+device trace's own clock. The annotation is inert while no session runs
+(about half a microsecond) and there is no switch for it. The profiler is
+taken from ``sys.modules``: a role that never imported JAX does not import
+it for tracing. The two clocks differ by construction (the profiler counts
+from its session's start, the ring stamps ``time.time()``); a span seen in
+both, by name and ``seq``, is the anchor that places ring-only spans
+(:func:`record_span`) on the device trace.
 """
 
 from __future__ import annotations
@@ -163,36 +174,145 @@ def wire_headers() -> Dict[str, str]:
     return h
 
 
+# jax.profiler.TraceAnnotation, once this process has imported JAX
+_annotation = None
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None  # asked again at the next span: JAX may come later
+        try:
+            _annotation = jax.profiler.TraceAnnotation
+        except AttributeError:
+            return None  # JAX is still being imported
+    return _annotation
+
+
+class StageAccumulator:
+    """Totals of the stage spans closed on the threads bound to it
+    (:func:`accumulate`): ``stages[name] = {n, busy_s, max_s}`` for work
+    (:func:`stage_span`) and ``waits[name] = {n, wait_s, max_s}`` for
+    waits (:func:`wait_span`). A work span's busy time leaves out the
+    waits nested in it. ``clock`` is what the bound threads' stage spans
+    are timed by."""
+
+    def __init__(self, clock=time.perf_counter):
+        self.clock = clock
+        self._lock = threading.Lock()
+        self.stages: Dict[str, Dict[str, float]] = {}
+        self.waits: Dict[str, Dict[str, float]] = {}
+
+    def add(self, name: str, secs: float, wait: bool = False) -> None:
+        table, key = (self.waits, "wait_s") if wait else (self.stages, "busy_s")
+        with self._lock:
+            row = table.get(name)
+            if row is None:
+                row = table[name] = {"n": 0, key: 0.0, "max_s": 0.0}
+            row["n"] += 1
+            row[key] += secs
+            if secs > row["max_s"]:
+                row["max_s"] = secs
+
+    def busy_s(self, *names: str) -> float:
+        with self._lock:
+            return sum(self.stages[n]["busy_s"] for n in names if n in self.stages)
+
+
 @contextmanager
-def span(name: str, **attrs):
-    """Time a pipeline stage; logs at debug level, records for export."""
-    if not _enabled:
-        yield
-        return
-    st = _stack()
-    if st:
-        trace_id, parent = st[-1]
-    else:
-        trace_id, parent = _gen_id(16), None  # this span IS the edge
-    span_id = _gen_id(8)
-    st.append((trace_id, span_id))
-    t0 = time.perf_counter()
-    ts_us = time.time() * 1e6
+def accumulate(acc: Optional[StageAccumulator]):
+    """Bind ``acc`` to this thread: stage and wait spans closed on it
+    while bound add to ``acc``."""
+    prev = getattr(_tls, "acc", None)
+    _tls.acc = acc
     try:
-        yield
+        yield acc
     finally:
+        _tls.acc = prev
+
+
+_PLAIN, _STAGE, _WAIT = 0, 1, 2
+
+
+class _Span:
+    """The one span primitive. Every kind opens a profiler annotation and,
+    while the tracer is enabled, records itself in the ring; the stage
+    kinds also feed the stage histogram and the thread's accumulator."""
+
+    __slots__ = ("name", "attrs", "kind", "_ann", "_ring", "_acc", "_clock", "_t0", "_w0")
+
+    def __init__(self, name: str, attrs: Dict[str, Any], kind: int):
+        self.name, self.attrs, self.kind = name, attrs, kind
+
+    def __enter__(self):
+        ann = _trace_annotation()
+        if ann is not None:
+            ann = ann(self.name, **self.attrs)
+            ann.__enter__()
+        self._ann = ann
+        self._ring = None
+        if _enabled:
+            st = _stack()
+            if st:
+                trace_id, parent = st[-1]
+            else:
+                trace_id, parent = _gen_id(16), None  # this span IS the edge
+            span_id = _gen_id(8)
+            st.append((trace_id, span_id))
+            self._ring = (trace_id, span_id, parent, time.time() * 1e6)
+        if self.kind:
+            acc = self._acc = getattr(_tls, "acc", None)
+            self._clock = time.perf_counter if acc is None else acc.clock
+            self._w0 = getattr(_tls, "wait_s", 0.0)
+            self._t0 = self._clock()
+        elif self._ring is not None:
+            self._clock = time.perf_counter
+            self._t0 = self._clock()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self.kind or self._ring is not None:
+            dur = self._clock() - self._t0
+            if self.kind:
+                acc = self._acc
+                if self.kind == _WAIT:
+                    _tls.wait_s = self._w0 + dur
+                    if acc is not None:
+                        acc.add(self.name, dur, wait=True)
+                elif acc is not None:
+                    acc.add(self.name, dur - (getattr(_tls, "wait_s", 0.0) - self._w0))
+            if self._ring is not None:
+                self._record(dur)
+            h = _get_histogram()
+            if h:
+                h.observe(dur, stage=self.name)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the work is done (a count, the
+        slowest part): set before the span closes."""
+        self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
+
+    def _record(self, dur: float) -> None:
+        trace_id, span_id, parent, ts_us = self._ring
+        st = _stack()
         st.pop()
-        dur = time.perf_counter() - t0
-        logger.debug("%s%s took %.3f ms %s", "  " * len(st), name, dur * 1e3,
-                     attrs if attrs else "")
-        args = {k: str(v) for k, v in attrs.items()}
+        logger.debug("%s%s took %.3f ms %s", "  " * len(st), self.name,
+                     dur * 1e3, self.attrs if self.attrs else "")
+        args = {k: str(v) for k, v in self.attrs.items()}
         args["trace_id"] = trace_id
         args["span_id"] = span_id
         if parent:
             args["parent_id"] = parent
         with _lock:
             _spans.append({
-                "name": name,
+                "name": self.name,
                 "ph": "X",
                 "ts": ts_us,
                 "dur": dur * 1e6,
@@ -200,9 +320,11 @@ def span(name: str, **attrs):
                 "tid": threading.get_ident() % 2**31,
                 "args": args,
             })
-        h = _get_histogram()
-        if h:
-            h.observe(dur, stage=name)
+
+
+def span(name: str, **attrs) -> _Span:
+    """Time a pipeline stage; logs at debug level, records for export."""
+    return _Span(name, attrs, _PLAIN)
 
 
 def record_span(name: str, dur_s: float, **attrs) -> None:
@@ -238,41 +360,21 @@ def record_span(name: str, dur_s: float, **attrs) -> None:
         h.observe(dur_s, stage=name)
 
 
-@contextmanager
-def stage_span(name: str, **attrs):
+def stage_span(name: str, **attrs) -> _Span:
     """Pipeline-stage timer that ALWAYS feeds the live stage histogram
-    (``persia_stage_duration_seconds{stage=...}``) and records a trace span
+    (``persia_stage_duration_seconds{stage=...}``) and the accumulator
+    bound to the thread (:func:`accumulate`), and records a trace span
     only when tracing is enabled. The sanctioned replacement for hand-rolled
     ``t0 = time.time()`` stage timers in pipeline modules (persia-lint
     OBS002); the bench reads the same series the trace viewer shows."""
-    if _enabled:
-        with span(name, **attrs):
-            yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        h = _get_histogram()
-        if h:
-            h.observe(time.perf_counter() - t0, stage=name)
+    return _Span(name, attrs, _STAGE)
 
 
-def timed(name: Optional[str] = None):
-    """Decorator flavor of :func:`span`."""
-
-    def deco(fn):
-        label = name or fn.__qualname__
-
-        def wrapper(*a, **kw):
-            with span(label):
-                return fn(*a, **kw)
-
-        wrapper.__name__ = fn.__name__
-        wrapper.__qualname__ = fn.__qualname__
-        return wrapper
-
-    return deco
+def wait_span(name: str, **attrs) -> _Span:
+    """:func:`stage_span` for time a thread spends blocked on another
+    (a full or empty queue, a ring, the device): accounted as ``waits``
+    and left out of the busy time of the stage spans around it."""
+    return _Span(name, attrs, _WAIT)
 
 
 def spans_snapshot() -> list:

@@ -57,17 +57,6 @@ def test_span_survives_exception():
     assert tracing.spans_snapshot()[0]["name"] == "boom"
 
 
-def test_timed_decorator():
-    tracing.clear()
-
-    @tracing.timed("myfn")
-    def f(x):
-        return x + 1
-
-    assert f(1) == 2
-    assert tracing.spans_snapshot()[0]["name"] == "myfn"
-
-
 def test_disable_enable():
     tracing.clear()
     tracing.enable(False)
@@ -136,6 +125,19 @@ def test_mq_put_full_times_out():
 
 
 # ---------------------------------------------------------------- detector
+
+@pytest.fixture(autouse=True)
+def _own_heartbeats():
+    """The detector's registry is the process's: a component an earlier test
+    file of this worker left beating (the gateway's health loop) must not
+    read as a stall here."""
+    from persia_tpu import diagnostics
+
+    with diagnostics._lock:
+        diagnostics._beats.clear()
+        diagnostics._inflight.clear()
+    yield
+
 
 def test_stall_detector_flags_silent_component():
     det = StallDetector(stall_after_s=0.1)
