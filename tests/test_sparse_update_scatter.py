@@ -1,0 +1,235 @@
+"""The sparse update's row write-back: which rows it may touch, the indices
+it scatters at, and what it declares to XLA (ISSUE 27).
+
+(a) property tests over masked, unmasked and adversarial ids for every
+    optimizer: the scatter index is strictly ascending; table and state equal
+    a NumPy per-row loop to the parity tests' tolerance; rows no live id
+    names are bit-identical. Not bit for bit on the CPU: its compiler
+    contracts multiply-adds by the program's shape (this function at 8 and at
+    27 rows a trip differs in Adam's first moment by 9e-10). On the chip the
+    loop and the whole-N form it replaced are bit-equal (PERF.md, PR 27).
+(b) structural tests on the StableHLO of a jitted ``sparse_update``: the
+    flags on every scatter, and that the row scatters sit in the loop over
+    live rows.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from persia_tpu.embedding.optim import SGD, Adagrad, Adam
+from persia_tpu.ops import sparse_update as su
+
+INT32_MAX = np.iinfo(np.int32).max
+V, D = 37, 16
+
+OPTS = {
+    "sgd_wd": SGD(lr=0.1, weight_decay=0.01),
+    "adagrad": Adagrad(lr=0.05, g_square_momentum=0.95, weight_decay=0.01),
+    "adagrad_vw": Adagrad(lr=0.05, vectorwise_shared=True),
+    "adam": Adam(lr=0.01),
+}
+
+
+def _ids_cases():
+    """name -> (ids, mask or None). n is 1, below, at and past a chunk of
+    the row loop (the tests run it at 8 rows a trip), and not a multiple."""
+    rng = np.random.default_rng(11)
+    n = 27
+    plain = rng.integers(0, V, n)
+    adversarial = plain.copy()
+    adversarial[[0, 5, 9, 13, 20, 26]] = [-1, V, V + n, INT32_MAX, -V, V + 3]
+    some = rng.random(n) < 0.6
+    return {
+        "plain": (plain, None),
+        "masked": (plain, some),
+        "adversarial_unmasked": (adversarial, None),
+        "adversarial_masked": (adversarial, some),
+        "negative_masked_by_caller": (np.where(some, plain, -1), some),
+        "all_duplicate": (np.full(n, 7), None),
+        "all_masked": (plain, np.zeros(n, bool)),
+        "all_out_of_range": (np.full(n, V), None),
+        "all_distinct": (rng.permutation(V)[:n], None),
+        "n_1": (np.array([V - 1]), None),
+        "n_1_dead": (np.array([-1]), None),
+        "one_chunk": (plain[:8], None),
+        "two_chunks": (plain[:16], None),
+    }
+
+
+IDS_CASES = _ids_cases()
+
+
+@pytest.fixture(autouse=True)
+def small_chunks(monkeypatch):
+    # the loop's partial-chunk and several-trip paths at a test's size
+    monkeypatch.setattr(su, "_CHUNK_ROWS", 8)
+
+
+def _live(ids, mask):
+    live = (ids >= 0) & (ids < V)
+    return live if mask is None else live & mask
+
+
+def _inputs(cfg, ids, seed=3):
+    rng = np.random.default_rng(seed)
+    table = rng.normal(size=(V, D)).astype(np.float32)
+    state = {k: np.asarray(v) + rng.random(v.shape).astype(np.float32)
+             for k, v in su.init_sparse_state(cfg, V, D).items()}
+    grads = rng.normal(size=(len(ids), D)).astype(np.float32)
+    return table, state, grads
+
+
+def _numpy_row_loop(cfg, table, state, ids, grads, mask, batch_state):
+    """Per distinct live row: sum its gradients in input order, take the
+    row's new value from the optimizer's row math, add the difference."""
+    live = _live(ids, mask)
+    rows = sorted(set(ids[live].tolist()))
+    table = table.copy()
+    state = {k: v.copy() for k, v in state.items()}
+    for row in rows:
+        gsum = np.zeros(D, np.float32)
+        for i, g, ok in zip(ids, grads, live):
+            if ok and i == row:
+                gsum += g
+        w = table[row:row + 1]
+        st = {k: v[row:row + 1] for k, v in state.items()}
+        new_w, new_st = su._apply_rows(
+            cfg, jnp.asarray(w), {k: jnp.asarray(v) for k, v in st.items()},
+            jnp.asarray(gsum[None]), jnp.asarray(batch_state, jnp.float32))
+        table[row] += (np.asarray(new_w) - w)[0]
+        for k in state:
+            state[k][row] += (np.asarray(new_st[k]) - st[k])[0]
+    return table, state, rows
+
+
+def _run(cfg, table, state, ids, grads, mask, batch_state):
+    t, s = jax.jit(lambda t, s, i, g, b, m: su.sparse_update(cfg, t, s, i, g, b, mask=m))(
+        jnp.asarray(table), {k: jnp.asarray(v) for k, v in state.items()},
+        jnp.asarray(ids, jnp.int32), jnp.asarray(grads), jnp.asarray(batch_state, jnp.float32),
+        None if mask is None else jnp.asarray(mask))
+    return np.asarray(t), {k: np.asarray(v) for k, v in s.items()}
+
+
+@pytest.mark.parametrize("case", sorted(IDS_CASES))
+def test_scatter_index_strictly_ascending(case):
+    ids, mask = IDS_CASES[case]
+    n = len(ids)
+    live = _live(ids, mask)
+    uid, _gsum, valid = su.dedup_gradients(
+        jnp.asarray(ids, jnp.int32), jnp.zeros((n, D)), jnp.asarray(live))
+    sidx = np.asarray(su.scatter_indices(uid, valid, V)).astype(np.int64)
+    assert (np.diff(sidx) > 0).all(), sidx
+    rows = sorted(set(ids[live].tolist()))
+    np.testing.assert_array_equal(sidx[:len(rows)], rows)  # the live prefix: the rows, ascending
+    assert (sidx[len(rows):] >= V).all()  # sentinel and tail: out of range, dropped
+    assert int(np.asarray(valid).sum()) == len(rows)
+
+
+def test_scatter_index_must_fit_int32():
+    uid = jnp.zeros((4,), jnp.int32)
+    with pytest.raises(ValueError, match="overflows the int32"):
+        su.scatter_indices(uid, uid == 0, INT32_MAX - 3)
+    su.scatter_indices(uid, uid == 0, INT32_MAX - 4)
+
+
+def _check(cfg, ids, mask, batch_state):
+    table, state, grads = _inputs(cfg, ids)
+    got_t, got_s = _run(cfg, table, state, ids, grads, mask, batch_state)
+    ref_t, ref_s, rows = _numpy_row_loop(cfg, table, state, ids, grads, mask, batch_state)
+    np.testing.assert_allclose(got_t, ref_t, rtol=2e-5, atol=2e-6)
+    assert sorted(got_s) == sorted(ref_s)
+    for k in ref_s:
+        np.testing.assert_allclose(got_s[k], ref_s[k], rtol=2e-5, atol=2e-6)
+    # rows no live id names (masked, negative, out of range, never named)
+    # are bit-identical: table and state
+    untouched = [r for r in range(V) if r not in rows]
+    np.testing.assert_array_equal(got_t[untouched], table[untouched])
+    for k in state:
+        np.testing.assert_array_equal(got_s[k][untouched], state[k][untouched])
+    if rows:
+        assert np.abs(got_t[rows] - table[rows]).sum() > 0
+
+
+@pytest.mark.parametrize("case", sorted(IDS_CASES))
+@pytest.mark.parametrize("opt", sorted(OPTS))
+def test_matches_numpy_row_loop(opt, case):
+    cfg = OPTS[opt].config
+    _check(cfg, *IDS_CASES[case], (cfg.beta1 ** 3, cfg.beta2 ** 3))
+
+
+@pytest.mark.parametrize("chunk", [1, 5, 27, 1024])
+def test_any_chunk_size_writes_each_live_row_once(monkeypatch, chunk):
+    """One trip or twenty-seven, a last chunk that overlaps the one before
+    (27 rows at 5 a trip) or not."""
+    monkeypatch.setattr(su, "_CHUNK_ROWS", chunk)
+    cfg = OPTS["adam"].config
+    _check(cfg, *IDS_CASES["all_distinct"], (cfg.beta1, cfg.beta2))
+
+
+def test_bf16_table_keeps_its_dtype_and_untouched_rows():
+    cfg = OPTS["adagrad"].config
+    ids, mask = IDS_CASES["adversarial_masked"]
+    table, state, grads = _inputs(cfg, ids)
+    table16 = jnp.asarray(table, jnp.bfloat16)
+    got_t, _ = jax.jit(lambda t, s, i, g, m: su.sparse_update(cfg, t, s, i, g, mask=m))(
+        table16, {k: jnp.asarray(v) for k, v in state.items()},
+        jnp.asarray(ids, jnp.int32), jnp.asarray(grads), jnp.asarray(mask))
+    assert got_t.dtype == jnp.bfloat16
+    rows = sorted(set(ids[_live(ids, mask)].tolist()))
+    untouched = [r for r in range(V) if r not in rows]
+    np.testing.assert_array_equal(np.asarray(got_t)[untouched], np.asarray(table16)[untouched])
+
+
+# ------------------------------------------------- (b) what XLA is told
+
+def _scatters(cfg, n=27):
+    """(op name with its scopes, unique_indices, indices_are_sorted, inside a
+    while) of every stablehlo.scatter of a jitted sparse_update."""
+    state = su.init_sparse_state(cfg, V, D)
+    lowered = jax.jit(lambda t, s, i, g: su.sparse_update(cfg, t, s, i, g)).lower(
+        jnp.zeros((V, D)), state, jnp.zeros((n,), jnp.int32), jnp.zeros((n, D)))
+    found = []
+
+    def walk(op, in_while):
+        for region in op.regions:
+            for block in region.blocks:
+                for o in block.operations:
+                    name = o.operation.name
+                    if name == "stablehlo.scatter":
+                        found.append((str(o.location).split('"')[1],
+                                      str(o.attributes["unique_indices"]) == "true",
+                                      str(o.attributes["indices_are_sorted"]) == "true",
+                                      in_while))
+                    walk(o.operation, in_while or name == "stablehlo.while")
+
+    walk(lowered.compiler_ir("stablehlo").operation, False)
+    return found
+
+
+@pytest.mark.parametrize("opt,scopes", [
+    ("sgd_wd", ["scatter_table"]),
+    ("adagrad", ["scatter_table", "scatter_acc"]),
+    ("adagrad_vw", ["scatter_table", "scatter_acc"]),
+    ("adam", ["scatter_table", "scatter_m", "scatter_v"]),
+])
+def test_row_scatters_declare_unique_indices_inside_the_live_row_loop(opt, scopes):
+    found = _scatters(OPTS[opt].config)
+    rows = [f for f in found if "sparse_update/row_update/" in f[0]]
+    assert sorted(f[0].split("/")[-2] for f in rows) == sorted(scopes)
+    for name, unique, is_sorted, in_while in rows:
+        assert unique, f"{name}: unique_indices dropped"
+        # true, and not said: the v5e's scatter copies its whole operand
+        # every trip of the loop when it is (PERF.md, PR 27)
+        assert not is_sorted, f"{name}: indices_are_sorted costs 8 ms a trip on the v5e"
+        assert in_while, f"{name}: outside the loop over live rows, the dead tail runs again"
+
+
+def test_dedup_scatters_declare_sorted_segments():
+    found = _scatters(OPTS["adagrad"].config)
+    dedup = [f for f in found if "/dedup/" in f[0]]
+    assert len(dedup) == 2  # the segment sum and the uid scatter
+    for name, unique, is_sorted, in_while in dedup:
+        assert is_sorted and not unique and not in_while, name
+    assert len(found) == len(dedup) + 2
