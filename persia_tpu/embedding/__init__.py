@@ -4,12 +4,4 @@ sparse optimizers (ref: persia/embedding/ + rust/persia-embedding-server)."""
 from persia_tpu.config import HyperParameters as EmbeddingHyperParameters  # noqa: F401
 from persia_tpu.embedding.optim import SGD, Adagrad, Adam  # noqa: F401
 from persia_tpu.embedding.store import EmbeddingStore  # noqa: F401
-from persia_tpu.embedding.tpu_table import (  # noqa: F401
-    EmbeddingSpec,
-    create_table,
-    create_tables,
-    embedding_bag,
-    embedding_lookup,
-    lookup_all,
-)
 from persia_tpu.embedding.worker import EmbeddingWorker  # noqa: F401

@@ -332,6 +332,26 @@ def run_train_stream(
                     continue
         return False
 
+    def _take(q, wait_name: str, timeout=None):
+        """``_put``'s mirror, every stage's blocking take: the next item;
+        SENTINEL once the stream unwinds (``stop``/``errors``), whatever is
+        queued; None when ``timeout`` passed with nothing queued. No stage
+        needs to be handed an end mark in order to stop."""
+        if stop.is_set() or errors:
+            return SENTINEL
+        try:
+            return q.get_nowait()
+        except _queue.Empty:
+            pass
+        with wait_span(wait_name):  # blocked on the stage upstream
+            while not (stop.is_set() or errors):
+                try:
+                    return q.get(timeout=timeout or 0.5)
+                except _queue.Empty:
+                    if timeout:
+                        return None
+        return SENTINEL
+
     # dispatch/feeder accounting for the bench artifact (ctx.stream_stats):
     # regressions in the hot loop must be visible from the JSON alone
     stats = {
@@ -469,7 +489,7 @@ def run_train_stream(
             with cv:
                 cv.notify_all()
         finally:
-            prep_q.put(SENTINEL)
+            _put(prep_q, SENTINEL, "stream.prep_put_wait")  # the clean end
 
     def _pipe_abort() -> bool:
         return stop.is_set() or bool(errors)
@@ -486,7 +506,7 @@ def run_train_stream(
         dispatch path."""
         try:
             while True:
-                got = prep_q.get()
+                got = _take(prep_q, "stream.stage_get_wait")
                 if got is SENTINEL:
                     break
                 if isinstance(got, tuple) and got[0] == "fence":
@@ -574,7 +594,7 @@ def run_train_stream(
             with cv:
                 cv.notify_all()
         finally:
-            staged_q.put(SENTINEL)  # main's shutdown drain guarantees room
+            _put(staged_q, SENTINEL, "stream.stage_put_wait")  # the clean end
 
     # every device→host transfer pays a fixed round-trip whatever its size
     # (a small fetch measured 1.6 ms on the v5e, PR 21 chip_smoke), so the
@@ -687,6 +707,9 @@ def run_train_stream(
     def writeback():
         acc: List = []
         ps_acc: List = []
+        # the sink outlives `stop` and `errors` on purpose: it lands what was
+        # dispatched and keeps wb_q moving for its one producer, the caller,
+        # whose `finally` always hands it the end mark (no second taker)
         while True:
             try:
                 item = wb_q.get(timeout=0.25)
@@ -947,7 +970,7 @@ def run_train_stream(
                         )
         except BaseException:
             # the in-hand item is already off the queue: the shutdown
-            # drain in finally can't see it, so its staleness ref must
+            # sweep in finally can't see it, so its staleness ref must
             # be released HERE or it leaks
             if ps_item is not None:
                 try:
@@ -1058,19 +1081,6 @@ def run_train_stream(
             )
         pack.clear()
 
-    def _staged_get(timeout=None):
-        """The next staged item; None when ``timeout`` passed with nothing
-        staged. Time blocked on the empty queue is the dispatcher's wait."""
-        try:
-            return staged_q.get_nowait()
-        except _queue.Empty:
-            pass
-        with wait_span("stream.dispatch_get_wait"):
-            try:
-                return staged_q.get(timeout=timeout)
-            except _queue.Empty:
-                return None
-
     def _dispatch_pack_dense():
         """One dense-only K-step dispatch over feed-done items — a packed
         window is ONE dense stage of the graph."""
@@ -1102,23 +1112,22 @@ def run_train_stream(
                     # never hold a partial pack while the pipeline idles: the
                     # feeder may be parked on ring back-pressure waiting for
                     # write-backs that only exist once these steps dispatch
-                    item = _staged_get(timeout=0.05)
+                    item = _take(staged_q, "stream.dispatch_get_wait", timeout=0.05)
                     if item is None:
                         _flush_pack_single()
                         continue
                 else:
-                    item = _staged_get()
-                if item is SENTINEL:
-                    _flush_pack_single()
-                    sentinel_drain(sentinel, sent_pending)
-                    if not errors:
-                        # end-of-stream drain: every feed's dense retired
-                        graph.drain_for_fence(self._global_step, reason="end")
-                    break
+                    item = _take(staged_q, "stream.dispatch_get_wait")
                 if errors:
                     # buffered pack items carry no PS refs (_packable) — drop
                     pack.clear()
                     _abort_drained(item)
+                    break
+                if item is SENTINEL:
+                    _flush_pack_single()
+                    sentinel_drain(sentinel, sent_pending)
+                    # end-of-stream drain: every feed's dense retired
+                    graph.drain_for_fence(self._global_step, reason="end")
                     break
                 if isinstance(item, tuple) and len(item) == 2 and item[0] == "fence":
                     _flush_pack_single()
@@ -1192,29 +1201,26 @@ def run_train_stream(
         with cv:
             cv.notify_all()
 
-        # unblock stages stuck on full queues, then reap all threads
-        while feeder_t.is_alive() or dp_t.is_alive():
-            try:
-                _abort_drained(prep_q.get_nowait())
-            except _queue.Empty:
-                pass
-            try:
-                _abort_drained(staged_q.get(timeout=0.1))
-            except _queue.Empty:
-                pass
-        # final sweep AFTER the feeders died: on an error shutdown they
-        # exit on their own, leaving queued items whose PS forward refs
-        # would otherwise leak staleness slots
+        # every stage gives up on `stop` by itself (_put/_take/ring_alloc
+        # poll it); the write-back drains wb_q up to its end mark first
+        wb_q.put(SENTINEL)
+        threads = (feeder_t, dp_t, wb_t)
+        for t in threads:
+            t.join(timeout=300)
+        # sweep AFTER the feeders ended: what they left queued may hold PS
+        # forward refs, which would otherwise leak staleness slots
         for q in (prep_q, staged_q):
             while True:
                 try:
                     _abort_drained(q.get_nowait())
                 except _queue.Empty:
                     break
-        wb_q.put(SENTINEL)
-        feeder_t.join(timeout=300)
-        dp_t.join(timeout=300)
-        wb_t.join(timeout=300)
+        alive = [t.name for t in threads if t.is_alive()]
+        if alive:
+            err = RuntimeError(f"cached train pipeline: {alive} outlived join(300) after stop")
+            if errors:
+                raise err from errors[0]
+            raise err  # the caller's own failure, if one is in flight, stays its context
     if errors:
         raise RuntimeError("cached train pipeline failed") from errors[0]
     if header is not None:

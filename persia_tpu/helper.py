@@ -75,7 +75,7 @@ class ServiceCtx:
             return self._enter_impl()
         except BaseException:
             # __exit__ never runs if __enter__ raises: reap spawned services
-            self._teardown()
+            self._teardown(grace_s=0.0)
             raise
 
     def _enter_impl(self) -> "ServiceCtx":
@@ -682,19 +682,23 @@ class ServiceCtx:
     def __exit__(self, *exc):
         self._watchdog_stop.set()
         self._guard_stop.set()
+        relayed = False
         try:
             for client in self.worker_clients():
                 try:
                     client.shutdown(shutdown_servers=True)
+                    relayed = True
                 except Exception:
                     pass
         except Exception:
             pass
-        self._teardown()
+        # the workers relay the shutdown to the servers: with no worker nobody
+        # was asked to stop, and waiting for them to do so is time lost
+        self._teardown(grace_s=5.0 if relayed else 0.0)
         return False
 
-    def _teardown(self):
-        deadline = time.time() + 5
+    def _teardown(self, grace_s: float):
+        deadline = time.time() + grace_s
         for p in self.procs:
             try:
                 p.wait(timeout=max(0.1, deadline - time.time()))

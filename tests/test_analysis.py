@@ -706,14 +706,14 @@ def test_cli_exit_codes():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     ok = subprocess.run(
         [sys.executable, "-m", "persia_tpu.analysis"],
-        cwd=REPO_ROOT, env=env, capture_output=True, text=True,
+        cwd=REPO_ROOT, env=env, capture_output=True, text=True, timeout=240,
     )
     assert ok.returncode == 0, ok.stdout + ok.stderr
     assert "0 finding(s)" in ok.stdout
     bad = subprocess.run(
         [sys.executable, "-m", "persia_tpu.analysis", "--rules", "RES001",
          "--root", REPO_ROOT],
-        cwd=REPO_ROOT, env=env, capture_output=True, text=True,
+        cwd=REPO_ROOT, env=env, capture_output=True, text=True, timeout=240,
     )
     assert bad.returncode == 0  # clean tree stays clean under a filter too
 
@@ -724,7 +724,7 @@ def test_cli_json_is_machine_readable():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-m", "persia_tpu.analysis", "--json"],
-        cwd=REPO_ROOT, env=env, capture_output=True, text=True,
+        cwd=REPO_ROOT, env=env, capture_output=True, text=True, timeout=240,
     )
     assert out.returncode == 0, out.stdout + out.stderr
     doc = json.loads(out.stdout)
@@ -756,7 +756,7 @@ def test_cli_baseline_grandfathers_recorded_findings(tmp_path):
         return subprocess.run(
             [sys.executable, "-m", "persia_tpu.analysis",
              "--rules", "RES", "--root", str(root), *extra],
-            cwd=REPO_ROOT, env=env, capture_output=True, text=True,
+            cwd=REPO_ROOT, env=env, capture_output=True, text=True, timeout=240,
         )
 
     dirty = run()

@@ -73,7 +73,8 @@ def test_retry_jitter_replays_deterministically_across_threads():
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
     assert len(out) == n
     assert sorted(out) == sorted(ref)
     # and every sleep respects the jitter envelope [d/2, d]

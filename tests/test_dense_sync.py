@@ -262,7 +262,7 @@ def test_sharded_update_dp_invariant_large_n(n, tmp_path):
     env["JAX_PLATFORMS"] = "cpu"
     subprocess.run(
         [sys.executable, "-c", _DP_CHILD.format(root=root, n=n, out=out)],
-        check=True, env=env, cwd=root,
+        check=True, env=env, cwd=root, timeout=240,
     )
     pn = np.load(out)
     drift = np.abs(p8 - pn).max()

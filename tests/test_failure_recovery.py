@@ -37,29 +37,40 @@ def _slow_server(delay_s: float = 0.05) -> RpcServer:
 
 
 def test_pool_parallel_in_flight_scaling():
-    """N threads over one pooled client must drive N concurrent calls: with
-    a 50 ms handler, 8 calls from 8 threads take ~1 handler-delay, not 8
-    (the round-1 single-socket client serialized them)."""
-    srv = _slow_server(0.05)
+    """N threads over one pooled client must drive N concurrent calls (the
+    round-1 single-socket client serialized them). Each handler call holds
+    until all 8 are in the server at once: a client that serializes never
+    gets the second one there. No clock is compared, so a loaded machine
+    cannot fail it."""
+    n = 8
+    all_in = threading.Barrier(n)
+    srv = RpcServer(port=0)
+
+    def handler(payload: bytes) -> bytes:
+        all_in.wait(timeout=30)  # BrokenBarrierError -> an RpcError at the caller
+        return b"done"
+
+    srv.register("meet", handler)
+    srv.start()
     try:
-        client = RpcClient(f"127.0.0.1:{srv.port}", pool_size=8)
+        client = RpcClient(f"127.0.0.1:{srv.port}", pool_size=n)
         client.call("ping")  # warm one connection
+        replies, errors = [], []
 
-        def run_n(n):
-            threads = []
-            t0 = time.perf_counter()
-            for _ in range(n):
-                t = threading.Thread(target=lambda: client.call("slow"))
-                threads.append(t)
-                t.start()
-            for t in threads:
-                t.join()
-            return time.perf_counter() - t0
+        def call():
+            try:
+                replies.append(client.call("meet", timeout_s=60.0))
+            except Exception as e:  # noqa: BLE001 — reported below
+                errors.append(e)
 
-        t1 = run_n(1)
-        t8 = run_n(8)
-        # serialized would be ~8×t1; parallel is ~t1 (+ thread overhead)
-        assert t8 < 4 * t1, f"pool did not parallelize: 1 call {t1:.3f}s, 8 calls {t8:.3f}s"
+        threads = [threading.Thread(target=call) for _ in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=90)
+        assert not any(t.is_alive() for t in threads)
+        assert not errors, f"pool did not parallelize: {errors[:2]}"
+        assert replies == [b"done"] * n
     finally:
         srv.stop()
 
@@ -74,7 +85,8 @@ def test_pool_bounds_connections_and_recovers_broken():
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
         assert client._total <= 2
         # break every pooled socket; next call must transparently reconnect
         with client._cond:

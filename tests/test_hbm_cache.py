@@ -1161,11 +1161,9 @@ def test_all_ps_stream_trains_and_releases_refs():
     assert any((a > 0.0501).any() for a in accs)
 
 
-def test_stream_dispatch_failure_releases_in_hand_ps_ref():
-    """A _dispatch failure on the MAIN thread must release the in-hand
-    item's PS-tier forward ref: that item is already off staged_q, so the
-    shutdown drain can't see it — the main loop's own except must abort it
-    (regression: the leak left worker.staleness stuck >0 forever)."""
+def _all_ps_ctx():
+    """A cached context whose three slots all sit on the PS tier, so every
+    step of a stream carries a forward ref; returns ``(ctx, worker)``."""
     import optax
 
     from persia_tpu.models import DNN
@@ -1185,6 +1183,15 @@ def test_stream_dispatch_failure_releases_in_hand_ps_ref():
         cache_rows=8,
         ps_slots=["cat_a", "cat_b", "cat_c"],  # all-PS: every step has a ref
     )
+    return ctx, worker
+
+
+def test_stream_dispatch_failure_releases_in_hand_ps_ref():
+    """A _dispatch failure on the MAIN thread must release the in-hand
+    item's PS-tier forward ref: that item is already off staged_q, so the
+    shutdown sweep can't see it — the main loop's own except must abort it
+    (regression: the leak left worker.staleness stuck >0 forever)."""
+    ctx, worker = _all_ps_ctx()
     calls = {"n": 0}
     orig = ctx._dispatch
 
@@ -1200,6 +1207,109 @@ def test_stream_dispatch_failure_releases_in_hand_ps_ref():
         ctx.train_stream(_batches(10, seed=6), prefetch=3, psgrad_batch=4)
     assert worker.staleness == 0
     assert not worker.post_forward_buffer
+
+
+def test_stream_dispatch_failure_with_stager_parked_returns():
+    """A _dispatch failure while prep_q is empty and the stager is parked in
+    its take: the stream must come back with the injected error, every time.
+    (Regression: the shutdown drain took the feeder's end mark off prep_q
+    ahead of the stager, which then waited for ever on a mark that was gone,
+    and the caller polled staged_q without end.) Threads that hog the GIL
+    with a long switch interval stand in for a machine with few cores: they
+    delay the woken stager so that another taker could get there first."""
+    import sys
+    import threading
+    import time
+
+    ctx, worker = _all_ps_ctx()
+    batches = list(_batches(4, seed=6))
+    ctx.train_stream(iter(batches[:3]), prefetch=3, psgrad_batch=4)  # compile
+    orig = ctx._dispatch
+    spin_on, spin_end = threading.Event(), threading.Event()
+
+    def spin():
+        while not spin_end.is_set():
+            if spin_on.wait(0.05):
+                for _ in range(1000):
+                    pass
+
+    spinners = [threading.Thread(target=spin, daemon=True) for _ in range(4)]
+    for t in spinners:
+        t.start()
+    switch = sys.getswitchinterval()
+    try:
+        for trial in range(12):
+            raised = threading.Event()
+            calls = {"n": 0}
+
+            def failing(*a, **kw):
+                calls["n"] += 1
+                if calls["n"] >= 3:
+                    raised.set()
+                    raise RuntimeError("injected dispatch failure")
+                return orig(*a, **kw)
+
+            def fourth_held_back():
+                yield from batches[:3]
+                # all three are through the stager by now: prep_q is empty
+                assert raised.wait(30)
+                spin_on.set()
+                time.sleep(trial * 0.01)  # sweep the caller's poll phase
+                yield batches[3]
+
+            ctx._dispatch = failing
+            sys.setswitchinterval(0.005)
+            t0 = time.perf_counter()
+            with pytest.raises(RuntimeError, match="injected dispatch failure"):
+                ctx.train_stream(fourth_held_back(), prefetch=3, psgrad_batch=4)
+            took = time.perf_counter() - t0
+            sys.setswitchinterval(switch)
+            spin_on.clear()
+            assert took < 5.0, f"trial {trial}: the error shutdown took {took:.1f}s"
+            assert worker.staleness == 0
+            assert not worker.post_forward_buffer
+    finally:
+        sys.setswitchinterval(switch)
+        spin_end.set()
+        spin_on.set()
+        for t in spinners:
+            t.join(timeout=10)
+    assert not any(t.is_alive() for t in spinners)
+
+
+def test_stream_thread_that_outlives_its_join_raises_by_name(monkeypatch):
+    """A stage that cannot see ``stop`` (here the feeder, inside the caller's
+    own batch iterator) outlives its join: the stream says which thread, and
+    keeps the failure that began the shutdown as the context."""
+    import threading
+
+    ctx, _worker = _all_ps_ctx()
+    release = threading.Event()
+    feeder = []
+
+    def stuck_after_two():
+        yield from _batches(2, seed=6)
+        feeder.append(threading.current_thread())
+        release.wait(60)
+
+    def failing(*a, **kw):
+        raise RuntimeError("injected dispatch failure")
+
+    ctx._dispatch = failing
+    join = threading.Thread.join
+    # the stream's join(300), cut to what a test can wait for
+    monkeypatch.setattr(
+        threading.Thread, "join",
+        lambda t, timeout=None: join(t, 0.5 if t.name.startswith("cache-") else timeout),
+    )
+    try:
+        with pytest.raises(RuntimeError, match=r"\['cache-feeder'\] outlived join") as ei:
+            ctx.train_stream(stuck_after_two(), prefetch=3, psgrad_batch=4)
+        assert "injected dispatch failure" in str(ei.value.__context__)
+    finally:
+        release.set()
+    join(feeder[0], 30)
+    assert not feeder[0].is_alive()
 
 
 def test_mixed_tier_requires_prefix_bit():

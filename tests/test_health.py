@@ -177,7 +177,7 @@ def test_data_loader_feed_quarantines(tmp_path):
     dl._feed(out)
     ids = []
     while True:
-        item = out.get()
+        item = out.get(timeout=60)
         if not isinstance(item, PersiaBatch):
             break
         ids.append(item.batch_id)

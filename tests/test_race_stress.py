@@ -179,7 +179,7 @@ def _run_threads(workers):
     def wrap(fn):
         def run():
             try:
-                barrier.wait()
+                barrier.wait(timeout=60)
                 fn()
             except BaseException as e:  # noqa: BLE001 - reported below
                 errors.append(e)
@@ -360,12 +360,14 @@ def test_sketch_observe_vs_decay_stats_export(cache_lib):
             try:
                 fn()
             finally:
-                obs_done.wait()
+                obs_done.wait(timeout=120)
         return run
 
     def closer():
-        obs_done.wait()
-        stop.set()
+        try:
+            obs_done.wait(timeout=120)
+        finally:  # a broken barrier must still end the fencer and exporter
+            stop.set()
 
     _run_threads(
         [obs_group(o) for o in observers] + [closer, fencer, exporter]
@@ -512,7 +514,8 @@ lib.canary_bump.argtypes = [ctypes.c_int64]
 ts = [threading.Thread(target=lib.canary_bump, args=(3_000_000,))
       for _ in range(4)]
 [t.start() for t in ts]
-[t.join() for t in ts]
+[t.join(120) for t in ts]
+assert not any(t.is_alive() for t in ts)
 print("canary done")
 """
 
