@@ -12,7 +12,9 @@ same per-unique-row math (`persia_tpu/embedding/optim.py` — SGD / Adagrad
    accumulation),
 2. gather the touched rows + optimizer state,
 3. apply the optimizer math on the (N, dim) block,
-4. scatter-add the deltas back at strictly ascending, distinct indices.
+4. write the new rows back at strictly ascending, distinct indices: row by
+   row by DMA where the array's layout allows (``_row_write_path``), else
+   scatter-added as deltas.
 
 Steps 2-4 run a chunk of rows a trip in a loop over the live rows only: the
 invalid tail of the static N positions is dropped, not executed.
@@ -29,6 +31,8 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from persia_tpu.embedding.optim import (
     OPTIMIZER_ADAGRAD,
@@ -36,6 +40,7 @@ from persia_tpu.embedding.optim import (
     OPTIMIZER_SGD,
     OptimizerConfig,
 )
+from persia_tpu.tracing import record_event
 
 
 def init_sparse_state(cfg: OptimizerConfig, vocab: int, dim: int) -> Dict[str, jnp.ndarray]:
@@ -163,7 +168,7 @@ def sparse_update(
     gathered, updated and written back exactly once, ``_CHUNK_ROWS`` rows at
     a time in a loop that ends with the last live row: the dead tail of the
     static N positions is never executed, and what a last, partly live
-    chunk holds of it carries indices ``>= V`` that the scatters drop. Rows
+    chunk holds of it carries indices ``>= V`` that the write-back drops. Rows
     only touched with zero effective delta are bit-identical unchanged.
     """
     if batch_state is None:
@@ -185,10 +190,12 @@ def sparse_update(
     return table, state
 
 
-# Rows a trip of the row-update loop handles. On the v5e a scatter costs
-# 75-80 ns an update whether the update lands or is dropped, so the loop's
-# trip count (the live rows, not the static N) is what the step pays for;
-# 512 to 2048 rows a trip read within 0.3 ms of each other (PERF.md, PR 27).
+# Rows a trip of the row-update loop handles. Either write-back pays by the
+# position: the v5e's scatter 75-80 ns an update whether it lands or is
+# dropped, the row-write kernel one DMA descriptor a live row and a scalar
+# test a dead one. So the loop's trip count (the live rows, not the static N)
+# is what the step pays for; 512 to 2048 rows a trip read within 0.3 ms of
+# each other with the scatter (PERF.md, PR 27) and with the kernel (PR 29).
 _CHUNK_ROWS = 1024
 
 
@@ -196,10 +203,26 @@ def _update_live_rows(cfg, table, state, sidx, gsum, n_live, batch_state):
     """Gather, optimizer math and write-back for the first ``n_live`` of
     ``scatter_indices``' N positions, a chunk a trip. A chunk that would run
     past N starts at N - chunk instead and sends the positions an earlier
-    trip already wrote out of range, so no row is added to twice."""
+    trip already wrote out of range, so no row is written twice."""
     n = sidx.shape[0]
     vocab = table.shape[0]
     chunk = min(_CHUNK_ROWS, n)
+    paths = {}
+    for name, full in {"table": table, **state}.items():
+        paths[name] = _row_write_path(full)
+        # once a traced sparse_update and array: which write-back it was built with
+        record_event("sparse_update.row_write", array=name, path=paths[name], rows=full.shape[0],
+                     dim=full.shape[1], dtype=full.dtype.name, chunk=chunk)
+
+    def write(name, full, idx, old, new):
+        """``full[idx] = old + (new - old)`` in ``full``'s dtype: the value a
+        scatter-add of the delta leaves there, whichever path writes it."""
+        delta = (new - old.astype(jnp.float32)).astype(full.dtype)
+        if paths[name] == "dma":
+            with jax.named_scope(f"write_{name}"):
+                return _write_rows_dma(full, idx, old + delta)
+        with jax.named_scope(f"scatter_{name}"):
+            return _scatter_add_rows(full, idx, delta)
 
     def body(trip, carry):
         table, state = carry
@@ -214,13 +237,9 @@ def _update_live_rows(cfg, table, state, sidx, gsum, n_live, batch_state):
             w = table[rows]
             st_rows = {k: v[rows] for k, v in state.items()}
         new_w, new_st = _apply_rows(cfg, w, st_rows, g, batch_state)
-        with jax.named_scope("scatter_table"):
-            table = _scatter_add_rows(table, idx, new_w - w.astype(jnp.float32))
-        out_state = {}
-        for k, full in state.items():
-            with jax.named_scope(f"scatter_{k}"):
-                out_state[k] = _scatter_add_rows(full, idx, new_st[k] - st_rows[k])
-        return table, out_state
+        table = write("table", table, idx, w, new_w)
+        state = {k: write(k, full, idx, st_rows[k], new_st[k]) for k, full in state.items()}
+        return table, state
 
     return jax.lax.fori_loop(0, (n_live + chunk - 1) // chunk, body, (table, state))
 
@@ -232,6 +251,98 @@ def _scatter_add_rows(full: jnp.ndarray, idx: jnp.ndarray, delta: jnp.ndarray) -
     third more outside a loop and a copy of the whole operand a trip inside
     one (PERF.md, PR 27)."""
     return full.at[idx].add(delta.astype(full.dtype), mode="drop", unique_indices=True)
+
+
+def _backend() -> Tuple[str, int]:
+    """(platform, devices) the step is being traced for."""
+    return jax.default_backend(), jax.device_count()
+
+
+def _row_write_path(full: jnp.ndarray) -> str:
+    """``"dma"`` where ``_write_rows_dma`` can write ``full``'s rows, else
+    ``"scatter"``. The kernel needs a row that is contiguous 512 B pieces in
+    the TPU's (8, 128) tiling of 32-bit words: float32 and a whole number of
+    128-lane vectors. bfloat16 packs two rows to a sublane, and a (V, 1) or
+    dim-16 row is a sliver of a tile. It needs one device too: GSPMD cannot
+    partition a custom call, so a process that sees several devices (tables
+    row-sharded by ``shard_fused_state``, pools on the cached tier's
+    ``data`` mesh) keeps the compiler's scatter, as every CPU run does."""
+    platform, devices = _backend()
+    contiguous_rows = full.dtype == jnp.float32 and full.shape[1] % 128 == 0
+    return "dma" if platform == "tpu" and devices == 1 and contiguous_rows else "scatter"
+
+
+# Positions a trip of the kernel's loop handles: unrolled by hand, since
+# Mosaic unrolls a ``fori_loop`` whole or not at all (40 ns a row at 1, 27 at
+# 8, 25 at 16 with a wait a row; PERF.md, PR 29).
+_DMA_UNROLL = 8
+
+
+def _write_rows_dma(
+    full: jnp.ndarray, idx: jnp.ndarray, rows: jnp.ndarray, *, interpret: bool = False
+) -> jnp.ndarray:
+    """``full[idx] = rows`` in place, a row a DMA, for a slice of
+    ``scatter_indices``' output; positions whose index is ``>= V`` are
+    skipped (what ``mode="drop"`` does for the scatter).
+
+    full (V, D) stays in HBM and is aliased to the output; idx (C,) int32 is
+    scalar-prefetched; rows (C, D), of ``full``'s dtype, is one VMEM block.
+    Every live position starts its own asynchronous copy of one (1, D) row on
+    one DMA semaphore, and all of a chunk's copies are started before any is
+    waited for: nothing orders them. That is legal only because the live
+    indices are DISTINCT: ``scatter_indices``' contract. Two positions naming
+    one row would race. The semaphore counts what has arrived, so the copies
+    are waited for by their number and not one by one: a wait for 2**b rows'
+    worth for every set bit b of the live count (a wait a row costs 7 ns a
+    row more; PERF.md, PR 29).
+
+    Compiled by Mosaic unless the caller passes ``interpret=True`` (the CPU
+    tests do); ``_row_write_path`` says where the compiled kernel applies."""
+    vocab, dim = full.shape
+    chunk = idx.shape[0]
+    unroll = _DMA_UNROLL if chunk % _DMA_UNROLL == 0 else 1
+
+    def kernel(idx_ref, rows_ref, full_ref, out_ref, sem):
+        del full_ref  # the same buffer as out_ref
+
+        def start_live(trip, n_started):
+            for k in range(unroll):
+                i = trip * unroll + k
+                row = idx_ref[i]
+                live = row < vocab
+
+                @pl.when(live)
+                def _(i=i, row=row):
+                    pltpu.make_async_copy(
+                        rows_ref.at[pl.ds(i, 1)], out_ref.at[pl.ds(row, 1)], sem).start()
+
+                n_started = n_started + live.astype(jnp.int32)
+            return n_started
+
+        n_started = jax.lax.fori_loop(0, chunk // unroll, start_live, jnp.int32(0))
+        for bit in range(chunk.bit_length()):
+            # never started: its size is what the wait takes off the semaphore
+            block = rows_ref.at[pl.ds(0, 1 << bit)]
+            rows_worth = pltpu.make_async_copy(block, block, sem)
+            pl.when((n_started >> bit) & 1 == 1)(rows_worth.wait)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(full.shape, full.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1,),
+            in_specs=[
+                pl.BlockSpec((chunk, dim), lambda _, idx_ref: (0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        ),
+        input_output_aliases={2: 0},  # operand 0 is idx, 1 rows, 2 full
+        interpret=interpret,
+        name="sparse_row_write",
+    )(idx, rows, full)
 
 
 def masked_flat_ids_grads(

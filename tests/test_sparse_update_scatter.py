@@ -1,5 +1,6 @@
 """The sparse update's row write-back: which rows it may touch, the indices
-it scatters at, and what it declares to XLA (ISSUE 27).
+it writes at, what it declares to XLA (ISSUE 27), and the row-write kernel
+that takes the scatter's place where the layout allows (ISSUE 29).
 
 (a) property tests over masked, unmasked and adversarial ids for every
     optimizer: the scatter index is strictly ascending; table and state equal
@@ -11,13 +12,23 @@ it scatters at, and what it declares to XLA (ISSUE 27).
 (b) structural tests on the StableHLO of a jitted ``sparse_update``: the
     flags on every scatter, and that the row scatters sit in the loop over
     live rows.
+(c) the row-write kernel (``_write_rows_dma``) through the Pallas TPU
+    interpreter, which this file asks for itself: alone against a NumPy row
+    assignment, and inside ``sparse_update`` with the DMA path forced, over
+    (a)'s grid: equal to the NumPy row loop and bit-equal to the scatter path
+    on the same inputs.
+(d) which path each array gets (``_row_write_path``) and the flight event
+    ``sparse_update.row_write`` that says so.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from persia_tpu import tracing
 from persia_tpu.embedding.optim import SGD, Adagrad, Adam
 from persia_tpu.ops import sparse_update as su
 
@@ -72,12 +83,12 @@ def _live(ids, mask):
     return live if mask is None else live & mask
 
 
-def _inputs(cfg, ids, seed=3):
+def _inputs(cfg, ids, seed=3, dim=D):
     rng = np.random.default_rng(seed)
-    table = rng.normal(size=(V, D)).astype(np.float32)
+    table = rng.normal(size=(V, dim)).astype(np.float32)
     state = {k: np.asarray(v) + rng.random(v.shape).astype(np.float32)
-             for k, v in su.init_sparse_state(cfg, V, D).items()}
-    grads = rng.normal(size=(len(ids), D)).astype(np.float32)
+             for k, v in su.init_sparse_state(cfg, V, dim).items()}
+    grads = rng.normal(size=(len(ids), dim)).astype(np.float32)
     return table, state, grads
 
 
@@ -89,7 +100,7 @@ def _numpy_row_loop(cfg, table, state, ids, grads, mask, batch_state):
     table = table.copy()
     state = {k: v.copy() for k, v in state.items()}
     for row in rows:
-        gsum = np.zeros(D, np.float32)
+        gsum = np.zeros(grads.shape[1], np.float32)
         for i, g, ok in zip(ids, grads, live):
             if ok and i == row:
                 gsum += g
@@ -134,8 +145,9 @@ def test_scatter_index_must_fit_int32():
     su.scatter_indices(uid, uid == 0, INT32_MAX - 4)
 
 
-def _check(cfg, ids, mask, batch_state):
-    table, state, grads = _inputs(cfg, ids)
+def _check(cfg, ids, mask, batch_state, dim=D):
+    """Returns what the run was given and what it gave, for a second look."""
+    table, state, grads = _inputs(cfg, ids, dim=dim)
     got_t, got_s = _run(cfg, table, state, ids, grads, mask, batch_state)
     ref_t, ref_s, rows = _numpy_row_loop(cfg, table, state, ids, grads, mask, batch_state)
     np.testing.assert_allclose(got_t, ref_t, rtol=2e-5, atol=2e-6)
@@ -150,6 +162,7 @@ def _check(cfg, ids, mask, batch_state):
         np.testing.assert_array_equal(got_s[k][untouched], state[k][untouched])
     if rows:
         assert np.abs(got_t[rows] - table[rows]).sum() > 0
+    return (table, state, grads), (got_t, got_s)
 
 
 @pytest.mark.parametrize("case", sorted(IDS_CASES))
@@ -233,3 +246,137 @@ def test_dedup_scatters_declare_sorted_segments():
     for name, unique, is_sorted, in_while in dedup:
         assert is_sorted and not unique and not in_while, name
     assert len(found) == len(dedup) + 2
+
+
+# ------------------------------------------------- (c) the row-write kernel
+
+D_DMA = 128  # a row the kernel takes: one 128-lane vector of float32
+
+
+# the interpreter is this file's choice, as in tests/test_flash_attention.py
+_write_rows_interpreted = functools.partial(su._write_rows_dma, interpret=True)
+
+
+@pytest.fixture
+def dma_path(monkeypatch):
+    """What a one-chip TPU process sees, with the kernel interpreted: every
+    float32 array whose row is a multiple of 128 lanes goes through
+    ``_write_rows_dma``."""
+    monkeypatch.setattr(su, "_backend", lambda: ("tpu", 1))
+    monkeypatch.setattr(su, "_write_rows_dma", _write_rows_interpreted)
+    tracing.flight_clear()
+
+
+def _row_write_events():
+    return [e["attrs"] for e in tracing.flight_snapshot() if e["kind"] == "sparse_update.row_write"]
+
+
+@pytest.mark.parametrize("chunk,vocab", [(1, 2048), (5, 2048), (8, 2048), (27, 2048), (1024, 2048), (64, 40)])
+@pytest.mark.parametrize("live", ["none", "some", "all"])
+def test_kernel_writes_the_live_rows_and_no_other(chunk, vocab, live):
+    """Positions whose index is >= V are skipped wherever they sit in the
+    chunk; every other row of ``full`` keeps its bits. 1, 5 and 27 rows take
+    the kernel's plain loop, 8, 64 and 1024 the unrolled one; the last case
+    is a table shorter than the chunk."""
+    rng = np.random.default_rng(chunk)
+    full = rng.normal(size=(vocab, D_DMA)).astype(np.float32)
+    rows = rng.normal(size=(chunk, D_DMA)).astype(np.float32)
+    share = {"none": 0.0, "some": 0.6, "all": 1.0}[live]
+    at = np.sort(rng.permutation(chunk)[:int(share * min(chunk, vocab) + 0.5)])  # the live positions
+    idx = vocab + np.arange(chunk, dtype=np.int32)
+    idx[at] = np.sort(rng.permutation(vocab)[:len(at)])
+    got = jax.jit(_write_rows_interpreted)(jnp.asarray(full), jnp.asarray(idx), jnp.asarray(rows))
+    want = full.copy()
+    want[idx[at]] = rows[at]
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_kernel_compiles_unless_asked_to_interpret():
+    """No caller gets the interpreter without asking: off the TPU the
+    default (compile with Mosaic) refuses."""
+    with pytest.raises(ValueError, match="interpret mode"):
+        jax.jit(su._write_rows_dma)(jnp.zeros((16, D_DMA)), jnp.arange(8, dtype=jnp.int32),
+                                    jnp.ones((8, D_DMA)))
+
+
+@pytest.mark.parametrize("case", sorted(IDS_CASES))
+@pytest.mark.parametrize("opt", sorted(OPTS))
+def test_dma_path_matches_numpy_row_loop_and_the_scatter_path_bit_for_bit(opt, case, dma_path):
+    """(a)'s grid with the kernel writing the rows: a partly live last chunk
+    (``plain``: 27 positions at 8 a trip), zero live rows (``all_masked``,
+    ``all_out_of_range``, ``n_1_dead``), all ids distinct."""
+    cfg = OPTS[opt].config
+    ids, mask = IDS_CASES[case]
+    batch_state = (cfg.beta1 ** 3, cfg.beta2 ** 3)
+    (table, state, grads), (dma_t, dma_s) = _check(cfg, ids, mask, batch_state, dim=D_DMA)
+    events = _row_write_events()
+    # vectorwise_shared Adagrad keeps the scatter for its (V, 1) accumulator
+    want = {"table": "dma", **{k: "dma" if v.shape[1] == D_DMA else "scatter" for k, v in state.items()}}
+    assert {e["array"]: e["path"] for e in events} == want
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(su, "_backend", lambda: ("cpu", 1))
+        sc_t, sc_s = _run(cfg, table, state, ids, grads, mask, batch_state)
+    assert {e["path"] for e in _row_write_events()[len(events):]} == {"scatter"}
+    np.testing.assert_array_equal(dma_t, sc_t)
+    for k in sc_s:
+        np.testing.assert_array_equal(dma_s[k], sc_s[k])
+
+
+@pytest.mark.parametrize("chunk", [1, 5, 27, 1024])
+def test_dma_path_any_chunk_size_writes_each_live_row_once(monkeypatch, dma_path, chunk):
+    monkeypatch.setattr(su, "_CHUNK_ROWS", chunk)
+    cfg = OPTS["adam"].config
+    _check(cfg, *IDS_CASES["all_distinct"], (cfg.beta1, cfg.beta2), dim=D_DMA)
+    assert [(e["path"], e["chunk"]) for e in _row_write_events()] == [("dma", str(min(chunk, 27)))] * 3
+
+
+# ------------------------------------------------- (d) which path, and the event that says so
+
+def _traced_paths(opt, dtype, dim):
+    """Trace one sparse_update (nothing is lowered, so the compiled kernel
+    can be chosen on the CPU) and return its flight events and how many
+    kernels the trace holds."""
+    cfg = OPTS[opt].config
+    tracing.flight_clear()
+    jaxpr = jax.make_jaxpr(lambda t, s, i, g: su.sparse_update(cfg, t, s, i, g))(
+        jnp.zeros((V, dim), dtype), su.init_sparse_state(cfg, V, dim),
+        jnp.zeros((27,), jnp.int32), jnp.zeros((27, dim)))
+    return _row_write_events(), str(jaxpr).count("pallas_call")
+
+
+@pytest.mark.parametrize("platform,devices,dtype,dim,opt,want", [
+    ("tpu", 1, "float32", 128, "adagrad", {"table": "dma", "acc": "dma"}),
+    ("tpu", 1, "float32", 256, "adam", {"table": "dma", "m": "dma", "v": "dma"}),
+    ("tpu", 1, "float32", 128, "sgd_wd", {"table": "dma"}),
+    # per array: the (V, 1) accumulator is a sliver of a tile
+    ("tpu", 1, "float32", 128, "adagrad_vw", {"table": "dma", "acc": "scatter"}),
+    # per array: a bfloat16 row is not contiguous, its float32 state is
+    ("tpu", 1, "bfloat16", 128, "adagrad", {"table": "scatter", "acc": "dma"}),
+    ("tpu", 1, "float32", 16, "adagrad", {"table": "scatter", "acc": "scatter"}),
+    ("tpu", 1, "float32", 192, "adam", {"table": "scatter", "m": "scatter", "v": "scatter"}),
+    # several devices: GSPMD cannot partition the custom call
+    ("tpu", 4, "float32", 128, "adagrad", {"table": "scatter", "acc": "scatter"}),
+    ("tpu", 16, "float32", 128, "sgd_wd", {"table": "scatter"}),
+    ("cpu", 1, "float32", 128, "adagrad", {"table": "scatter", "acc": "scatter"}),
+    ("gpu", 1, "float32", 128, "adam", {"table": "scatter", "m": "scatter", "v": "scatter"}),
+])
+def test_row_write_path_follows_backend_dtype_row_width_and_device_count(
+        monkeypatch, platform, devices, dtype, dim, opt, want):
+    monkeypatch.setattr(su, "_backend", lambda: (platform, devices))
+    events, kernels = _traced_paths(opt, jnp.dtype(dtype), dim)
+    assert [e["array"] for e in events] == list(want)  # one event an array, the table first
+    assert {e["array"]: e["path"] for e in events} == want
+    assert kernels == sum(p == "dma" for p in want.values())
+    table_event = events[0]
+    assert (table_event["rows"], table_event["dim"], table_event["dtype"], table_event["chunk"]) == (
+        str(V), str(dim), dtype, "8")
+    # a second trace records its own
+    events_again, _ = _traced_paths(opt, jnp.dtype(dtype), dim)
+    assert events_again == events
+
+
+def test_this_process_takes_the_scatter_for_every_array():
+    """Nothing patched: a CPU run (all of tier-1) never picks the kernel."""
+    assert su._backend()[0] == "cpu"
+    events, kernels = _traced_paths("adam", jnp.float32, 128)
+    assert [e["path"] for e in events] == ["scatter"] * 3 and kernels == 0

@@ -1,0 +1,93 @@
+"""Compiles for a described TPU v5e (no chip attached) at the benchmark
+cells' own widths: what the chip's compiler refuses or copies shows here,
+at no chip time (ISSUE 29).
+
+One file and a module fixture on purpose: only one process may hold the
+TPU's library, so the topology is described after a test of this file has
+started, never at import, and every such test lives here.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from persia_tpu import tracing
+from persia_tpu.embedding.optim import Adagrad, Adam
+from persia_tpu.ops import sparse_update as su
+
+N_IDS = 106_496  # 26 slots x 4,096 samples
+CELL_ROWS = {"tb-cached-resident": 6_291_457, "tb-pinned-share16": 11_735_473}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    # a compile for a described device is written to the persistent cache
+    # and cannot be read back without a chip: keep it out
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _compile_sparse_update(cfg, vocab, dim, sharding):
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    state = {k: shaped(v.shape, v.dtype)
+             for k, v in jax.eval_shape(lambda: su.init_sparse_state(cfg, vocab, dim)).items()}
+    tracing.flight_clear()
+    compiled = jax.jit(
+        lambda t, s, i, g: su.sparse_update(cfg, t, s, i, g, mask=i >= 0), donate_argnums=(0, 1),
+    ).lower(shaped((vocab, dim), jnp.float32), state, shaped((N_IDS,), jnp.int32),
+            shaped((N_IDS, dim), jnp.float32)).compile()
+    events = [e["attrs"] for e in tracing.flight_snapshot() if e["kind"] == "sparse_update.row_write"]
+    return compiled, events
+
+
+@pytest.mark.parametrize("cell,opt", [
+    pytest.param("tb-cached-resident", Adagrad(lr=0.05), id="cached-adagrad"),
+    pytest.param("tb-pinned-share16", Adagrad(lr=0.05), id="pinned-adagrad"),
+    pytest.param("tb-cached-resident", Adam(lr=0.01), id="cached-adam"),
+])
+def test_sparse_update_writes_rows_by_dma_in_place_at_the_cells_widths(
+        monkeypatch, one_chip, no_compile_cache, cell, opt):
+    """The kernel compiles for the v5e, one call an array inside the loop
+    over live rows, and the donated table and state are updated where they
+    lie: no whole-array copy, a megabyte of scratch."""
+    # jax.default_backend() still says cpu here; the program is built for the chip
+    monkeypatch.setattr(su, "_backend", lambda: ("tpu", 1))
+    vocab, dim = CELL_ROWS[cell], 128
+    compiled, events = _compile_sparse_update(opt.config, vocab, dim, one_chip)
+    assert [e["path"] for e in events] == ["dma"] * len(events) and events
+    text = compiled.as_text()
+    whole = re.escape(f"f32[{vocab},{dim}]")
+    calls = [line for line in text.splitlines()
+             if re.search(rf"= {whole}\S* custom-call\(.*tpu_custom_call", line)]
+    assert len(calls) == len(events)
+    for call in calls:
+        assert "output_to_operand_aliasing={{}: (2, {})}" in call
+        assert re.search(r"sparse_update/row_update/while/body/[^\"]*write_\w+/", call), call[:300]
+    assert not re.search(rf"= {whole}\S* (copy|scatter)\(", text)
+    mem = compiled.memory_analysis()
+    # every array aliased to its output (rows padded to whole tiles of 8)
+    assert mem.alias_size_in_bytes >= len(events) * vocab * dim * 4
+    assert mem.temp_size_in_bytes < 16 * 2**20
