@@ -381,15 +381,6 @@ def _dispatch_k() -> int:
     return int(os.environ.get("BENCH_DISPATCH_K", "8"))
 
 
-def _pipeline_depth() -> int:
-    """MPMD stage-pipeline depth for the stream modes (pipeline_depth in
-    hbm_cache/stream.py: feeds hoist up to depth-1 steps above the dense
-    stage under the hazard ledger). Default 1 keeps the historical
-    in-order records comparable; the cached-pipelined mode A/Bs both on
-    one record."""
-    return int(os.environ.get("BENCH_PIPELINE_DEPTH", "1"))
-
-
 def _stream_record(ctx, samples_per_sec: float) -> dict:
     """The cached-tier mode record: throughput plus the dispatch-mode and
     feeder-utilization fields that make hot-loop regressions visible from
@@ -397,10 +388,7 @@ def _stream_record(ctx, samples_per_sec: float) -> dict:
     single-step dispatch, or a feeder pinned at 100%, is a finding)."""
     st = ctx.stream_stats() or {}
     total = st.get("packed_steps", 0) + st.get("single_steps", 0)
-    depth = st.get("pipeline_depth", 1)
-    if depth > 1:
-        dispatch_mode = f"pipe-{depth}-k{st.get('dispatch_k', 1)}"
-    elif st.get("dispatch_k", 1) > 1:
+    if st.get("dispatch_k", 1) > 1:
         dispatch_mode = f"kstep-{st.get('dispatch_k')}"
     else:
         dispatch_mode = "single"
@@ -434,18 +422,6 @@ def _stream_record(ctx, samples_per_sec: float) -> dict:
             "dense_wire_bytes_per_step", ctx.dense_wire_bytes_per_step()
         ),
     }
-    if depth > 1:
-        # stage-pipeline accounting: per-stage wall + the overlap fraction
-        # are the proof the hoisted feeds actually rode under dense
-        # compute (stage_overlap_frac == 0 on a pipe-* record is a finding)
-        rec.update(
-            pipeline_depth=depth,
-            stage_overlap_frac=st.get("stage_overlap_frac", 0.0),
-            stage_wall_s=st.get("stage_wall_s"),
-            pipeline_stalls=st.get("pipeline_stalls", 0),
-            pipeline_drains=st.get("pipeline_drains", 0),
-            pipelined_feeds=st.get("pipelined_feeds", 0),
-        )
     return rec
 
 
@@ -516,13 +492,13 @@ def bench_cached():
     # and materialized only after the window, so the timing holds no
     # metric fetch the training loop does not need
     ctx.train_stream(batches[:warmup], fetch_final=False,
-                     dispatch_k=_dispatch_k(), pipeline_depth=_pipeline_depth())
+                     dispatch_k=_dispatch_k())
 
     prog = _Progress()
     prog.start()
     t0 = time.perf_counter()
     ctx.train_stream(prog.wrap(batches[warmup:]), fetch_final=False,
-                     dispatch_k=_dispatch_k(), pipeline_depth=_pipeline_depth())
+                     dispatch_k=_dispatch_k())
     elapsed = time.perf_counter() - t0
     m = ctx.last_metrics()  # d2h outside the timed window
     assert m is not None and np.isfinite(m["loss"])
@@ -542,67 +518,16 @@ def bench_cached_saturated():
     warmup = 8
     batches = [make_batch() for _ in range(warmup + steps)]
     ctx.train_stream(batches[:warmup], fetch_final=False,
-                     dispatch_k=_dispatch_k(), pipeline_depth=_pipeline_depth())
+                     dispatch_k=_dispatch_k())
     prog = _Progress()
     prog.start()
     t0 = time.perf_counter()
     ctx.train_stream(prog.wrap(batches[warmup:]), fetch_final=False,
-                     dispatch_k=_dispatch_k(), pipeline_depth=_pipeline_depth())
+                     dispatch_k=_dispatch_k())
     elapsed = time.perf_counter() - t0
     m = ctx.last_metrics()
     assert m is not None and np.isfinite(m["loss"])
     return _stream_record(ctx, steps * BATCH_SIZE / elapsed)
-
-
-def bench_cached_pipelined():
-    """In-order vs stage-pipelined dispatch, A/B'd on ONE record: the same
-    cached-tier builder and the same zipf stream driven first with
-    pipeline_depth=1 (the historical in-order cadence) and then with the
-    MPMD stage pipeline (feeds hoist up to depth-1 steps above the dense
-    stage under the hazard ledger, parallel/stage_graph.py). Identical
-    dispatch_k on both legs so the only variable is the pipeline; each leg
-    gets a fresh ctx and its own warmup so neither inherits the other's
-    jit cache or cache fill.
-
-    The record's headline samples_per_sec is the PIPELINED leg (it is the
-    mode this bench exists to price); ``baseline_inorder`` carries the
-    depth-1 leg's full stream record and ``speedup_vs_inorder`` the ratio,
-    so the overlap claim is falsifiable from the committed JSON alone —
-    together with the pipelined leg's own stage_overlap_frac and
-    feeder_util (a speedup without overlap, or overlap without speedup,
-    is a finding)."""
-    steps = int(os.environ.get("BENCH_CACHED_PIPE_STEPS", "150"))
-    depth = _pipeline_depth()
-    if depth <= 1:
-        depth = int(os.environ.get("BENCH_PIPE_AB_DEPTH", "4"))
-    k = _dispatch_k()
-    make_batch = _zipf_batch_maker()
-    warmup = 8
-    batches = [make_batch() for _ in range(warmup + steps)]
-
-    def leg(d):
-        ctx = _cached_tier_ctx()
-        ctx.train_stream(batches[:warmup], fetch_final=False,
-                         dispatch_k=k, pipeline_depth=d)
-        prog = _Progress()
-        prog.start()
-        t0 = time.perf_counter()
-        ctx.train_stream(prog.wrap(batches[warmup:]), fetch_final=False,
-                         dispatch_k=k, pipeline_depth=d)
-        elapsed = time.perf_counter() - t0
-        m = ctx.last_metrics()  # d2h outside the timed window
-        assert m is not None and np.isfinite(m["loss"])
-        return _stream_record(ctx, steps * BATCH_SIZE / elapsed)
-
-    base = leg(1)
-    pipe = leg(depth)
-    rec = dict(pipe)
-    rec["baseline_inorder"] = base
-    if base["samples_per_sec"]:
-        rec["speedup_vs_inorder"] = round(
-            pipe["samples_per_sec"] / base["samples_per_sec"], 3
-        )
-    return rec
 
 
 def bench_ps_stream():
@@ -1260,7 +1185,6 @@ _BENCHES = {
     "hybrid": bench_hybrid,
     "cached": bench_cached,
     "cached-saturated": bench_cached_saturated,
-    "cached-pipelined": bench_cached_pipelined,
     "ps-stream": bench_ps_stream,
     "link": bench_link,
     "chaos": bench_chaos,  # opt-in (--chaos / BENCH_MODE=chaos); not in "all"

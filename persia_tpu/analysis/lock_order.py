@@ -17,13 +17,7 @@ Order (outermost first):
 1. ``cv``            — the stream pipeline condition (hbm_cache/stream.py);
                        guards heads/tails/alloc queue/sign map. Nothing may
                        be held when taking it.
-2. ``_pipe_cv``      — stage-graph window condition
-                       (parallel/stage_graph.py); guards the in-flight
-                       feed window, lane accounting, and abort flag.
-                       Leaf-ish: only the metrics/tracing leaves
-                       (``_flight_lock``, ``_REGISTRY_LOCK``) are ever
-                       taken under it
-3. ``_cv``           — data-loader prefetch pipeline condition; same
+2. ``_cv``           — data-loader prefetch pipeline condition; same
                        contract as ``cv`` for the loader threads
 3. ``_cond``         — RPC response-waiter / serving-batcher queue
                        conditions; taken first by their worker threads
@@ -38,26 +32,19 @@ Order (outermost first):
                        reset, degraded purge, flight event) runs after
                        release, so nothing is ever nested under it
 8. ``_swap_lock``    — serving engine model-swap latch
-9. ``_state_lock``   — CachedTrainCtx device-state mutex (hbm_cache/ctx.py):
-                       serializes the stager thread's feed dispatch against
-                       the main thread's dense dispatch in pipelined
-                       streams (every read-modify-replace of ``self.state``
-                       / ``self._ev_rings``). Never nested with ``cv`` or
-                       ``_pipe_cv``; only generic leaves below may be taken
-                       under it
-10. ``_lock``/``lock``— generic leaf locks (breakers, caches, registries,
+9. ``_lock``/``lock``— generic leaf locks (breakers, caches, registries,
                        checkpoint shard fan-out); must never wrap a
                        ranked-above lock
-11. ``_flight_lock``  — tracing flight-recorder ring (leaf; appends only)
-12. ``_rng_lock``    — RetryPolicy jitter RNG (innermost; held for one
+10. ``_flight_lock``  — tracing flight-recorder ring (leaf; appends only)
+11. ``_rng_lock``    — RetryPolicy jitter RNG (innermost; held for one
                        random() call only)
-13. ``_DEFAULT_LOCK``— resilience default-policy registry (leaf)
-14. ``_PROC_LOCK``   — native-build serializer (_native_build.py): a LAZY
+12. ``_DEFAULT_LOCK``— resilience default-policy registry (leaf)
+13. ``_PROC_LOCK``   — native-build serializer (_native_build.py): a LAZY
                        first-use build can trigger under any lock above,
                        and nothing ranked is ever taken under it (only the
                        compile subprocess + flock), so it is a leaf despite
                        being held the longest
-15. ``_REGISTRY_LOCK``— metrics registry (innermost leaf)
+14. ``_REGISTRY_LOCK``— metrics registry (innermost leaf)
 
 Native mutexes (native/cache.cpp) live below every Python lock: a ctypes
 call can run under any ``with`` above (CONC005 audits which ones), and the
@@ -98,7 +85,6 @@ NATIVE_LOCK_RANKS: Dict[str, int] = {
 # attribute-name suffix -> rank (lower = must be taken first / outermost)
 LOCK_RANKS: Dict[str, int] = {
     "cv": 0,
-    "_pipe_cv": 1,
     "_cv": 2,
     "_cond": 6,
     "_buf_lock": 10,
@@ -106,7 +92,6 @@ LOCK_RANKS: Dict[str, int] = {
     "_deg_lock": 30,
     "_ring_lock": 35,
     "_swap_lock": 40,
-    "_state_lock": 45,
     "_lock": 50,
     "lock": 50,
     "_flight_lock": 55,

@@ -341,7 +341,7 @@ class TrainCtx(EmbeddingCtx):
         ctx's sync mode (0 before state init — the param count prices it)."""
         return self._dense_wire_bytes_per_step
 
-    def _note_dense_sync(self, state) -> None:
+    def _price_dense_sync(self, state) -> None:
         """Price the per-step dense collective once (param count is known
         after state init) so the hot path only adds a python-int counter
         bump — no host syncs (persia-lint JAX001)."""
@@ -449,7 +449,7 @@ class TrainCtx(EmbeddingCtx):
             from persia_tpu.parallel.grad_sync import init_residual
 
             self._sync_residual = init_residual(state.params)
-        self._note_dense_sync(state)
+        self._price_dense_sync(state)
         return state
 
     def _place_state(self, state: TrainState) -> TrainState:

@@ -6,7 +6,6 @@ from __future__ import annotations
 
 import ctypes
 import os
-import threading
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
@@ -221,14 +220,12 @@ class CachedTrainCtx:
         # the most recent train_stream's dispatch/feeder accounting
         self._kstep_jit = None
         self._stream_stats: Optional[Dict] = None
-        # stage-graph pipelining (parallel/stage_graph.py): every
-        # read-modify-replace of ``state``/``_ev_rings`` holds _state_lock
-        # once train_stream dispatches feed programs from its stager
-        # thread (pipeline_depth > 1); the sync path is single-threaded
-        # and pays only an uncontended acquire. _stage_rebuild_hooks are
-        # copied onto each stream's StageGraph and fire at a drained
-        # fence after a tier migration (StageGraph.rebuild).
-        self._state_lock = threading.Lock()
+        # the last stream's lanes and time accounting
+        # (parallel/stage_graph.py). _stage_rebuild_hooks are copied onto
+        # each stream's StageGraph and fire at a drained fence after a
+        # tier migration (StageGraph.rebuild). ``state``/``_ev_rings`` are
+        # written from one thread: the dispatcher (the caller of
+        # train_stream or of the sync train_step).
         self._stage_graph = None
         self._stage_rebuild_hooks: List[Callable[[int], None]] = []
         # crash-consistent job state (persia_tpu.jobstate): manifest epoch
@@ -468,14 +465,7 @@ class CachedTrainCtx:
         """The FEED stage: ONE fused aux program per touched group
         (evict-payload read → ring write → warm scatter → cold scatter;
         ``_apply_aux``/``_apply_aux_ring``). Returns the per-group eviction
-        payloads for the write-back thread's bounded d2h fetch.
-
-        In the pipelined stream this runs on the STAGER thread under
-        ``_state_lock``, up to ``pipeline_depth - 1`` steps ahead of its
-        own dense stage — sound because the stream only hoists a feed
-        whose rows are disjoint from every in-flight dense stage's trained
-        rows (stage_graph.feed_hazard_info), and scatter/gather chains
-        over disjoint rows commute bitwise."""
+        payloads for the write-back thread's bounded d2h fetch."""
         evict_payload = {}
         touched = set(miss_aux) | set(cold_aux) | set(evict_aux)
         if not touched:
@@ -668,33 +658,6 @@ class CachedTrainCtx:
         self.state = state
         self._ev_rings.update(rings_out)
         return headers, payloads
-
-    # -------------------------------------------- pipelined (dense-only)
-
-    def _dispatch_dense(self, device_inputs, layout):
-        """DENSE stage of a pipelined step: the feed was already
-        dispatched from the stager thread (``_apply_feed``), so only the
-        main train program runs here. Caller holds ``_state_lock``."""
-        with span("ctx.main_step"):
-            self.state, header, _ps = self._step(
-                self.state, device_inputs, layout
-            )
-        return header
-
-    def _dispatch_packed_dense(self, items):
-        """Dispatch K feed-done steps as ONE dense-only K-step program.
-        Reuses ``_kstep_fn`` with empty per-step aux — its ``if aux:``
-        branch folds away at trace time, so the packed window carries no
-        aux leaves in the call pytree and no new program shape beyond the
-        (one-time) dense-only trace. ``items``: ``[(di, layout), ...]``
-        with one shared layout. Caller holds ``_state_lock``."""
-        layout = items[0][1]
-        steps = tuple((di, {}) for di, _lay in items)
-        state, _rings, headers, _payloads = self._kstep_fn()(
-            self.state, {}, steps, layout
-        )
-        self.state = state
-        return headers
 
     def stream_stats(self) -> Optional[Dict]:
         """Dispatch/feeder accounting of the most recent ``train_stream``:

@@ -9,7 +9,7 @@ import ctypes
 import os
 import threading
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Set, Tuple
 
 import flax.struct
 import jax
@@ -44,6 +44,21 @@ from persia_tpu.embedding.hbm_cache.directory import (  # noqa: F401
     PendingSignMap,
     _BufRing,
 )
+
+class _Staged(NamedTuple):
+    """One prepared step with its inputs on the device: what the stager
+    hands the dispatcher through ``staged_q``."""
+
+    seq: int
+    di: Dict
+    layout: CacheLayout
+    miss_aux: Dict
+    cold_aux: Dict
+    restore_aux: Dict
+    evict_aux: Dict
+    evict_meta: Dict
+    ps_item: Optional[Tuple]
+
 
 def run_train_stream(
     self,
@@ -140,26 +155,9 @@ def run_train_stream(
     and journal ids for a resumed stream
     (``train_stream(batches_from_F, start_step=F, ...)``).
 
-    ``pipeline_depth``: MPMD stage-graph pipelining
-    (persia_tpu/parallel/stage_graph.py). At depth >= 2 the step's FEED
-    stage (the fused aux scatters of ``_apply_feed``) dispatches from the
-    STAGER thread up to ``depth - 1`` steps ahead of its own dense stage,
-    so batch N+k's embedding feed rides under batch N's dense compute —
-    the source paper's bounded-staleness overlap expressed in the
-    dispatch layer, with the depth as the staleness knob. Bit-parity is
-    preserved (not approximated): a feed only hoists when its rows are
-    disjoint from every in-flight dense stage's trained rows (disjoint
-    scatters commute bitwise); a conflict stalls the feed
-    (``pipeline.stall``) until the dense stages retire. Steps the hazard
-    ledger already serializes — in-flight-eviction restores, PS-tier
-    forwards — enter the window as BARRIERS: they dispatch through the
-    full in-order path and no later feed hoists across them. Feed-done
-    steps pack into dense-only K-step windows (``min(dispatch_k, depth)``
-    wide, so a full pack never overruns the window); fences drain the
-    window before capture (``pipeline.drain``) so jobstate bit-parity
-    holds unchanged, and a post-migration fence fires the stage graph's
-    ``rebuild()`` hooks. ``on_metrics`` forces depth 1 (per-step header
-    sync), like ``dispatch_k``.
+    ``pipeline_depth``: accepted with the one value 1 (the benchmark's
+    entry still passes it); the feed-hoisting regime it selected went in
+    PR 30 and any other value raises ``ValueError``.
 
     ``fence_callback``: a hook invoked at EVERY fence with the global
     step, after the manifest commit (when ``job_state`` is armed) and the
@@ -195,14 +193,14 @@ def run_train_stream(
 
     if prefetch < 1:
         raise ValueError(f"prefetch must be >= 1, got {prefetch}")
-    if pipeline_depth < 1:
-        raise ValueError(f"pipeline_depth must be >= 1, got {pipeline_depth}")
-    from persia_tpu.parallel.stage_graph import StageGraph, feed_hazard_info
+    if pipeline_depth != 1:
+        raise ValueError(
+            f"pipeline_depth must be 1, got {pipeline_depth}: the stream "
+            "dispatches in one order since PR 30 (feed hoisting was removed)"
+        )
+    from persia_tpu.parallel.stage_graph import StageGraph
 
-    # on_metrics needs a per-step header sync, which serializes the
-    # stages anyway — force the in-order pipeline (same rule as dispatch_k)
-    PIPE = pipeline_depth > 1 and on_metrics is None
-    graph = StageGraph(pipeline_depth if PIPE else 1)
+    graph = StageGraph()
     self._stage_graph = graph
     # the stream's one time accounting: every stage and wait span closed on
     # a thread of this stream adds to it (ctx.stream_stats()["stages"/"waits"])
@@ -223,15 +221,12 @@ def run_train_stream(
     self._land_pending()  # do not mix with a sync-path deferred step
     cv = threading.Condition()
     stop = threading.Event()
-    # a pipelined stream needs the staged queue at least window-deep or
-    # the queue cap (not the depth knob) would bound the feed look-ahead
-    qcap = max(prefetch, graph.depth)
-    staged_q: "_queue.Queue" = _queue.Queue(maxsize=qcap)
+    staged_q: "_queue.Queue" = _queue.Queue(maxsize=prefetch)
     # bounds device-memory retention: at most ~(queue + one flush batch)
     # steps of eviction payloads (+ one psgrad batch) stay pinned in HBM
     # while the PS lags
     wb_q: "_queue.Queue" = _queue.Queue(
-        maxsize=max(1, wb_flush_steps) + qcap + max(1, psgrad_batch)
+        maxsize=max(1, wb_flush_steps) + prefetch + max(1, psgrad_batch)
     )
     SENTINEL = object()
     errors: List[BaseException] = []
@@ -357,7 +352,6 @@ def run_train_stream(
     stats = {
         "dispatch_k": max(1, int(dispatch_k)) if on_metrics is None else 1,
         "packs": 0, "packed_steps": 0, "single_steps": 0,
-        "pipelined_feeds": 0,
         "feeder_busy_s": 0.0, "wall_s": 0.0,
         "stages": timing.stages, "waits": timing.waits,
         "degraded_steps": 0, "degraded_lookup_frac_max": 0.0,
@@ -491,19 +485,8 @@ def run_train_stream(
         finally:
             _put(prep_q, SENTINEL, "stream.prep_put_wait")  # the clean end
 
-    def _pipe_abort() -> bool:
-        return stop.is_set() or bool(errors)
-
     def feeder_dp():
-        """Stage 2 — the FEED stage of the stage graph: async host→device
-        staging, and (pipeline_depth > 1) the feed-program dispatch
-        itself, hoisted above the not-yet-dispatched dense stages of
-        earlier steps. ``reserve_feed`` holds a feed back while its rows
-        collide with an in-flight dense stage (bit-parity by row
-        disjointness; stage_graph module docstring) or while the window
-        is at depth (the staleness bound). Restore/PS/pre-init steps
-        forward un-fed as window BARRIERS and keep the full in-order
-        dispatch path."""
+        """Stage 2 — the FEED lane: async host→device staging."""
         try:
             while True:
                 got = _take(prep_q, "stream.stage_get_wait")
@@ -517,21 +500,6 @@ def run_train_stream(
                 seq, item, ps_item = got
                 (di, layout, miss_aux, cold_aux, restore_aux, evict_aux,
                  evict_meta) = item
-                # self.state races only benignly here: the main thread
-                # sets it once (init_state at step 0); a stale None read
-                # just routes this step through the in-order barrier path
-                pipelinable = (
-                    PIPE and not restore_aux and ps_item is None
-                    and self.state is not None
-                )
-                hazard = None
-                if pipelinable:
-                    # hazard sets come from the HOST arrays, before the
-                    # staging below turns them into device buffers
-                    hazard = feed_hazard_info(
-                        di, miss_aux, cold_aux, evict_aux,
-                        {n: g.name for n, g in self.tier._slot_group.items()},
-                    )
                 with graph.lane("feed"):
                     with stage_span("stream.stage", seq=seq):
                         di, miss_aux, cold_aux, evict_aux = self._stage(
@@ -552,38 +520,10 @@ def run_train_stream(
                         gn: [(p, put(src), put(dst)) for (p, src, dst) in lst]
                         for gn, lst in restore_aux.items()
                     }
-                feed_done = False
-                feed_payload = None
-                if pipelinable:
-                    # stall time (reserve_feed) stays OUTSIDE the feed
-                    # lane so stage_overlap_frac measures work, not waits
-                    with wait_span("stream.reserve_feed_wait", seq=seq):
-                        reserved = graph.reserve_feed(
-                            seq, hazard[0], hazard[1], should_abort=_pipe_abort
-                        )
-                    if not reserved:
-                        return
-                    with graph.lane("feed"):
-                        with stage_span("stream.feed_dispatch", seq=seq):
-                            with self._state_lock:
-                                feed_payload = self._apply_feed(
-                                    miss_aux, cold_aux, evict_aux, evict_meta
-                                )
-                    feed_done = True
-                elif PIPE:
-                    with wait_span("stream.reserve_feed_wait", seq=seq):
-                        reserved = graph.reserve_feed(
-                            seq, None, None, should_abort=_pipe_abort,
-                            barrier=True,
-                        )
-                    if not reserved:
-                        if ps_item is not None:
-                            self.worker.abort_gradient(ps_item[0])
-                        return
                 if not _put(
                     staged_q,
-                    (seq, di, layout, miss_aux, cold_aux, restore_aux,
-                     evict_aux, evict_meta, ps_item, feed_done, feed_payload),
+                    _Staged(seq, di, layout, miss_aux, cold_aux, restore_aux,
+                            evict_aux, evict_meta, ps_item),
                     "stream.stage_put_wait",
                 ):
                     if ps_item is not None:
@@ -780,11 +720,10 @@ def run_train_stream(
     def _abort_drained(got) -> None:
         # a drained-but-never-applied item may carry a PS-tier forward
         # ref: release its staleness slot + stashed layout. prep_q items
-        # are (seq, item, ps_item) 3-tuples; staged items carry ps_item
-        # at index 8 (the pipelined fields ride behind it)
+        # are (seq, item, ps_item) 3-tuples
         if not (isinstance(got, tuple) and len(got) >= 3):
             return
-        ps_item = got[8] if len(got) >= 9 else got[-1]
+        ps_item = got.ps_item if isinstance(got, _Staged) else got[-1]
         if (
             ps_item is not None
             and isinstance(ps_item, tuple) and len(ps_item) == 4
@@ -795,9 +734,6 @@ def run_train_stream(
                 pass
 
     K = stats["dispatch_k"]
-    # a full pack retires as ONE dense stage: cap it at the window depth
-    # so pack assembly never waits on feeds the window cannot admit
-    K_eff = min(K, graph.depth) if PIPE else K
     pack: List = []  # staged hazard-free items awaiting a K-step dispatch
     pack_sig: List = [None]
 
@@ -855,7 +791,7 @@ def run_train_stream(
                     if stats.get("migrations", 0) != n_mig:
                         # the tier swap re-registered groups under the
                         # stage programs: fire the fence-point stage-graph
-                        # rebuild hooks (window drained, feeder parked)
+                        # rebuild hooks (write-back drained, feeder parked)
                         graph.rebuild(gstep)
                     if fence_callback is not None:
                         # topology-change window: feeder parked, write-back
@@ -949,25 +885,15 @@ def run_train_stream(
 
     def _dispatch_one(item):
         nonlocal header
-        (seq, di, layout, miss_aux, cold_aux, restore_aux, evict_aux,
-         evict_meta, ps_item, feed_done, feed_payload) = item
+        seq, di, ps_item = item.seq, item.di, item.ps_item
         try:
             with graph.lane("dense"), stage_span("stream.dispatch", seq=seq):
                 if self.state is None:
-                    self.init_state(jax.random.PRNGKey(0), di, layout)
-                with self._state_lock:
-                    if feed_done:
-                        # FEED already dispatched from the stager thread:
-                        # dense stage only (the payload came back with the
-                        # feed)
-                        header = self._dispatch_dense(di, layout)
-                        evict_payload, ps_gpacked = feed_payload, None
-                        stats["pipelined_feeds"] += 1
-                    else:
-                        header, evict_payload, ps_gpacked = self._dispatch(
-                            di, layout, miss_aux, cold_aux, restore_aux,
-                            evict_aux, evict_meta,
-                        )
+                    self.init_state(jax.random.PRNGKey(0), di, item.layout)
+                header, evict_payload, ps_gpacked = self._dispatch(
+                    di, item.layout, item.miss_aux, item.cold_aux,
+                    item.restore_aux, item.evict_aux, item.evict_meta,
+                )
         except BaseException:
             # the in-hand item is already off the queue: the shutdown
             # sweep in finally can't see it, so its staleness ref must
@@ -978,8 +904,6 @@ def run_train_stream(
                 except Exception:  # noqa: BLE001 — shutdown best-effort
                     pass
             raise
-        if PIPE:
-            graph.note_dense(seq)
         stats["single_steps"] += 1
         if ps_item is not None:
             # gradient return for PS-tier slots rides the write-back
@@ -987,7 +911,7 @@ def run_train_stream(
             # keeps the worker's per-batch Adam advance in step order.
             # The global step rides along as the apply-journal step id.
             wb_q.put(("psgrad", ps_item, ps_gpacked, start_step + seq))
-        _post_step(seq, di, evict_meta, evict_payload)
+        _post_step(seq, di, item.evict_meta, evict_payload)
         sentinel_note(
             sentinel, sent_pending, start_step + seq, header,
             int(np.prod(di["labels"][0].shape)),
@@ -1009,8 +933,7 @@ def run_train_stream(
         member shares one signature) so the K-step jit cache is keyed on
         a single step's shapes × K — the same cardinality as the
         single-step cache, not its K-th power."""
-        (_seq, di, layout, miss_aux, cold_aux, _restore, evict_aux,
-         evict_meta, _ps, _fd, _fp) = item
+        di, evict_meta = item.di, item.evict_meta
 
         def aux_sig(d):
             return tuple(sorted(
@@ -1019,10 +942,10 @@ def run_train_stream(
             ))
 
         return (
-            layout,
+            item.layout,
             tuple(sorted((k, tuple(np.shape(v))) for k, v in di["stacked_rows"].items())),
             tuple(np.shape(x) for x in di["labels"]),
-            aux_sig(miss_aux), aux_sig(cold_aux), aux_sig(evict_aux),
+            aux_sig(item.miss_aux), aux_sig(item.cold_aux), aux_sig(item.evict_aux),
             tuple(sorted((gn, evict_meta[gn][2] >= 0) for gn in evict_meta)),
         )
 
@@ -1031,27 +954,8 @@ def run_train_stream(
         # (its gradient return is per-step), and the state must exist
         return (
             self.state is not None
-            and not item[5]          # restore_aux
-            and item[8] is None      # ps_item
-        )
-
-    def _dense_sig(item):
-        """Signature of a feed-done step's DENSE stage: the feed's aux is
-        out of the program, so only the model-input shapes key the
-        dense-only K-step jit cache."""
-        (_seq, di, layout) = item[:3]
-        return (
-            layout,
-            tuple(sorted(
-                (k, tuple(np.shape(v)))
-                for k, v in di["stacked_rows"].items()
-            )),
-            tuple(sorted(
-                (k, tuple(np.shape(v)))
-                for k, v in di.get("raw_rows", {}).items()
-            )),
-            tuple(np.shape(x) for x in di["labels"]),
-            "stacked_scale" in di,
+            and not item.restore_aux
+            and item.ps_item is None
         )
 
     def _flush_pack_single():
@@ -1065,43 +969,20 @@ def run_train_stream(
     def _dispatch_pack():
         nonlocal header
         with graph.lane("dense"):
-            with stage_span("stream.dispatch_pack", seq=pack[0][0], k=len(pack)):
+            with stage_span("stream.dispatch_pack", seq=pack[0].seq, k=len(pack)):
                 headers, payloads = self._dispatch_packed(
-                    [(it[1], it[2], it[3], it[4], it[6], it[7]) for it in pack]
+                    [(it.di, it.layout, it.miss_aux, it.cold_aux,
+                      it.evict_aux, it.evict_meta) for it in pack]
                 )
         header = headers[-1]
         stats["packs"] += 1
         stats["packed_steps"] += len(pack)
         for it, payload in zip(pack, payloads):
-            _post_step(it[0], it[1], it[7], payload)
+            _post_step(it.seq, it.di, it.evict_meta, payload)
         for it, h in zip(pack, headers):
             sentinel_note(
-                sentinel, sent_pending, start_step + it[0], h,
-                int(np.prod(it[1]["labels"][0].shape)),
-            )
-        pack.clear()
-
-    def _dispatch_pack_dense():
-        """One dense-only K-step dispatch over feed-done items — a packed
-        window is ONE dense stage of the graph."""
-        nonlocal header
-        with graph.lane("dense"):
-            with stage_span("stream.dispatch_pack", seq=pack[0][0], k=len(pack)):
-                with self._state_lock:
-                    headers = self._dispatch_packed_dense(
-                        [(it[1], it[2]) for it in pack]
-                    )
-        header = headers[-1]
-        stats["packs"] += 1
-        stats["packed_steps"] += len(pack)
-        stats["pipelined_feeds"] += len(pack)
-        graph.note_dense(pack[-1][0])
-        for it in pack:
-            _post_step(it[0], it[1], it[7], it[10])
-        for it, h in zip(pack, headers):
-            sentinel_note(
-                sentinel, sent_pending, start_step + it[0], h,
-                int(np.prod(it[1]["labels"][0].shape)),
+                sentinel, sent_pending, start_step + it.seq, h,
+                int(np.prod(it.di["labels"][0].shape)),
             )
         pack.clear()
 
@@ -1126,30 +1007,15 @@ def run_train_stream(
                 if item is SENTINEL:
                     _flush_pack_single()
                     sentinel_drain(sentinel, sent_pending)
-                    # end-of-stream drain: every feed's dense retired
-                    graph.drain_for_fence(self._global_step, reason="end")
                     break
                 if isinstance(item, tuple) and len(item) == 2 and item[0] == "fence":
                     _flush_pack_single()
                     # the sentinel must digest every pre-fence header BEFORE
                     # the capture: a poisoned step must never become LAST_GOOD
                     sentinel_drain(sentinel, sent_pending)
-                    # feeder parked + FIFO => the window is empty here; the
-                    # drain is asserted + recorded before the capture reads
-                    graph.drain_for_fence(item[1])
                     _run_fence(item[1])
                     continue
-                if PIPE and K_eff > 1 and item[9]:  # feed_done: dense-only pack
-                    sig = _dense_sig(item)
-                    if pack and sig != pack_sig[0]:
-                        _flush_pack_single()
-                    if not pack:
-                        pack_sig[0] = sig
-                    pack.append(item)
-                    if len(pack) == K_eff:
-                        _dispatch_pack_dense()
-                    continue
-                if K > 1 and not PIPE and _packable(item):
+                if K > 1 and _packable(item):
                     sig = _item_sig(item)
                     if pack and sig != pack_sig[0]:
                         _flush_pack_single()
@@ -1197,7 +1063,6 @@ def run_train_stream(
         stats.update(graph.stats(stats["wall_s"]))
         self._stream_stats = stats
         stop.set()
-        graph.abort()  # unparks a stager blocked in reserve_feed
         with cv:
             cv.notify_all()
 

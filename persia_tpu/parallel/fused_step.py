@@ -567,7 +567,7 @@ class FusedPipeline:
     ``depth`` in flight) while the caller's thread runs the DENSE stage
     (the jitted single- or K-step program). Every table row is HBM-resident
     and the sparse update is fused INTO the dense program, so there are no
-    feed/gradient hazards to ledger — the stage graph's window only bounds
+    feed/gradient hazards — a semaphore of ``depth`` permits only bounds
     how many staged batches (and therefore how much staging HBM) ride
     ahead of the dense stage. Batches enter the program in stream order,
     so with ``k == 1`` the result is the sequential ``step`` loop's bit
@@ -576,11 +576,10 @@ class FusedPipeline:
     bitwise (see its docstring) — same trade as calling that program
     directly.
 
-    ``run`` drains the window before returning — callers may checkpoint
-    (``FusedTrainCtx.dump_checkpoint``) immediately after with fence
-    semantics. The cached tier's ``train_stream(pipeline_depth=...)``
-    applies the same stage graph WITH the hazard ledger (rows there are
-    cache slots that feeds mutate); see parallel/stage_graph.py.
+    ``run`` returns with every staged batch dispatched — callers may
+    checkpoint (``FusedTrainCtx.dump_checkpoint``) immediately after with
+    fence semantics. When the caller's dispatch raises, the feeder thread
+    gives up within its poll interval and ``run`` re-raises.
     """
 
     def __init__(self, step, multi=None, depth: int = 2, k: int = 1):
@@ -596,7 +595,7 @@ class FusedPipeline:
         # a full pack must fit in the window or feed and dense deadlock
         # waiting on each other
         self.k = max(1, min(int(k), self.depth))
-        self.graph = StageGraph(self.depth)
+        self.graph = StageGraph()
 
     def run(self, state, batches, stage=None):
         """Drive ``batches`` (iterable of fused batch dicts — or anything
@@ -611,20 +610,27 @@ class FusedPipeline:
 
         stage = jax.device_put if stage is None else stage
         graph = self.graph
-        q: "_queue.Queue" = _queue.Queue(maxsize=self.depth)
+        # at most ``depth`` batches between ``stage`` and their dispatch:
+        # the feeder takes a permit before staging, the dispatcher hands
+        # back a pack's permits once it went out. The queue itself is
+        # unbounded (the permits are its bound), so the feeder's only park
+        # is the permit wait, which polls ``abort``
+        window = threading.Semaphore(self.depth)
+        abort = threading.Event()
+        q: "_queue.Queue" = _queue.Queue()
         errors: List[BaseException] = []
         SENTINEL = object()
+
+        def admit() -> bool:
+            while not abort.is_set():
+                if window.acquire(timeout=0.05):
+                    return True
+            return False
 
         def feeder():
             try:
                 for seq, b in enumerate(batches):
-                    if errors:
-                        break
-                    # no hazard rows: empty feed/trained sets, the window
-                    # acts purely as the staging-buffer bound
-                    if not graph.reserve_feed(
-                        seq, {}, {}, should_abort=lambda: bool(errors)
-                    ):
+                    if not admit():
                         break
                     with graph.lane("feed"), stage_span("fused.stage", seq=seq):
                         staged = stage(b)
@@ -639,7 +645,6 @@ class FusedPipeline:
         th.start()
         losses: List[jnp.ndarray] = []
         pack: List[Tuple[int, Dict]] = []
-        n_seen = 0
         try:
             def flush():
                 nonlocal state
@@ -658,7 +663,7 @@ class FusedPipeline:
                     with self.graph.lane("dense"), stage_span("fused.dispatch", seq=seq):
                         state, (loss, _preds) = self._step(state, pack[0][1])
                     losses.append(loss)
-                graph.note_dense(pack[-1][0])
+                window.release(len(pack))
                 pack.clear()
 
             while True:
@@ -666,22 +671,20 @@ class FusedPipeline:
                 if item is SENTINEL:
                     break
                 pack.append(item)
-                n_seen += 1
                 if len(pack) >= self.k:
                     flush()
             flush()
             if errors:
                 raise errors[0]
-            graph.drain_for_fence(n_seen, reason="end")
         finally:
-            graph.abort()
+            abort.set()
             th.join(timeout=5.0)
         self._wall_s = _time.perf_counter() - t0
         return state, losses
 
     def stats(self) -> Dict:
-        """Pipeline stats of the last :meth:`run` (stage_graph stats dict
-        plus the run's wall seconds)."""
+        """Lane stats of the last :meth:`run` (``StageGraph.stats``) plus
+        the run's wall seconds."""
         out = self.graph.stats(getattr(self, "_wall_s", 0.0))
         out["wall_s"] = round(getattr(self, "_wall_s", 0.0), 6)
         return out

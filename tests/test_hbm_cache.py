@@ -1591,12 +1591,14 @@ def test_stream_deterministic_under_flush_timing():
 # ------------------------------------------------- K-step fused dispatch
 
 
-def _block_batches(n, batch_size=16, n_blocks=16, block=16, seed=5):
+def _block_batches(n, batch_size=16, n_blocks=16, block=16, seed=5, cover=False):
     """Rotating disjoint id blocks over ONE 256-sign slot: every step
     evicts (the cache is smaller than the sign space) but an evicted sign
     is only re-missed ``n_blocks`` steps later — past the in-flight
     write-back window, so steps stay hazard-free and PACKABLE while the
-    eviction ring carries real traffic."""
+    eviction ring carries real traffic. ``cover``: a step holds every id
+    of its block once (not ``batch_size`` draws from it), so all steps
+    miss and evict the same counts and share one shape signature."""
     from persia_tpu.config import EmbeddingConfig, SlotConfig
 
     cfg = EmbeddingConfig(
@@ -1606,7 +1608,12 @@ def _block_batches(n, batch_size=16, n_blocks=16, block=16, seed=5):
     out = []
     for i in range(n):
         lo = (i % n_blocks) * block
-        data = list(rng.integers(lo, lo + block, (batch_size, 1), dtype=np.uint64))
+        if cover:
+            assert batch_size == block
+            data = list(rng.permutation(
+                np.arange(lo, lo + block, dtype=np.uint64)).reshape(batch_size, 1))
+        else:
+            data = list(rng.integers(lo, lo + block, (batch_size, 1), dtype=np.uint64))
         out.append(
             PersiaBatch(
                 [IDTypeFeature("cat", data)],
@@ -1618,6 +1625,11 @@ def _block_batches(n, batch_size=16, n_blocks=16, block=16, seed=5):
             )
         )
     return cfg, out
+
+
+# 32 blocks of 8 ids, each covered whole: every step has one shape signature
+# and an evicted sign comes back 32 steps later, so packs of 8 form
+UNIFORM_STREAM = dict(batch_size=8, n_blocks=32, block=8, cover=True)
 
 
 def _one_slot_ctx(cfg, cache_rows, seed=11):
@@ -1652,18 +1664,31 @@ def _one_slot_entries(store, cfg):
     }
 
 
-def test_stream_kstep_packing_bitwise_parity():
+@pytest.mark.parametrize("cache_rows", [40, 136])
+@pytest.mark.parametrize("dispatch_k", [2, 4, 8])
+def test_stream_kstep_packing_bitwise_parity(dispatch_k, cache_rows):
     """Multi-step fused dispatch must be BIT-transparent: a stream that
     packs hazard-free windows (including steps with live eviction-ring
     writes) produces exactly the single-dispatch stream's final PS state
-    and loss. The slow-step shim forces staged items to queue so packs
-    genuinely form (asserted) — without it a fast device drains the queue
-    one item at a time and nothing would be tested."""
+    and loss, at every pack width (8 is what the benchmark's cached cell
+    runs) and with a cache of two id blocks or of eight. The slow-step
+    shim forces staged items to queue so packs genuinely form (asserted)
+    — without it a fast device drains the queue one item at a time and
+    nothing would be tested."""
     import time
 
+    # a pack of 8 needs 8 consecutive hazard-free steps of one shape
+    # signature. The drawn stream's distinct-id count crosses a bucket
+    # edge more often than that, and its 16 blocks bring an evicted sign
+    # back within the 15 steps the feeder may run ahead of a pack of 8 (a
+    # restore, so no pack): K = 8 runs over 32 blocks of 8 ids, each
+    # covered whole. The narrower packs keep the drawn stream and with it
+    # the flushes of partial packs at signature changes
+    stream = UNIFORM_STREAM if dispatch_k > 4 else {}
+
     def run(k, slow):
-        cfg, batches = _block_batches(36)
-        ctx, store = _one_slot_ctx(cfg, cache_rows=40)
+        cfg, batches = _block_batches(36, **stream)
+        ctx, store = _one_slot_ctx(cfg, cache_rows=cache_rows)
         if slow:
             orig = ctx._step
 
@@ -1679,17 +1704,19 @@ def test_stream_kstep_packing_bitwise_parity():
         return m["loss"], _one_slot_entries(store, cfg), st
 
     l1, e1, _s1 = run(1, slow=False)
-    l4, e4, s4 = run(4, slow=True)
-    assert s4["packed_steps"] > 0, f"packs never formed: {s4}"
-    assert l1 == l4, "packing changed the loss bits"
-    assert set(e1) == set(e4)
+    lk, ek, sk = run(dispatch_k, slow=True)
+    assert sk["packed_steps"] > 0, f"packs never formed: {sk}"
+    assert sk["packed_steps"] == sk["packs"] * dispatch_k
+    assert l1 == lk, "packing changed the loss bits"
+    assert set(e1) == set(ek)
     for key in e1:
         np.testing.assert_array_equal(
-            e1[key], e4[key], err_msg=f"sign {key}: packing changed the math"
+            e1[key], ek[key], err_msg=f"sign {key}: packing changed the math"
         )
 
 
-def test_stream_packing_never_overlaps_inflight_eviction():
+@pytest.mark.parametrize("dispatch_k", [4, 8])
+def test_stream_packing_never_overlaps_inflight_eviction(dispatch_k):
     """The hazard side of dispatch_k: a step that restores from the
     standing ring (its miss overlaps an in-flight eviction write-back)
     must NEVER enter a pack — it dispatches singly AFTER the pack that
@@ -1711,7 +1738,7 @@ def test_stream_packing_never_overlaps_inflight_eviction():
 
     cached._dispatch = spy
     with cached:
-        m = cached.train_stream(batches, dispatch_k=4)
+        m = cached.train_stream(batches, dispatch_k=dispatch_k)
         st = cached.stream_stats()
     assert m is not None and np.isfinite(m["loss"])
     assert restores_seen[0] > 0, "scenario must actually exercise restores"

@@ -144,10 +144,10 @@ def test_sharded_walk_has_one_live_span_around_the_native_call():
 
 # ------------------------------- (c), (d) the stream's one time accounting
 
-def _stream_stats(depth, k, slow_s):
-    from test_hbm_cache import _block_batches, _one_slot_ctx
+def _stream_stats(k, slow_s, uniform=False):
+    from test_hbm_cache import UNIFORM_STREAM, _block_batches, _one_slot_ctx
 
-    cfg, batches = _block_batches(36)
+    cfg, batches = _block_batches(36, **(UNIFORM_STREAM if uniform else {}))
     ctx, _store = _one_slot_ctx(cfg, cache_rows=136)
     orig = ctx._step
 
@@ -162,8 +162,7 @@ def _stream_stats(depth, k, slow_s):
         yield from batches
 
     with ctx:
-        ctx.train_stream(late_start(), dispatch_k=k, pipeline_depth=depth, wb_flush_steps=2,
-                         prefetch=2)
+        ctx.train_stream(late_start(), dispatch_k=k, wb_flush_steps=2, prefetch=2)
         st = ctx.stream_stats()
     return st
 
@@ -172,22 +171,24 @@ WORK_ALWAYS = {"stream.prep", "stream.stage", "stream.wb_flush", "stream.wb_fetc
                "stream.wb_store", "stage.feed", "stage.dense", "stage.psgrad"}
 CASES = {
     # in order, step by step: the aux programs go out one by one
-    "in_order": (1, 1, WORK_ALWAYS | {"stream.dispatch", "ctx.apply_aux"},
+    "in_order": (1, False, WORK_ALWAYS | {"stream.dispatch", "ctx.apply_aux"},
                  {"stream.dispatch_get_wait", "stream.prep_put_wait", "stream.stage_put_wait",
                   "stream.drain"}),
     # K-step packs: the aux rides inside the pack's program
-    "packed": (1, 4, WORK_ALWAYS | {"stream.dispatch_pack"},
+    "packed": (4, False, WORK_ALWAYS | {"stream.dispatch_pack"},
                {"stream.dispatch_get_wait", "stream.drain"}),
-    # the feed stage hoisted onto the stager's thread
-    "pipelined": (3, 1, WORK_ALWAYS | {"stream.dispatch", "stream.feed_dispatch", "ctx.apply_aux"},
-                  {"stream.dispatch_get_wait", "stream.reserve_feed_wait", "stream.drain"}),
+    # the width the benchmark's cached cell runs, over test_hbm_cache.UNIFORM_STREAM
+    # (test_stream_kstep_packing_bitwise_parity says why)
+    "packed_k8": (8, True,
+                  WORK_ALWAYS | {"stream.dispatch_pack"},
+                  {"stream.dispatch_get_wait", "stream.drain"}),
 }
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_stream_accounts_for_every_stage_and_wait(case):
-    depth, k, work, waits = CASES[case]
-    st = _stream_stats(depth, k, slow_s=0.02)
+    k, uniform, work, waits = CASES[case]
+    st = _stream_stats(k, slow_s=0.02, uniform=uniform)
     stages, wts = st["stages"], st["waits"]
     assert work <= set(stages), f"missing work spans: {work - set(stages)}"
     assert waits <= set(wts), f"missing wait spans: {waits - set(wts)}"
@@ -212,7 +213,7 @@ def test_stream_accounts_for_every_stage_and_wait(case):
 def test_dispatcher_thread_has_no_hole():
     """Busy in the dispatch calls plus blocked on the empty staged queue is
     the dispatcher's whole stream, give or take its bookkeeping."""
-    st = _stream_stats(1, 1, slow_s=0.03)
+    st = _stream_stats(1, slow_s=0.03)
     busy = st["stages"]["stream.dispatch"]["busy_s"]
     wait = st["waits"]["stream.dispatch_get_wait"]["wait_s"]
     assert 0.85 * st["wall_s"] <= busy + wait <= st["wall_s"], (busy, wait, st["wall_s"])

@@ -383,59 +383,6 @@ def test_gateway_edge_generates_and_propagates_fresh_id():
         srv.stop()
 
 
-# --------------------------------- pipelined dispatch × telemetry (PR 12)
-
-
-def test_pipeline_stage_events_metrics_and_spans(tmp_path):
-    """OBS PIN for the pipelined stream: one depth-3 run with a
-    stall-forcing cache must land (a) ``pipeline.stall`` and
-    ``pipeline.drain`` flight events, (b) the
-    ``persia_tpu_pipeline_{stalls,drains,depth}`` metric family, and
-    (c) ``stage.feed``/``stage.dense``/``stage.psgrad`` lane spans in the
-    exported Perfetto doc — the overlap is auditable from the trace
-    alone."""
-    import sys as _sys
-    import time as _t
-
-    _sys.path.insert(0, os.path.join(REPO_ROOT, "tests"))
-    from test_hbm_cache import _block_batches, _one_slot_ctx
-
-    from persia_tpu.metrics import get_metrics
-
-    tracing.enable(True)
-    cfg, batches = _block_batches(10)
-    # cache barely over one id block: feeds evict in-flight trained rows,
-    # so the hazard ledger must stall at least once
-    ctx, _store = _one_slot_ctx(cfg, cache_rows=40)
-    orig = ctx._step
-
-    def slow_step(*a):
-        _t.sleep(0.03)
-        return orig(*a)
-
-    ctx._step = slow_step
-    with ctx:
-        ctx.train_stream(batches, pipeline_depth=3, wb_flush_steps=2)
-        st = ctx.stream_stats()
-        ctx.flush()
-    assert st["pipeline_stalls"] > 0, st
-
-    kinds = [e["kind"] for e in tracing.flight_snapshot()]
-    assert "pipeline.stall" in kinds
-    assert "pipeline.drain" in kinds
-
-    snap = get_metrics().snapshot("persia_tpu_pipeline")
-    assert snap["persia_tpu_pipeline_depth"][""] == 3.0
-    assert snap["persia_tpu_pipeline_stalls"][""] >= 1.0
-    assert snap["persia_tpu_pipeline_drains"][""] >= 1.0
-
-    path = str(tmp_path / "pipe.trace.json")
-    assert tracing.trace_export(path) > 0
-    doc = json.loads(open(path).read())
-    names = {e["name"] for e in doc["traceEvents"]}
-    assert {"stage.feed", "stage.dense", "stage.psgrad"} <= names, names
-
-
 def test_sharded_feeder_gauge_and_spans(monkeypatch):
     """OBS PIN for the round-14 sharded feeder: a sharded cached run must
     land (a) one ``persia_tpu_feeder_shard_busy`` gauge series per
