@@ -9,7 +9,8 @@ same per-unique-row math (`persia_tpu/embedding/optim.py` — SGD / Adagrad
 (±vectorwise-shared) / Adam), expressed as static-shape XLA:
 
 1. sort ids, segment-sum duplicate gradients (the worker's per-sign
-   accumulation),
+   accumulation); a second sort of the sorted ids packs the distinct ones to
+   the front, so no id is gathered or scattered one element at a time,
 2. gather the touched rows + optimizer state,
 3. apply the optimizer math on the (N, dim) block,
 4. write the new rows back at strictly ascending, distinct indices: row by
@@ -75,22 +76,32 @@ def dedup_gradients(
     name no row) are folded into one out-of-vocab sentinel *before* the
     sort, so they sort last, land in row U flagged invalid and can never
     touch a real row — not even through weight decay, which applies to
-    every *touched* row. Rows past the sentinel hold uid 0 and a zero sum.
+    every *touched* row. Rows from U on hold the sentinel as their uid, and
+    past the sentinel's own row a zero sum.
+
+    The ids ride two sorts, and nothing N-wide is gathered or scattered but
+    the gradient rows: on the v5e an int32 gather or scatter takes its
+    elements one after another at 4.6-7.1 ns each, 0.49-0.76 ms a pass of the
+    cells' 106,496, where the two sorts take 0.10 and 0.045 ms (PERF.md, PR
+    31). The first sort must stay STABLE: equal ids keep their input order,
+    which fixes the order in which the segment sum adds their float32
+    gradients. The second sorts keys alone and need not be (stable it costs
+    0.06 ms more).
     """
     n = ids.shape[0]
     if mask is not None:
         ids = jnp.where(mask, ids, _PAD_SENTINEL)
         grads = grads * mask[..., None].astype(grads.dtype)
-    order = jnp.argsort(ids)
-    sids = ids[order]
+    sids, order = jax.lax.sort(
+        (ids, jnp.arange(n, dtype=jnp.int32)), num_keys=1, is_stable=True)
     sg = grads[order]
     is_new = jnp.concatenate(
         [jnp.ones((1,), dtype=bool), sids[1:] != sids[:-1]]
     )
     seg = jnp.cumsum(is_new) - 1  # (N,) segment index per sorted element, non-decreasing
     gsum = jax.ops.segment_sum(sg, seg, num_segments=n, indices_are_sorted=True)
-    uid = jnp.zeros((n,), dtype=ids.dtype).at[seg].set(sids, indices_are_sorted=True)
-    valid = (jnp.arange(n) <= seg[-1]) & (uid != _PAD_SENTINEL)
+    uid = jax.lax.sort(jnp.where(is_new, sids, _PAD_SENTINEL), is_stable=False)
+    valid = uid != _PAD_SENTINEL
     return uid, gsum, valid
 
 

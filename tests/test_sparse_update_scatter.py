@@ -10,8 +10,8 @@ that takes the scatter's place where the layout allows (ISSUE 29).
     27 rows a trip differs in Adam's first moment by 9e-10). On the chip the
     loop and the whole-N form it replaced are bit-equal (PERF.md, PR 27).
 (b) structural tests on the StableHLO of a jitted ``sparse_update``: the
-    flags on every scatter, and that the row scatters sit in the loop over
-    live rows.
+    flags on every scatter, that the row scatters sit in the loop over
+    live rows, and that ``dedup`` moves its ids by two sorts and no gather.
 (c) the row-write kernel (``_write_rows_dma``) through the Pallas TPU
     interpreter, which this file asks for itself: alone against a NumPy row
     assignment, and inside ``sparse_update`` with the DMA path forced, over
@@ -19,6 +19,9 @@ that takes the scatter's place where the layout allows (ISSUE 29).
     on the same inputs.
 (d) which path each array gets (``_row_write_path``) and the flight event
     ``sparse_update.row_write`` that says so.
+(e) ``dedup_gradients`` with its ids riding two sorts (ISSUE 31) against the
+    form it replaced (an N-wide index gather and a ``uid`` scatter), kept
+    here frozen: bit for bit, since no float sum changed its order.
 """
 
 import functools
@@ -197,9 +200,11 @@ def test_bf16_table_keeps_its_dtype_and_untouched_rows():
 
 # ------------------------------------------------- (b) what XLA is told
 
-def _scatters(cfg, n=27):
-    """(op name with its scopes, unique_indices, indices_are_sorted, inside a
-    while) of every stablehlo.scatter of a jitted sparse_update."""
+def _ops(cfg, kind, n=27):
+    """Every ``kind`` operation in the StableHLO of a jitted sparse_update, in
+    program order, as plain values (the module does not outlive this call):
+    its name with its scopes, whether it sits inside a while, its attributes
+    as text, its operand count and its first result's type."""
     state = su.init_sparse_state(cfg, V, D)
     lowered = jax.jit(lambda t, s, i, g: su.sparse_update(cfg, t, s, i, g)).lower(
         jnp.zeros((V, D)), state, jnp.zeros((n,), jnp.int32), jnp.zeros((n, D)))
@@ -210,15 +215,23 @@ def _scatters(cfg, n=27):
             for block in region.blocks:
                 for o in block.operations:
                     name = o.operation.name
-                    if name == "stablehlo.scatter":
-                        found.append((str(o.location).split('"')[1],
-                                      str(o.attributes["unique_indices"]) == "true",
-                                      str(o.attributes["indices_are_sorted"]) == "true",
-                                      in_while))
+                    if name == kind:
+                        found.append({
+                            "name": str(o.location).split('"')[1], "in_while": in_while,
+                            "attrs": {a: str(o.attributes[a]) for a in o.attributes},
+                            "operands": len(o.operands), "result": str(o.results[0].type)})
                     walk(o.operation, in_while or name == "stablehlo.while")
 
     walk(lowered.compiler_ir("stablehlo").operation, False)
     return found
+
+
+def _scatters(cfg, n=27):
+    """(op name with its scopes, unique_indices, indices_are_sorted, inside a
+    while) of every stablehlo.scatter of a jitted sparse_update."""
+    return [(o["name"], o["attrs"]["unique_indices"] == "true",
+             o["attrs"]["indices_are_sorted"] == "true", o["in_while"])
+            for o in _ops(cfg, "stablehlo.scatter", n)]
 
 
 @pytest.mark.parametrize("opt,scopes", [
@@ -242,10 +255,25 @@ def test_row_scatters_declare_unique_indices_inside_the_live_row_loop(opt, scope
 def test_dedup_scatters_declare_sorted_segments():
     found = _scatters(OPTS["adagrad"].config)
     dedup = [f for f in found if "/dedup/" in f[0]]
-    assert len(dedup) == 2  # the segment sum and the uid scatter
+    assert len(dedup) == 1  # the segment sum; the uid scatter went into a sort (ISSUE 31)
     for name, unique, is_sorted, in_while in dedup:
         assert is_sorted and not unique and not in_while, name
     assert len(found) == len(dedup) + 2
+
+
+def test_dedup_moves_its_ids_by_two_sorts_and_no_gather():
+    """The sorted ids are the first sort's keys and the distinct ids a second
+    sort's: no gather of int32 elements under ``dedup`` (the one gather left
+    there takes the gradient rows). The first sort is stable: it fixes the
+    order of the float sums."""
+    cfg = OPTS["adagrad"].config
+    gathers = [o for o in _ops(cfg, "stablehlo.gather") if "/dedup/" in o["name"]]
+    assert [o["result"] for o in gathers] == [f"tensor<27x{D}xf32>"], gathers
+    sorts = _ops(cfg, "stablehlo.sort")
+    assert len(sorts) == 2 and all("/dedup/" in o["name"] and not o["in_while"] for o in sorts), sorts
+    first, second = sorts
+    assert first["operands"] == 2 and first["attrs"]["is_stable"] == "true"  # ids and positions
+    assert second["operands"] == 1  # keys alone
 
 
 # ------------------------------------------------- (c) the row-write kernel
@@ -380,3 +408,73 @@ def test_this_process_takes_the_scatter_for_every_array():
     assert su._backend()[0] == "cpu"
     events, kernels = _traced_paths("adam", jnp.float32, 128)
     assert [e["path"] for e in events] == ["scatter"] * 3 and kernels == 0
+
+
+# ------------------------------------------------- (e) dedup against the form it replaced
+
+def _dedup_gradients_frozen(ids, grads, mask=None):
+    """``dedup_gradients`` as it stood until ISSUE 31, kept as the reference:
+    the sorted ids gathered back through the permutation, the distinct ids
+    compacted by a scatter, a tail of uid 0."""
+    n = ids.shape[0]
+    if mask is not None:
+        ids = jnp.where(mask, ids, su._PAD_SENTINEL)
+        grads = grads * mask[..., None].astype(grads.dtype)
+    order = jnp.argsort(ids)
+    sids = ids[order]
+    sg = grads[order]
+    is_new = jnp.concatenate(
+        [jnp.ones((1,), dtype=bool), sids[1:] != sids[:-1]]
+    )
+    seg = jnp.cumsum(is_new) - 1
+    gsum = jax.ops.segment_sum(sg, seg, num_segments=n, indices_are_sorted=True)
+    uid = jnp.zeros((n,), dtype=ids.dtype).at[seg].set(sids, indices_are_sorted=True)
+    valid = (jnp.arange(n) <= seg[-1]) & (uid != su._PAD_SENTINEL)
+    return uid, gsum, valid
+
+
+def _big_ids_case():
+    """The cells' N (26 slots x 4,096 samples) of skewed ids: 8% below 0,
+    40% at or past the vocabulary, a tenth masked out; 47% live, naming
+    22,510 distinct rows (the cells' steps name 19-21.5k)."""
+    rng = np.random.default_rng(31)
+    n, vocab = 106_496, 6_291_457
+    ids = np.minimum(rng.zipf(1.05, n), vocab + 5) - 3
+    return ids, (ids >= 0) & (ids < vocab) & (rng.random(n) < 0.9), vocab, 8
+
+
+# name -> (ids, live or None, vocab, dim). ``masked`` hands dedup_gradients
+# what sparse_update does (the case's mask and the ids that name a row),
+# ``unmasked`` the raw ids and no mask, negative and sentinel-valued ones
+# among them.
+DEDUP_CASES = {
+    **{f"{case}-masked": (ids, _live(ids, mask), V, D) for case, (ids, mask) in IDS_CASES.items()},
+    **{f"{case}-unmasked": (ids, None, V, D) for case, (ids, _) in IDS_CASES.items()},
+    "n_106496-masked": _big_ids_case(),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DEDUP_CASES))
+def test_dedup_is_bit_for_bit_the_form_it_replaced(case):
+    ids, live, vocab, dim = DEDUP_CASES[case]
+    n = len(ids)
+    ids = jnp.asarray(ids, jnp.int32)
+    grads = jnp.asarray(np.random.default_rng(n).normal(size=(n, dim)).astype(np.float32))
+
+    def outputs(dedup):
+        def f(i, g, m):
+            uid, gsum, valid = dedup(i, g, m)
+            return uid, gsum, valid, su.scatter_indices(uid, valid, vocab), jnp.sum(valid, dtype=jnp.int32)
+        return [np.asarray(x) for x in jax.jit(f)(ids, grads, None if live is None else jnp.asarray(live))]
+
+    uid, gsum, valid, sidx, n_live = outputs(su.dedup_gradients)
+    ref_uid, ref_gsum, ref_valid, ref_sidx, ref_n_live = outputs(_dedup_gradients_frozen)
+    np.testing.assert_array_equal(sidx, ref_sidx)  # all N positions
+    np.testing.assert_array_equal(gsum.view(np.uint32), ref_gsum.view(np.uint32))  # all N rows, by their bits
+    np.testing.assert_array_equal(valid, ref_valid)
+    assert n_live == ref_n_live
+    # the distinct ids, ascending, then the sentinel to the end (the frozen form: zeros)
+    distinct = len(np.unique(ids if live is None else np.where(live, ids, INT32_MAX)))
+    np.testing.assert_array_equal(uid[:distinct], ref_uid[:distinct])
+    assert (np.diff(uid[:distinct].astype(np.int64)) > 0).all()
+    assert (uid[distinct:] == INT32_MAX).all() and (ref_uid[distinct:] == 0).all()

@@ -1,12 +1,13 @@
 """Compiles for a described TPU v5e (no chip attached) at the benchmark
-cells' own widths: what the chip's compiler refuses or copies shows here,
-at no chip time (ISSUE 29).
+cells' own widths: what the chip's compiler refuses, copies or runs one
+element at a time shows here, at no chip time (ISSUES 29, 31).
 
 One file and a module fixture on purpose: only one process may hold the
 TPU's library, so the topology is described after a test of this file has
 started, never at import, and every such test lives here.
 """
 
+import functools
 import os
 import re
 
@@ -48,17 +49,23 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
+@functools.lru_cache(maxsize=None)  # two tests read each Adagrad cell's program: 20 s a compile
 def _compile_sparse_update(cfg, vocab, dim, sharding):
+    """(the compiled ``sparse_update``, its ``row_write`` flight events), built
+    as a one-chip TPU process builds it: ``jax.default_backend()`` still says
+    cpu here."""
     def shaped(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     state = {k: shaped(v.shape, v.dtype)
              for k, v in jax.eval_shape(lambda: su.init_sparse_state(cfg, vocab, dim)).items()}
     tracing.flight_clear()
-    compiled = jax.jit(
-        lambda t, s, i, g: su.sparse_update(cfg, t, s, i, g, mask=i >= 0), donate_argnums=(0, 1),
-    ).lower(shaped((vocab, dim), jnp.float32), state, shaped((N_IDS,), jnp.int32),
-            shaped((N_IDS, dim), jnp.float32)).compile()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(su, "_backend", lambda: ("tpu", 1))
+        compiled = jax.jit(
+            lambda t, s, i, g: su.sparse_update(cfg, t, s, i, g, mask=i >= 0), donate_argnums=(0, 1),
+        ).lower(shaped((vocab, dim), jnp.float32), state, shaped((N_IDS,), jnp.int32),
+                shaped((N_IDS, dim), jnp.float32)).compile()
     events = [e["attrs"] for e in tracing.flight_snapshot() if e["kind"] == "sparse_update.row_write"]
     return compiled, events
 
@@ -69,12 +76,10 @@ def _compile_sparse_update(cfg, vocab, dim, sharding):
     pytest.param("tb-cached-resident", Adam(lr=0.01), id="cached-adam"),
 ])
 def test_sparse_update_writes_rows_by_dma_in_place_at_the_cells_widths(
-        monkeypatch, one_chip, no_compile_cache, cell, opt):
+        one_chip, no_compile_cache, cell, opt):
     """The kernel compiles for the v5e, one call an array inside the loop
     over live rows, and the donated table and state are updated where they
     lie: no whole-array copy, a megabyte of scratch."""
-    # jax.default_backend() still says cpu here; the program is built for the chip
-    monkeypatch.setattr(su, "_backend", lambda: ("tpu", 1))
     vocab, dim = CELL_ROWS[cell], 128
     compiled, events = _compile_sparse_update(opt.config, vocab, dim, one_chip)
     assert [e["path"] for e in events] == ["dma"] * len(events) and events
@@ -91,3 +96,22 @@ def test_sparse_update_writes_rows_by_dma_in_place_at_the_cells_widths(
     # every array aliased to its output (rows padded to whole tiles of 8)
     assert mem.alias_size_in_bytes >= len(events) * vocab * dim * 4
     assert mem.temp_size_in_bytes < 16 * 2**20
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_ROWS))
+def test_dedup_runs_one_serial_fusion_and_sorts_its_ids(one_chip, no_compile_cache, cell):
+    """On the v5e a gather or scatter is a ``kCustom`` fusion that takes its
+    elements one after another (4.6-7.1 ns an int32, PERF.md section 5).
+    ``dedup`` keeps one, the segment sum with the gradient rows' gather fused
+    into its operand; the ids go through two sorts and loop fusions."""
+    compiled, _ = _compile_sparse_update(Adagrad(lr=0.05).config, CELL_ROWS[cell], 128, one_chip)
+    entry = re.search(r"^ENTRY .*?^}", compiled.as_text(), re.S | re.M).group(0)
+    dedup = [line for line in entry.splitlines() if re.search(r'op_name="[^"]*/dedup/', line)]
+    serial = [line for line in dedup if "kind=kCustom" in line]
+    assert len(serial) == 1, [line[:200] for line in serial]
+    assert re.search(rf'= f32\[{N_IDS},128\]\S* fusion\(.*op_name="[^"]*/dedup/scatter-add"', serial[0]), serial[0][:300]
+    sorts = [line for line in dedup if re.search(r"\bsort\(", line)]
+    assert len(sorts) == 2 and "is_stable=true" in sorts[0], [line[:200] for line in sorts]
+    for line in dedup:  # every other fusion of N int32: a loop fusion
+        if re.search(rf"= \(?s32\[{N_IDS}\].* fusion\(", line):
+            assert "kind=kLoop" in line, line[:300]
