@@ -1,20 +1,28 @@
-"""Flash attention as a Pallas TPU kernel.
+"""Flash attention as Pallas TPU kernels.
 
 Tiled online-softmax attention: the [L, L] score matrix is never
-materialized in HBM. Grid = (B*H, q_blocks, k_blocks); the innermost grid
-dimension is sequential on TPU, so VMEM scratch carries the (m, l, acc)
-online-softmax state across k blocks and the output block is written once on
-the last k step. fp32 accumulation regardless of input dtype; MXU matmuls via
-``preferred_element_type``.
+materialized in HBM. fp32 accumulation regardless of input dtype; MXU matmuls
+via ``preferred_element_type``. Two entry points:
 
-The kernel is compiled by Mosaic unless the caller asks for
+``flash_attention`` (causal or full, one K/V head a query head): grid =
+(B*H, q_blocks, k_blocks); the innermost grid dimension is sequential on TPU,
+so VMEM scratch carries the (m, l, acc) online-softmax state across k blocks
+and the output block is written once on the last k step. Its backward
+recomputes attention densely under XLA (``@jax.custom_vjp``): exact
+gradients, O(L^2) memory on the backward only, so it is for sequences whose
+dense scores fit. No model of the zoo calls it; ``parallel/sequence.py``'s
+ring attention takes dense blocks of its own.
+
+``block_diffusion_attention`` (K/V heads shared by groups of query heads, the
+block-diffusion mask of ``(seq_len, block_len)``, dead tile pairs never
+visited): forward, dq and dk/dv are all kernels and only the log-sum-exp a
+row is kept between them, so nothing is L^2 anywhere. ``models/sdar_moe.py``
+runs on it, at 2 x 8,192 positions and 32 query heads over 4 K/V heads in
+the benchmark's sequence cell.
+
+The kernels are compiled by Mosaic unless the caller asks for
 ``interpret=True`` (the CPU tests do); nothing picks the interpreter on the
-caller's behalf. The backward pass recomputes attention densely under XLA
-(``@jax.custom_vjp``) — exact gradients, O(L^2) memory on the backward only.
-
-Used by the model zoo for long user-behavior sequences (DIN-style attention)
-and usable as the local block of ring attention for L/n still too large for
-dense scores.
+caller's behalf.
 """
 
 from __future__ import annotations
@@ -25,6 +33,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -170,3 +180,333 @@ def flash_attention(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     return _flash(q, k, v, scale, causal, block_q, block_k, interpret)
+
+
+# ---------------------------------------------------------------------------
+# Block-diffusion attention: K/V heads shared by groups of query heads, the
+# mask given by (seq_len, block_len), dead (q tile, k tile) pairs never
+# visited, and a backward that keeps only the row statistics.
+# ---------------------------------------------------------------------------
+
+# Every product of these kernels takes its operands as they come (bfloat16 on
+# the chip) in one pass and sums in float32, whatever the process's default
+# matmul precision says: Mosaic refuses a bfloat16 operand at "highest".
+_ONE_PASS = jax.lax.Precision.DEFAULT
+# Square tiles of 512 positions: at L 4096 on the v5e, 256 and 1024 both run
+# slower (my chip runs, PR 33). A shorter sequence is one tile of its length.
+BLOCK_DIFFUSION_TILE = 512
+ATTENTION_OUT, ATTENTION_LSE = "block_diffusion_attention_out", "block_diffusion_attention_lse"
+
+
+def block_diffusion_allowed(q_pos, k_pos, seq_len: int, block_len: int):
+    """Whether query position ``q_pos`` may read key position ``k_pos`` of the
+    ``2 * seq_len`` positions ``[noised | clean]`` (numpy or traced int32
+    arrays, non-negative; they broadcast). With ``beta(i) = i // block_len``
+    the block of position ``i`` of either half: a noised query reads the
+    noised keys of its own block and the clean keys of earlier blocks; a
+    clean query reads the clean keys of its own and earlier blocks and no
+    noised key."""
+    traced = isinstance(q_pos, jax.Array) or isinstance(k_pos, jax.Array)
+    div = jax.lax.div if traced else (lambda a, b: a // b)
+    where = jnp.where if traced else np.where
+    q_clean, k_clean = q_pos >= seq_len, k_pos >= seq_len
+    qb = div(q_pos - where(q_clean, seq_len, 0), block_len)
+    kb = div(k_pos - where(k_clean, seq_len, 0), block_len)
+    # and/or, not a select between masks: Mosaic has no select of 1-bit vectors
+    return (k_clean & (kb <= qb) & (q_clean | (kb < qb))) | (~q_clean & ~k_clean & (kb == qb))
+
+
+def block_diffusion_mask(seq_len: int, block_len: int) -> np.ndarray:
+    """The dense (2L, 2L) mask, True where a query (row) reads a key (column)."""
+    pos = np.arange(2 * seq_len)
+    return block_diffusion_allowed(pos[:, None], pos[None, :], seq_len, block_len)
+
+
+def _live_tiles(seq_len: int, block_len: int, tile: int):
+    """Which (q tile, k tile) pairs of the mask hold any allowed pair
+    (``live``) and which hold nothing else (``full``): (n, n) bool each."""
+    n = 2 * seq_len // tile
+    live, full = np.zeros((n, n), bool), np.zeros((n, n), bool)
+    pos = np.arange(2 * seq_len)
+    for qi in range(n):
+        rows = block_diffusion_allowed(
+            pos[qi * tile:(qi + 1) * tile, None], pos[None, :], seq_len, block_len)
+        tiles = rows.reshape(tile, n, tile)
+        live[qi], full[qi] = tiles.any(axis=(0, 2)), tiles.all(axis=(0, 2))
+    return live, full
+
+
+def _visit_tables(live: np.ndarray, full: np.ndarray):
+    """The live (row, column) pairs of ``live`` in row-major order, as flat
+    int32 arrays for scalar prefetch: each pair's row, its column, whether the
+    pair is whole, whether it is the first of its row, whether the last. A
+    kernel's grid runs over the pairs and nothing else: a dead pair costs no
+    grid step (0.35 us each; at L 4096 and tiles of 512, 80 pairs of 256)."""
+    rows, cols = np.nonzero(live)
+    first = np.concatenate([[True], rows[1:] != rows[:-1]])
+    last = np.concatenate([rows[1:] != rows[:-1], [True]])
+    as_i32 = lambda x: jnp.asarray(np.asarray(x, np.int32))
+    return tuple(as_i32(x) for x in (rows, cols, full[rows, cols], first, last))
+
+
+def _bd_scores(q, k, scale, q_tile, k_tile, whole, *, tile, seq_len, block_len):
+    """Scaled scores of one (q tile, k tile) pair in float32, keys down and
+    queries across, masked unless the pair is whole. In this orientation a
+    query's statistics are a row, reduced over sublanes and stored lane-dense."""
+    s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32, precision=_ONE_PASS) * scale
+
+    def masked(s):
+        q_pos = q_tile * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        k_pos = k_tile * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        return jnp.where(block_diffusion_allowed(q_pos, k_pos, seq_len, block_len), s, _NEG_BIG)
+
+    return jax.lax.cond(whole == 1, lambda s: s, masked, s)
+
+
+def _bd_fwd_kernel(row_ref, col_ref, whole_ref, first_ref, last_ref, q_ref, k_ref, v_ref,
+                   o_ref, lse_ref, m_ref, l_ref, acc_ref, *, scale, tile, seq_len, block_len):
+    p = pl.program_id(2)
+
+    @pl.when(first_ref[p] == 1)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, _NEG_BIG)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    v = v_ref[0]
+    st = _bd_scores(q_ref[0], k_ref[0], scale, row_ref[p], col_ref[p], whole_ref[p], tile=tile,
+                    seq_len=seq_len, block_len=block_len)
+    m_prev = m_ref[:1]
+    m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+    # the first tile a query visits holds a key it may read (its own block, or
+    # block 0 of the clean half), so m_new is a real score from then on and a
+    # masked score's exp is 0
+    pt = jnp.exp(st - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[:] = jnp.broadcast_to(l_ref[:1] * corr + jnp.sum(pt, axis=0, keepdims=True), l_ref.shape)
+    # the output is accumulated transposed, (D, queries): v^T p^T
+    acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+        v, pt.astype(v.dtype), (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=_ONE_PASS)
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+
+    @pl.when(last_ref[p] == 1)
+    def _finalize():
+        o_ref[0] = jnp.transpose(acc_ref[:] / l_ref[:1]).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_ref[:1] + jnp.log(l_ref[:1])
+
+
+def _bd_dq_kernel(row_ref, col_ref, whole_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref,
+                  lse_ref, delta_ref, dq_ref, acc_ref, *, scale, tile, seq_len, block_len):
+    p = pl.program_id(2)
+
+    @pl.when(first_ref[p] == 1)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    k = k_ref[0]
+    st = _bd_scores(q_ref[0], k, scale, row_ref[p], col_ref[p], whole_ref[p], tile=tile,
+                    seq_len=seq_len, block_len=block_len)
+    pt = jnp.exp(st - lse_ref[0, 0])
+    dpt = jax.lax.dot_general(v_ref[0], do_ref[0], (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32, precision=_ONE_PASS)
+    dst = (pt * (dpt - delta_ref[0, 0])).astype(k.dtype)
+    acc_ref[:] += jax.lax.dot_general(dst, k, (((0,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32, precision=_ONE_PASS)
+
+    @pl.when(last_ref[p] == 1)
+    def _finalize():
+        dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
+
+
+def _bd_dkv_kernel(row_ref, col_ref, whole_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref,
+                   lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, tile, group,
+                   seq_len, block_len):
+    # here a "row" of the tables is a k tile and its "columns" the q tiles that read it
+    p, g = pl.program_id(2), pl.program_id(3)
+
+    @pl.when((first_ref[p] == 1) & (g == 0))
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    q, do = q_ref[0], do_ref[0]
+    st = _bd_scores(q, k_ref[0], scale, col_ref[p], row_ref[p], whole_ref[p], tile=tile,
+                    seq_len=seq_len, block_len=block_len)
+    pt = jnp.exp(st - lse_ref[0, 0])
+    dv_acc[:] += jax.lax.dot_general(pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32, precision=_ONE_PASS)
+    dpt = jax.lax.dot_general(v_ref[0], do, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32, precision=_ONE_PASS)
+    dst = (pt * (dpt - delta_ref[0, 0])).astype(q.dtype)
+    dk_acc[:] += jax.lax.dot_general(dst, q, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32, precision=_ONE_PASS)
+
+    @pl.when((last_ref[p] == 1) & (g == group - 1))
+    def _finalize():
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+_STAT_ROWS = 8  # the forward's running max and sum a query: one row, kept in a whole (8, tile) tile
+
+
+def _bd_plan(q, k, seq_len, block_len, tile):
+    b, t, hq, d = q.shape
+    hkv = k.shape[2]
+    if t != 2 * seq_len or k.shape != (b, t, hkv, d) or hq % hkv:
+        raise ValueError(f"q {q.shape} and k {k.shape} do not fit seq_len {seq_len}")
+    tile = min(tile, seq_len)
+    if seq_len % tile or tile % 8 or d % 128:
+        raise ValueError(f"seq_len {seq_len} must be a multiple of the tile {tile}, the tile of "
+                         f"8, and the head size {d} of 128")
+    return b, t, hq, hkv, d, tile
+
+
+def _bd_forward(q, k, v, seq_len, block_len, scale, tile, interpret):
+    b, t, hq, hkv, d, tile = _bd_plan(q, k, seq_len, block_len, tile)
+    group = hq // hkv
+    tables = _visit_tables(*_live_tiles(seq_len, block_len, tile))
+    # heads stay where the projections left them: a head is a 128-lane column
+    # block of the (B, T, H * D) array, so nothing is transposed in HBM
+    q2, k2, v2 = (x.reshape(b, t, -1) for x in (q, k, v))
+    q_at = lambda bi, h, p, row, col, *_: (bi, row[p], h)
+    kv_at = lambda bi, h, p, row, col, *_: (bi, col[p], h // group)
+    out, lse = pl.pallas_call(
+        functools.partial(_bd_fwd_kernel, scale=scale, tile=tile, seq_len=seq_len,
+                          block_len=block_len),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(b, hq, tables[0].shape[0]),
+            in_specs=[pl.BlockSpec((1, tile, d), q_at), pl.BlockSpec((1, tile, d), kv_at),
+                      pl.BlockSpec((1, tile, d), kv_at)],
+            out_specs=[pl.BlockSpec((1, tile, d), q_at),
+                       pl.BlockSpec((1, 1, 1, tile), lambda bi, h, p, row, *_: (bi, h, 0, row[p]))],
+            scratch_shapes=[pltpu.VMEM((_STAT_ROWS, tile), jnp.float32),
+                            pltpu.VMEM((_STAT_ROWS, tile), jnp.float32),
+                            pltpu.VMEM((d, tile), jnp.float32)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((b, t, hq * d), q.dtype),
+                   jax.ShapeDtypeStruct((b, hq, 1, t), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="block_diffusion_attention_fwd",
+    )(*tables, q2, k2, v2)
+    return out.reshape(b, t, hq, d), lse
+
+
+def _bd_backward(q, k, v, out, lse, do, seq_len, block_len, scale, tile, interpret):
+    b, t, hq, hkv, d, tile = _bd_plan(q, k, seq_len, block_len, tile)
+    group = hq // hkv
+    live, full = _live_tiles(seq_len, block_len, tile)
+    # sum_j P_ij dP_ij = o_i . do_i: a row statistic too, lane-dense like lse
+    delta = jnp.einsum("bthd,bthd->bht", out.astype(jnp.float32),
+                       do.astype(jnp.float32))[:, :, None, :]
+    do = do.astype(q.dtype)
+    q2, k2, v2, do2 = (x.reshape(b, t, -1) for x in (q, k, v, do))
+
+    tables = _visit_tables(live, full)
+    q_at = lambda bi, h, p, row, col, *_: (bi, row[p], h)
+    kv_at = lambda bi, h, p, row, col, *_: (bi, col[p], h // group)
+    stat_at = lambda bi, h, p, row, *_: (bi, h, 0, row[p])
+    dq = pl.pallas_call(
+        functools.partial(_bd_dq_kernel, scale=scale, tile=tile, seq_len=seq_len,
+                          block_len=block_len),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(b, hq, tables[0].shape[0]),
+            in_specs=[pl.BlockSpec((1, tile, d), q_at), pl.BlockSpec((1, tile, d), kv_at),
+                      pl.BlockSpec((1, tile, d), kv_at), pl.BlockSpec((1, tile, d), q_at),
+                      pl.BlockSpec((1, 1, 1, tile), stat_at),
+                      pl.BlockSpec((1, 1, 1, tile), stat_at)],
+            out_specs=pl.BlockSpec((1, tile, d), q_at),
+            scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, t, hq * d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="block_diffusion_attention_dq",
+    )(*tables, q2, k2, v2, do2, lse, delta)
+
+    # for a k tile, the q tiles that read it: the transposed tables; the
+    # group's query heads innermost, so that a k tile's sums stay in VMEM
+    tables = _visit_tables(live.T, full.T)
+    kv_at = lambda bi, hk, p, g, row, col, *_: (bi, row[p], hk)
+    q_at = lambda bi, hk, p, g, row, col, *_: (bi, col[p], hk * group + g)
+    stat_at = lambda bi, hk, p, g, row, col, *_: (bi, hk * group + g, 0, col[p])
+    dk, dv = pl.pallas_call(
+        functools.partial(_bd_dkv_kernel, scale=scale, tile=tile, group=group,
+                          seq_len=seq_len, block_len=block_len),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(b, hkv, tables[0].shape[0], group),
+            in_specs=[pl.BlockSpec((1, tile, d), q_at), pl.BlockSpec((1, tile, d), kv_at),
+                      pl.BlockSpec((1, tile, d), kv_at), pl.BlockSpec((1, tile, d), q_at),
+                      pl.BlockSpec((1, 1, 1, tile), stat_at),
+                      pl.BlockSpec((1, 1, 1, tile), stat_at)],
+            out_specs=[pl.BlockSpec((1, tile, d), kv_at), pl.BlockSpec((1, tile, d), kv_at)],
+            scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32),
+                            pltpu.VMEM((tile, d), jnp.float32)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((b, t, hkv * d), k.dtype),
+                   jax.ShapeDtypeStruct((b, t, hkv * d), v.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="block_diffusion_attention_dkv",
+    )(*tables, q2, k2, v2, do2, lse, delta)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _bd_attention(q, k, v, seq_len, block_len, scale, tile, interpret):
+    return _bd_forward(q, k, v, seq_len, block_len, scale, tile, interpret)[0]
+
+
+def _bd_attention_fwd(q, k, v, seq_len, block_len, scale, tile, interpret):
+    out, lse = _bd_forward(q, k, v, seq_len, block_len, scale, tile, interpret)
+    # named, so that a caller that recomputes its layer in the backward can keep
+    # these two (jax.checkpoint_policies.save_only_these_names) and not run the
+    # forward kernel a second time
+    out, lse = checkpoint_name(out, ATTENTION_OUT), checkpoint_name(lse, ATTENTION_LSE)
+    return out, (q, k, v, out, lse)
+
+
+def _bd_attention_bwd(seq_len, block_len, scale, tile, interpret, res, do):
+    return _bd_backward(*res, do, seq_len, block_len, scale, tile, interpret)
+
+
+_bd_attention.defvjp(_bd_attention_fwd, _bd_attention_bwd)
+
+
+def block_diffusion_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    seq_len: int,
+    block_len: int,
+    tile: int = BLOCK_DIFFUSION_TILE,
+    interpret: bool = False,
+) -> jax.Array:
+    """Attention under the block-diffusion mask of ``(seq_len, block_len)``
+    (``block_diffusion_allowed``) over ``[noised | clean]``: q [B, 2L, Hq, D],
+    k and v [B, 2L, Hkv, D] -> [B, 2L, Hq, D]; query head ``g`` reads K/V head
+    ``g // (Hq // Hkv)``.
+
+    Forward and backward are Pallas kernels over square tiles of ``tile``
+    positions. Each q tile visits only the k tiles that hold a pair it may
+    read (a prefetched table; 80 of 256 tile pairs at L 4096 and tile 512),
+    masks only the tiles that are not whole, and the backward (one kernel for
+    dq, one for dk and dv summed over the group's query heads) recomputes the
+    probabilities from the forward's log-sum-exp a row: nothing L^2 is ever
+    held. Products take the inputs' dtype as operands and accumulate in
+    float32; the softmax is float32, over scores scaled by ``D ** -0.5``.
+    ``tile`` is for the CPU tests, which cut a short sequence into several.
+    """
+    if q.ndim != 4:
+        raise ValueError(f"expected [B, 2L, H, D], got shape {q.shape}")
+    return _bd_attention(q, k, v, int(seq_len), int(block_len), q.shape[-1] ** -0.5, int(tile),
+                         interpret)

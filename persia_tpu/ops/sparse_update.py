@@ -271,16 +271,20 @@ def _backend() -> Tuple[str, int]:
 
 def _row_write_path(full: jnp.ndarray) -> str:
     """``"dma"`` where ``_write_rows_dma`` can write ``full``'s rows, else
-    ``"scatter"``. The kernel needs a row that is contiguous 512 B pieces in
-    the TPU's (8, 128) tiling of 32-bit words: float32 and a whole number of
-    128-lane vectors. bfloat16 packs two rows to a sublane, and a (V, 1) or
-    dim-16 row is a sliver of a tile. It needs one device too: GSPMD cannot
-    partition a custom call, so a process that sees several devices (tables
-    row-sharded by ``shard_fused_state``, pools on the cached tier's
-    ``data`` mesh) keeps the compiler's scatter, as every CPU run does."""
+    ``"scatter"``. The kernel copies one row a DMA, and Mosaic slices one row
+    out of a 32-bit array only where the array is one tile column wide: a
+    float32 row of exactly 128 lanes, one contiguous 512 B piece of the TPU's
+    (8, 128) tiling. A wider row (256 lanes as much as an 8 KB row of 2,048)
+    is 512 B pieces 4 KB apart, and the compiler refuses the slice ("Slice
+    shape along dimension 0 must be aligned to tiling (8)", in VMEM and in
+    HBM alike; PERF.md, PR 33); bfloat16 packs two rows to a sublane, and a
+    (V, 1) or dim-16 row is a sliver of a tile. It needs one device too:
+    GSPMD cannot partition a custom call, so a process that sees several
+    devices (tables row-sharded by ``shard_fused_state``, pools on the cached
+    tier's ``data`` mesh) keeps the compiler's scatter, as every CPU run does."""
     platform, devices = _backend()
-    contiguous_rows = full.dtype == jnp.float32 and full.shape[1] % 128 == 0
-    return "dma" if platform == "tpu" and devices == 1 and contiguous_rows else "scatter"
+    one_tile_column = full.dtype == jnp.float32 and full.shape[1] == 128
+    return "dma" if platform == "tpu" and devices == 1 and one_tile_column else "scatter"
 
 
 # Positions a trip of the kernel's loop handles: unrolled by hand, since

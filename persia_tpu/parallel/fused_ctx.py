@@ -93,7 +93,11 @@ def batch_to_fused(
         "ids": ids,
     }
     if batch.labels:
-        out["labels"] = [np.asarray(l.data, np.float32) for l in batch.labels]
+        # integer labels (a sequence model's target ids) stay integers
+        out["labels"] = [
+            np.asarray(l.data, np.int32 if np.issubdtype(l.data.dtype, np.integer) else np.float32)
+            for l in batch.labels
+        ]
     return out
 
 

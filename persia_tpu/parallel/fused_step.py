@@ -346,6 +346,11 @@ def build_fused_train_step(
     """
     slot_order = list(slot_order or sorted(specs))
     groups = group_stacked_specs(specs, slot_order) if stack else None
+    # a model may state its own loss, over all of the batch's labels, and what
+    # a step hands back beside it (``models/sdar_moe.py``); the click models
+    # state neither and keep ``loss_fn`` on the first label and the sigmoid
+    model_loss = getattr(model, "loss", None)
+    model_outputs = getattr(model, "outputs", jax.nn.sigmoid)
 
     def step(state: FusedTrainState, batch: Dict):
         ids = batch["ids"]
@@ -373,7 +378,10 @@ def build_fused_train_step(
                 logits = model.apply(variables, batch["dense"], model_emb, train=True)
                 new_stats = state.batch_stats
             with jax.named_scope("loss"):
-                loss = loss_fn(logits, batch["labels"][0])
+                if model_loss is not None:
+                    loss = model_loss(logits, batch["labels"])
+                else:
+                    loss = loss_fn(logits, batch["labels"][0])
             return loss, (logits, new_stats)
 
         (loss, (logits, new_stats)), (param_grads, emb_grads) = jax.value_and_grad(
@@ -443,7 +451,7 @@ def build_fused_train_step(
             emb_batch_state=batch_state,
             step=state.step + 1,
         )
-        return new_state, (loss, jax.nn.sigmoid(logits))
+        return new_state, (loss, model_outputs(logits))
 
     if not jit:
         return step
@@ -508,7 +516,7 @@ def build_fused_eval_step(model, specs, slot_order=None, stack: bool = False):
         if state.batch_stats:
             variables["batch_stats"] = state.batch_stats
         logits = model.apply(variables, batch["dense"], model_emb, train=False)
-        return jax.nn.sigmoid(logits)
+        return getattr(model, "outputs", jax.nn.sigmoid)(logits)
 
     return jax.jit(eval_step)
 

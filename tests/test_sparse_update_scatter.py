@@ -374,7 +374,9 @@ def _traced_paths(opt, dtype, dim):
 
 @pytest.mark.parametrize("platform,devices,dtype,dim,opt,want", [
     ("tpu", 1, "float32", 128, "adagrad", {"table": "dma", "acc": "dma"}),
-    ("tpu", 1, "float32", 256, "adam", {"table": "dma", "m": "dma", "v": "dma"}),
+    # per array: one row of a wider array is no contiguous piece (Mosaic refuses the slice)
+    ("tpu", 1, "float32", 256, "adam", {"table": "scatter", "m": "scatter", "v": "scatter"}),
+    ("tpu", 1, "float32", 2048, "adagrad", {"table": "scatter", "acc": "scatter"}),
     ("tpu", 1, "float32", 128, "sgd_wd", {"table": "dma"}),
     # per array: the (V, 1) accumulator is a sliver of a tile
     ("tpu", 1, "float32", 128, "adagrad_vw", {"table": "dma", "acc": "scatter"}),

@@ -115,3 +115,68 @@ def test_dedup_runs_one_serial_fusion_and_sorts_its_ids(one_chip, no_compile_cac
     for line in dedup:  # every other fusion of N int32: a loop fusion
         if re.search(rf"= \(?s32\[{N_IDS}\].* fusion\(", line):
             assert "kind=kLoop" in line, line[:300]
+
+
+# ---- the sequence cell's kernels and rows (sdar-ep8-bd4-seq4k, ISSUE 33) ----
+
+@pytest.fixture
+def highest_by_default():
+    """What ``perf/harness.py`` sets for a configuration that states
+    ``highest``: the kernels have to compile under it (the first chip run of
+    the cell did not: Mosaic refuses a bfloat16 operand at fp32 precision)."""
+    before = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", "highest")
+    yield
+    jax.config.update("jax_default_matmul_precision", before)
+
+
+@pytest.mark.parametrize("what", ["forward", "backward"])
+def test_block_diffusion_attention_compiles_at_the_cells_widths(
+        one_chip, no_compile_cache, highest_by_default, what):
+    """32 query heads over 4 K/V heads of 128, 2 x 8,192 positions, blocks of
+    4, tiles of 512: three Mosaic kernels, nothing L^2 in HBM."""
+    from persia_tpu.ops.flash_attention import block_diffusion_attention
+
+    length = 4096
+    q = jax.ShapeDtypeStruct((2, 2 * length, 32, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 2 * length, 4, 128), jnp.bfloat16, sharding=one_chip)
+
+    def forward(q, k, v):
+        return block_diffusion_attention(q, k, v, length, 4)
+
+    def backward(q, k, v):
+        return jax.grad(lambda *a: forward(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(forward if what == "forward" else backward).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    kernels = sorted(set(re.findall(r"block_diffusion_attention_\w+", text)))
+    want = ["block_diffusion_attention_fwd"] if what == "forward" else [
+        "block_diffusion_attention_dkv", "block_diffusion_attention_dq", "block_diffusion_attention_fwd"]
+    assert [k for k in want if any(k in name for name in kernels)] == want, kernels
+    # the scores of one (batch, head) alone would be 256 MB in float32
+    assert compiled.memory_analysis().temp_size_in_bytes < 600 * 2**20
+
+
+def test_rows_wider_than_one_tile_column_keep_the_scatter(one_chip, no_compile_cache):
+    """An 8 KB row is no contiguous piece of the (8, 128) tiling: Mosaic
+    refuses the one-row slice the row-write kernel copies, at 256 lanes as at
+    2,048, so ``_row_write_path`` sends such rows to the compiler's scatter,
+    and the token table of the sequence cell is still updated where it lies."""
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(su._write_rows_dma, donate_argnums=(0,)).lower(
+            shaped((4096, 256), jnp.float32), shaped((1024,), jnp.int32),
+            shaped((1024, 256), jnp.float32)).compile()
+    cfg, vocab, dim, n = Adagrad(lr=0.01).config, 18_992, 2048, 16_384
+    tracing.flight_clear()
+    state = {k: shaped(v.shape, v.dtype)
+             for k, v in jax.eval_shape(lambda: su.init_sparse_state(cfg, vocab, dim)).items()}
+    import unittest.mock
+
+    with unittest.mock.patch.object(su, "_backend", lambda: ("tpu", 1)):
+        compiled = jax.jit(functools.partial(su.sparse_update, cfg), donate_argnums=(0, 1)).lower(
+            shaped((vocab, dim), jnp.float32), state, shaped((n,), jnp.int32),
+            shaped((n, dim), jnp.float32)).compile()
+    events = [e["attrs"] for e in tracing.flight_snapshot() if e["kind"] == "sparse_update.row_write"]
+    assert [(e["array"], e["path"]) for e in events] == [("table", "scatter"), ("acc", "scatter")]
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * vocab * dim * 4
