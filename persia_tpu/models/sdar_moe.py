@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from persia_tpu.ops.flash_attention import (
-    ATTENTION_LSE, ATTENTION_OUT, BLOCK_DIFFUSION_TILE, block_diffusion_attention,
+    ATTENTION_LSE, ATTENTION_OUT, block_diffusion_attention, block_diffusion_plan,
 )
 from persia_tpu.ops.grouped_matmul import grouped_matmul, grouped_outer
 from persia_tpu.tracing import record_event
@@ -230,10 +230,12 @@ class SDARMoE:
         angle = (jnp.arange(t, dtype=jnp.float32) % seq_len)[:, None] * inv[None, :]
         cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)
         sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)
+        # with what the attention kernels execute a head and sequence: visits by
+        # the tile's kind, sub-tiles executed of the visited, pairs beside the live
         record_event("sdar_moe.paths", attention="pallas_block_mask",
                      experts="ragged_dot", seq_len=seq_len, block_len=self.block_len,
-                     tile=min(BLOCK_DIFFUSION_TILE, seq_len), held=self.n_held,
-                     pick_chunk=self.pick_chunk(b * t))
+                     held=self.n_held, pick_chunk=self.pick_chunk(b * t),
+                     **block_diffusion_plan(seq_len, self.block_len))
 
         # each layer is recomputed in the backward, but for its attention
         # kernel's output and row statistics, which are kept (150 MB a layer at

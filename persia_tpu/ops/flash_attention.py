@@ -15,10 +15,11 @@ ring attention takes dense blocks of its own.
 
 ``block_diffusion_attention`` (K/V heads shared by groups of query heads, the
 block-diffusion mask of ``(seq_len, block_len)``, dead tile pairs never
-visited): forward, dq and dk/dv are all kernels and only the log-sum-exp a
-row is kept between them, so nothing is L^2 anywhere. ``models/sdar_moe.py``
-runs on it, at 2 x 8,192 positions and 32 query heads over 4 K/V heads in
-the benchmark's sequence cell.
+visited, a tile the mask cuts walked by sub-tiles over what it holds):
+forward, dq and dk/dv are all kernels and only the log-sum-exp a row is kept
+between them, so nothing is L^2 anywhere. ``models/sdar_moe.py`` runs on it,
+at 2 x 8,192 positions and 32 query heads over 4 K/V heads in the
+benchmark's sequence cell.
 
 The kernels are compiled by Mosaic unless the caller asks for
 ``interpret=True`` (the CPU tests do); nothing picks the interpreter on the
@@ -29,6 +30,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 from typing import Optional
 
 import jax
@@ -194,25 +196,32 @@ def flash_attention(
 _ONE_PASS = jax.lax.Precision.DEFAULT
 # Square tiles of 512 positions: at L 4096 on the v5e, 256 and 1024 both run
 # slower (my chip runs, PR 33). A shorter sequence is one tile of its length.
+# A tile the mask cuts is walked by sub-tiles of a quarter of it (``_sub_tile``)
+# and costs 0.4 to 1.0 of a whole one (0.9-1.3 us on the noised diagonal,
+# 1.4-2.1 in a triangle, against 1.8-2.1), where it cost 1.4-1.6 of one
+# (3.1-3.5 us) while its mask was computed position by position on the tile's
+# grid, 1.5 us a visit in each kernel (my chip runs, PR 34).
 BLOCK_DIFFUSION_TILE = 512
 ATTENTION_OUT, ATTENTION_LSE = "block_diffusion_attention_out", "block_diffusion_attention_lse"
+# What a (q tile, k tile) pair holds. A tile the mask cuts is a diagonal tile
+# of one of three kinds, named by the test a key's block passes against the
+# query's: the noised diagonal, noised queries on clean keys, the clean diagonal.
+_DEAD, _WHOLE, _EQ, _LT, _LE = range(5)
+_CUT = {_EQ: operator.eq, _LT: operator.lt, _LE: operator.le}  # key block ? query block
+_KIND_NAMES = {_WHOLE: "whole", _EQ: "noised_diagonal", _LT: "noised_on_clean", _LE: "clean_diagonal"}
 
 
 def block_diffusion_allowed(q_pos, k_pos, seq_len: int, block_len: int):
     """Whether query position ``q_pos`` may read key position ``k_pos`` of the
-    ``2 * seq_len`` positions ``[noised | clean]`` (numpy or traced int32
-    arrays, non-negative; they broadcast). With ``beta(i) = i // block_len``
-    the block of position ``i`` of either half: a noised query reads the
-    noised keys of its own block and the clean keys of earlier blocks; a
-    clean query reads the clean keys of its own and earlier blocks and no
-    noised key."""
-    traced = isinstance(q_pos, jax.Array) or isinstance(k_pos, jax.Array)
-    div = jax.lax.div if traced else (lambda a, b: a // b)
-    where = jnp.where if traced else np.where
+    ``2 * seq_len`` positions ``[noised | clean]`` (numpy int arrays,
+    non-negative; they broadcast). With ``beta(i) = i // block_len`` the block
+    of position ``i`` of either half: a noised query reads the noised keys of
+    its own block and the clean keys of earlier blocks; a clean query reads
+    the clean keys of its own and earlier blocks and no noised key. The
+    definition: the kernels take a cut tile's mask from its kind."""
     q_clean, k_clean = q_pos >= seq_len, k_pos >= seq_len
-    qb = div(q_pos - where(q_clean, seq_len, 0), block_len)
-    kb = div(k_pos - where(k_clean, seq_len, 0), block_len)
-    # and/or, not a select between masks: Mosaic has no select of 1-bit vectors
+    qb = (q_pos - np.where(q_clean, seq_len, 0)) // block_len
+    kb = (k_pos - np.where(k_clean, seq_len, 0)) // block_len
     return (k_clean & (kb <= qb) & (q_clean | (kb < qb))) | (~q_clean & ~k_clean & (kb == qb))
 
 
@@ -222,50 +231,131 @@ def block_diffusion_mask(seq_len: int, block_len: int) -> np.ndarray:
     return block_diffusion_allowed(pos[:, None], pos[None, :], seq_len, block_len)
 
 
-def _live_tiles(seq_len: int, block_len: int, tile: int):
-    """Which (q tile, k tile) pairs of the mask hold any allowed pair
-    (``live``) and which hold nothing else (``full``): (n, n) bool each."""
+@functools.lru_cache(maxsize=8)
+def _live_tiles(seq_len: int, block_len: int, tile: int) -> np.ndarray:
+    """The kind of every (q tile, k tile) pair of the mask, (n, n) int32:
+    ``_DEAD`` (no allowed pair), ``_WHOLE`` (nothing else), or the cut kind
+    whose test the tile's own mask equals, block index against block index
+    from the tile's corner. Read from the mask itself: a shape that cuts a
+    tile any other way (a block that straddles tiles) raises."""
     n = 2 * seq_len // tile
-    live, full = np.zeros((n, n), bool), np.zeros((n, n), bool)
+    kinds = np.zeros((n, n), np.int32)
     pos = np.arange(2 * seq_len)
+    block = np.arange(tile) // block_len
     for qi in range(n):
         rows = block_diffusion_allowed(
             pos[qi * tile:(qi + 1) * tile, None], pos[None, :], seq_len, block_len)
         tiles = rows.reshape(tile, n, tile)
-        live[qi], full[qi] = tiles.any(axis=(0, 2)), tiles.all(axis=(0, 2))
-    return live, full
+        kinds[qi] = np.where(tiles.all(axis=(0, 2)), _WHOLE, _DEAD)
+        for ki in np.nonzero(tiles.any(axis=(0, 2)) & (kinds[qi] == _DEAD))[0]:
+            fits = [kind for kind, test in _CUT.items()
+                    if np.array_equal(tiles[:, ki], test(block[None, :], block[:, None]))]
+            if not fits:
+                raise ValueError(
+                    f"the block-diffusion mask of seq_len {seq_len} and block_len {block_len} cuts "
+                    f"tile pair ({qi}, {ki}) of {tile} positions off its diagonal")
+            kinds[qi, ki] = fits[0]
+    kinds.setflags(write=False)
+    return kinds
 
 
-def _visit_tables(live: np.ndarray, full: np.ndarray):
-    """The live (row, column) pairs of ``live`` in row-major order, as flat
-    int32 arrays for scalar prefetch: each pair's row, its column, whether the
-    pair is whole, whether it is the first of its row, whether the last. A
-    kernel's grid runs over the pairs and nothing else: a dead pair costs no
-    grid step (0.35 us each; at L 4096 and tiles of 512, 80 pairs of 256)."""
-    rows, cols = np.nonzero(live)
+def _sub_tile(tile: int, block_len: int) -> int:
+    """The width of the query columns and key rows a cut tile is walked by: a
+    quarter of the tile where blocks do not straddle it (128 at tiles of 512,
+    the MXU's width on the v5e), else the tile as one. Measured at the
+    benchmark's cell (ms a layer, forward + dq + dk/dv; my chip runs, PR 34):
+    28.06 at 128, 28.09 at 256, 28.24 with a cut tile as one sub-tile, 39.66
+    before; the forward alone is quickest unwalked (8.40 against 8.54), dq at
+    256 (9.73 against 10.24), dk/dv at 128 (9.30 against 10.02 unwalked)."""
+    return tile // 4 if tile % (4 * block_len) == 0 else tile
+
+
+def _cut_slabs(kind: int, nsub: int, by_keys: bool):
+    """The slabs a cut tile of ``kind`` with ``nsub`` x ``nsub`` sub-tiles is
+    walked in, each (first query column, columns, first key row, rows) in
+    sub-tiles: the noised diagonal's are its diagonal sub-tiles; a triangle's
+    are, by query column, the key rows up to the column's own, or, by key
+    row, the query columns from the row's own on. The kind's test cuts the
+    slab's sub-tile on the diagonal and passes the rest of it."""
+    if kind == _EQ:
+        return [(i, 1, i, 1) for i in range(nsub)]
+    if by_keys:
+        return [(i, nsub - i, i, 1) for i in range(nsub)]
+    return [(i, 1, 0, i + 1) for i in range(nsub)]
+
+
+def _visit_tables(kinds: np.ndarray):
+    """The live (row, column) pairs of ``kinds`` in row-major order, as flat
+    int32 arrays for scalar prefetch: each pair's row, its column, its kind,
+    whether the pair is the first of its row, whether the last. A kernel's
+    grid runs over the pairs and nothing else: a dead pair costs no grid step
+    (0.35 us each; at L 4096 and tiles of 512, 80 pairs of 256). The kind is
+    the (q tile, k tile) pair's whichever of the two the rows are."""
+    rows, cols = np.nonzero(kinds)
     first = np.concatenate([[True], rows[1:] != rows[:-1]])
     last = np.concatenate([rows[1:] != rows[:-1], [True]])
     as_i32 = lambda x: jnp.asarray(np.asarray(x, np.int32))
-    return tuple(as_i32(x) for x in (rows, cols, full[rows, cols], first, last))
+    return tuple(as_i32(x) for x in (rows, cols, kinds[rows, cols], first, last))
 
 
-def _bd_scores(q, k, scale, q_tile, k_tile, whole, *, tile, seq_len, block_len):
-    """Scaled scores of one (q tile, k tile) pair in float32, keys down and
-    queries across, masked unless the pair is whole. In this orientation a
-    query's statistics are a row, reduced over sublanes and stored lane-dense."""
+def block_diffusion_plan(seq_len: int, block_len: int, tile: int = BLOCK_DIFFUSION_TILE) -> dict:
+    """What the kernels execute for one head of one sequence: the visited
+    tile pairs by kind, the sub-tile's width, the sub-tiles executed of all
+    that the visited tiles hold, and the pairs executed beside the live ones."""
+    tile = min(tile, seq_len)
+    kinds = _live_tiles(seq_len, block_len, tile)
+    sub = _sub_tile(tile, block_len)
+    nsub = tile // sub
+    held = {kind: sum(nq * nk for _, nq, _, nk in _cut_slabs(kind, nsub, False)) for kind in _CUT}
+    held[_WHOLE] = nsub * nsub
+    visits = {kind: int((kinds == kind).sum()) for kind in held}
+    executed = sum(visits[kind] * held[kind] for kind in held)
+    plan = {f"visits_{_KIND_NAMES[kind]}": visits[kind] for kind in held}
+    return dict(plan, visits=sum(visits.values()), tile=tile, sub_tile=sub,
+                sub_tiles_executed=executed, sub_tiles_visited=sum(visits.values()) * nsub * nsub,
+                pairs_executed=executed * sub * sub, pairs_live=seq_len * (seq_len + block_len))
+
+
+def _bd_scores(q, k, scale, test, block_len, q_at, k_at):
+    """Scaled scores of keys ``k`` against queries ``q`` in float32, keys down
+    and queries across: in this orientation a query's statistics are a row,
+    reduced over sublanes and stored lane-dense. With a ``test`` the keys and
+    queries are a slab of a cut tile that starts at the tile's positions
+    ``k_at`` and ``q_at``, and a key is masked unless its block passes the
+    test against the query's: the block indices are a column and a row of
+    positions in the tile, the grid pays one compare and one select."""
     s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32, precision=_ONE_PASS) * scale
+    if test is None:
+        return s
 
-    def masked(s):
-        q_pos = q_tile * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        k_pos = k_tile * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        return jnp.where(block_diffusion_allowed(q_pos, k_pos, seq_len, block_len), s, _NEG_BIG)
+    def block(shape, axis, at):
+        pos = jax.lax.broadcasted_iota(jnp.int32, shape, axis) + at
+        if block_len & (block_len - 1):
+            return jax.lax.div(pos, jnp.int32(block_len))
+        return jax.lax.shift_right_logical(pos, jnp.int32(block_len.bit_length() - 1))
 
-    return jax.lax.cond(whole == 1, lambda s: s, masked, s)
+    return jnp.where(test(block((k.shape[0], 1), 0, k_at), block((1, q.shape[0]), 1, q_at)),
+                     s, _NEG_BIG)
 
 
-def _bd_fwd_kernel(row_ref, col_ref, whole_ref, first_ref, last_ref, q_ref, k_ref, v_ref,
-                   o_ref, lse_ref, m_ref, l_ref, acc_ref, *, scale, tile, seq_len, block_len):
+def _walk(kind, visit, tile, sub, by_keys):
+    """Runs ``visit(queries, keys, test)`` over what a tile pair of ``kind`` (a
+    prefetched scalar) holds: a whole tile in one visit; a cut tile in the
+    slabs of ``_cut_slabs``, by query columns in the kernels that keep sums
+    a query and by key rows in the one that keeps sums a key, so that each sum
+    runs over the operands it ran over before, in their order, less the exact
+    zeros of what no query of the slab can read: those are never computed."""
+    pl.when(kind == _WHOLE)(lambda: visit(pl.ds(0, tile), pl.ds(0, tile), None))
+    for cut, test in _CUT.items():
+        @pl.when(kind == cut)
+        def _cut(cut=cut, test=test):
+            for q0, nq, k0, nk in _cut_slabs(cut, tile // sub, by_keys):
+                visit(pl.ds(q0 * sub, nq * sub), pl.ds(k0 * sub, nk * sub), test)
+
+
+def _bd_fwd_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref,
+                   o_ref, lse_ref, m_ref, l_ref, acc_ref, *, scale, tile, sub, block_len):
     p = pl.program_id(2)
 
     @pl.when(first_ref[p] == 1)
@@ -274,22 +364,26 @@ def _bd_fwd_kernel(row_ref, col_ref, whole_ref, first_ref, last_ref, q_ref, k_re
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    v = v_ref[0]
-    st = _bd_scores(q_ref[0], k_ref[0], scale, row_ref[p], col_ref[p], whole_ref[p], tile=tile,
-                    seq_len=seq_len, block_len=block_len)
-    m_prev = m_ref[:1]
-    m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
-    # the first tile a query visits holds a key it may read (its own block, or
-    # block 0 of the clean half), so m_new is a real score from then on and a
-    # masked score's exp is 0
-    pt = jnp.exp(st - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[:] = jnp.broadcast_to(l_ref[:1] * corr + jnp.sum(pt, axis=0, keepdims=True), l_ref.shape)
-    # the output is accumulated transposed, (D, queries): v^T p^T
-    acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-        v, pt.astype(v.dtype), (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=_ONE_PASS)
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    def visit(queries, keys, test):
+        st = _bd_scores(q_ref[0, queries], k_ref[0, keys], scale, test, block_len,
+                        queries.start, keys.start)
+        m_prev = m_ref[:1, queries]
+        m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+        # the first keys a query visits hold a key it may read (its own block,
+        # or block 0 of the clean half), so m_new is a real score from then on
+        # and a masked score's exp is 0
+        pt = jnp.exp(st - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l = l_ref[:1, queries] * corr + jnp.sum(pt, axis=0, keepdims=True)
+        l_ref[:, queries] = jnp.broadcast_to(l, (_STAT_ROWS, queries.size))
+        # the output is accumulated transposed, (D, queries): v^T p^T
+        v = v_ref[0, keys]
+        acc_ref[:, queries] = acc_ref[:, queries] * corr + jax.lax.dot_general(
+            v, pt.astype(v.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=_ONE_PASS)
+        m_ref[:, queries] = jnp.broadcast_to(m_new, (_STAT_ROWS, queries.size))
+
+    _walk(kind_ref[p], visit, tile, sub, by_keys=False)
 
     @pl.when(last_ref[p] == 1)
     def _finalize():
@@ -297,33 +391,38 @@ def _bd_fwd_kernel(row_ref, col_ref, whole_ref, first_ref, last_ref, q_ref, k_re
         lse_ref[0, 0] = m_ref[:1] + jnp.log(l_ref[:1])
 
 
-def _bd_dq_kernel(row_ref, col_ref, whole_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref,
-                  lse_ref, delta_ref, dq_ref, acc_ref, *, scale, tile, seq_len, block_len):
+def _bd_dq_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref,
+                  lse_ref, delta_ref, dq_ref, acc_ref, *, scale, tile, sub, block_len):
     p = pl.program_id(2)
 
     @pl.when(first_ref[p] == 1)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    k = k_ref[0]
-    st = _bd_scores(q_ref[0], k, scale, row_ref[p], col_ref[p], whole_ref[p], tile=tile,
-                    seq_len=seq_len, block_len=block_len)
-    pt = jnp.exp(st - lse_ref[0, 0])
-    dpt = jax.lax.dot_general(v_ref[0], do_ref[0], (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32, precision=_ONE_PASS)
-    dst = (pt * (dpt - delta_ref[0, 0])).astype(k.dtype)
-    acc_ref[:] += jax.lax.dot_general(dst, k, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32, precision=_ONE_PASS)
+    def visit(queries, keys, test):
+        k, do = k_ref[0, keys], do_ref[0, queries]
+        st = _bd_scores(q_ref[0, queries], k, scale, test, block_len, queries.start, keys.start)
+        pt = jnp.exp(st - lse_ref[0, 0, :, queries])
+        dpt = jax.lax.dot_general(v_ref[0, keys], do, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32, precision=_ONE_PASS)
+        dst = (pt * (dpt - delta_ref[0, 0, :, queries])).astype(k.dtype)
+        acc_ref[queries] += jax.lax.dot_general(dst, k, (((0,), (0,)), ((), ())),
+                                                preferred_element_type=jnp.float32,
+                                                precision=_ONE_PASS)
+
+    _walk(kind_ref[p], visit, tile, sub, by_keys=False)
 
     @pl.when(last_ref[p] == 1)
     def _finalize():
         dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
-def _bd_dkv_kernel(row_ref, col_ref, whole_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref,
-                   lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, tile, group,
-                   seq_len, block_len):
-    # here a "row" of the tables is a k tile and its "columns" the q tiles that read it
+def _bd_dkv_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref,
+                   lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, tile, sub, group,
+                   block_len):
+    # here a "row" of the tables is a k tile and its "columns" the q tiles that
+    # read it; a pair's kind is the same pair's, and a cut tile is walked by
+    # key rows, each over the query columns that read it
     p, g = pl.program_id(2), pl.program_id(3)
 
     @pl.when((first_ref[p] == 1) & (g == 0))
@@ -331,17 +430,19 @@ def _bd_dkv_kernel(row_ref, col_ref, whole_ref, first_ref, last_ref, q_ref, k_re
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q, do = q_ref[0], do_ref[0]
-    st = _bd_scores(q, k_ref[0], scale, col_ref[p], row_ref[p], whole_ref[p], tile=tile,
-                    seq_len=seq_len, block_len=block_len)
-    pt = jnp.exp(st - lse_ref[0, 0])
-    dv_acc[:] += jax.lax.dot_general(pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32, precision=_ONE_PASS)
-    dpt = jax.lax.dot_general(v_ref[0], do, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32, precision=_ONE_PASS)
-    dst = (pt * (dpt - delta_ref[0, 0])).astype(q.dtype)
-    dk_acc[:] += jax.lax.dot_general(dst, q, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32, precision=_ONE_PASS)
+    def visit(queries, keys, test):
+        q, do = q_ref[0, queries], do_ref[0, queries]
+        st = _bd_scores(q, k_ref[0, keys], scale, test, block_len, queries.start, keys.start)
+        pt = jnp.exp(st - lse_ref[0, 0, :, queries])
+        dv_acc[keys] += jax.lax.dot_general(pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                                            preferred_element_type=jnp.float32, precision=_ONE_PASS)
+        dpt = jax.lax.dot_general(v_ref[0, keys], do, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32, precision=_ONE_PASS)
+        dst = (pt * (dpt - delta_ref[0, 0, :, queries])).astype(q.dtype)
+        dk_acc[keys] += jax.lax.dot_general(dst, q, (((1,), (0,)), ((), ())),
+                                            preferred_element_type=jnp.float32, precision=_ONE_PASS)
+
+    _walk(kind_ref[p], visit, tile, sub, by_keys=True)
 
     @pl.when((last_ref[p] == 1) & (g == group - 1))
     def _finalize():
@@ -361,20 +462,20 @@ def _bd_plan(q, k, seq_len, block_len, tile):
     if seq_len % tile or tile % 8 or d % 128:
         raise ValueError(f"seq_len {seq_len} must be a multiple of the tile {tile}, the tile of "
                          f"8, and the head size {d} of 128")
-    return b, t, hq, hkv, d, tile
+    return b, t, hq, hkv, d, tile, _sub_tile(tile, block_len), _live_tiles(seq_len, block_len, tile)
 
 
 def _bd_forward(q, k, v, seq_len, block_len, scale, tile, interpret):
-    b, t, hq, hkv, d, tile = _bd_plan(q, k, seq_len, block_len, tile)
+    b, t, hq, hkv, d, tile, sub, kinds = _bd_plan(q, k, seq_len, block_len, tile)
     group = hq // hkv
-    tables = _visit_tables(*_live_tiles(seq_len, block_len, tile))
+    tables = _visit_tables(kinds)
     # heads stay where the projections left them: a head is a 128-lane column
     # block of the (B, T, H * D) array, so nothing is transposed in HBM
     q2, k2, v2 = (x.reshape(b, t, -1) for x in (q, k, v))
     q_at = lambda bi, h, p, row, col, *_: (bi, row[p], h)
     kv_at = lambda bi, h, p, row, col, *_: (bi, col[p], h // group)
     out, lse = pl.pallas_call(
-        functools.partial(_bd_fwd_kernel, scale=scale, tile=tile, seq_len=seq_len,
+        functools.partial(_bd_fwd_kernel, scale=scale, tile=tile, sub=sub,
                           block_len=block_len),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
@@ -398,21 +499,20 @@ def _bd_forward(q, k, v, seq_len, block_len, scale, tile, interpret):
 
 
 def _bd_backward(q, k, v, out, lse, do, seq_len, block_len, scale, tile, interpret):
-    b, t, hq, hkv, d, tile = _bd_plan(q, k, seq_len, block_len, tile)
+    b, t, hq, hkv, d, tile, sub, kinds = _bd_plan(q, k, seq_len, block_len, tile)
     group = hq // hkv
-    live, full = _live_tiles(seq_len, block_len, tile)
     # sum_j P_ij dP_ij = o_i . do_i: a row statistic too, lane-dense like lse
     delta = jnp.einsum("bthd,bthd->bht", out.astype(jnp.float32),
                        do.astype(jnp.float32))[:, :, None, :]
     do = do.astype(q.dtype)
     q2, k2, v2, do2 = (x.reshape(b, t, -1) for x in (q, k, v, do))
 
-    tables = _visit_tables(live, full)
+    tables = _visit_tables(kinds)
     q_at = lambda bi, h, p, row, col, *_: (bi, row[p], h)
     kv_at = lambda bi, h, p, row, col, *_: (bi, col[p], h // group)
     stat_at = lambda bi, h, p, row, *_: (bi, h, 0, row[p])
     dq = pl.pallas_call(
-        functools.partial(_bd_dq_kernel, scale=scale, tile=tile, seq_len=seq_len,
+        functools.partial(_bd_dq_kernel, scale=scale, tile=tile, sub=sub,
                           block_len=block_len),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
@@ -433,13 +533,13 @@ def _bd_backward(q, k, v, out, lse, do, seq_len, block_len, scale, tile, interpr
 
     # for a k tile, the q tiles that read it: the transposed tables; the
     # group's query heads innermost, so that a k tile's sums stay in VMEM
-    tables = _visit_tables(live.T, full.T)
+    tables = _visit_tables(kinds.T)
     kv_at = lambda bi, hk, p, g, row, col, *_: (bi, row[p], hk)
     q_at = lambda bi, hk, p, g, row, col, *_: (bi, col[p], hk * group + g)
     stat_at = lambda bi, hk, p, g, row, col, *_: (bi, hk * group + g, 0, col[p])
     dk, dv = pl.pallas_call(
-        functools.partial(_bd_dkv_kernel, scale=scale, tile=tile, group=group,
-                          seq_len=seq_len, block_len=block_len),
+        functools.partial(_bd_dkv_kernel, scale=scale, tile=tile, sub=sub, group=group,
+                          block_len=block_len),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(b, hkv, tables[0].shape[0], group),
@@ -498,13 +598,19 @@ def block_diffusion_attention(
 
     Forward and backward are Pallas kernels over square tiles of ``tile``
     positions. Each q tile visits only the k tiles that hold a pair it may
-    read (a prefetched table; 80 of 256 tile pairs at L 4096 and tile 512),
-    masks only the tiles that are not whole, and the backward (one kernel for
-    dq, one for dk and dv summed over the group's query heads) recomputes the
-    probabilities from the forward's log-sum-exp a row: nothing L^2 is ever
-    held. Products take the inputs' dtype as operands and accumulate in
-    float32; the softmax is float32, over scores scaled by ``D ** -0.5``.
-    ``tile`` is for the CPU tests, which cut a short sequence into several.
+    read (a prefetched table of the pairs and their kinds; 80 of 256 tile
+    pairs at L 4096 and tile 512, 56 whole and 24 cut). A tile the mask cuts
+    is a diagonal tile of one of three kinds and costs what it holds: it is
+    walked by sub-tiles (``_sub_tile``: 128 positions there) over the slabs its
+    queries can read (``_cut_slabs``: 4 or 10 of its 16 sub-tiles, 1,088 of
+    the 1,280 a head; ``block_diffusion_plan`` counts them), masked by one
+    compare of the keys' block indices (a column) with the queries' (a row).
+    The backward (one kernel for dq, one for dk and dv summed over the group's
+    query heads) recomputes the probabilities from the forward's log-sum-exp
+    a row: nothing L^2 is ever held. Products take the inputs' dtype as
+    operands and accumulate in float32; the softmax is float32, over scores
+    scaled by ``D ** -0.5``. ``tile`` is for the CPU tests, which cut a short
+    sequence into several.
     """
     if q.ndim != 4:
         raise ValueError(f"expected [B, 2L, H, D], got shape {q.shape}")

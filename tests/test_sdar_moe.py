@@ -20,11 +20,13 @@ if ROOT not in sys.path:
 
 from perf import sdar_weights  # noqa: E402
 from perf.reference import sdar_moe as reference  # noqa: E402
+from persia_tpu import tracing  # noqa: E402
 from persia_tpu.data import IDTypeFeature, Label, PersiaBatch  # noqa: E402
 from persia_tpu.embedding.optim import Adagrad  # noqa: E402
 from persia_tpu.models import SDARMoE  # noqa: E402
 from persia_tpu.ops.flash_attention import (  # noqa: E402
-    _live_tiles, _visit_tables, block_diffusion_attention, block_diffusion_mask,
+    _CUT, _DEAD, _EQ, _LE, _LT, _WHOLE, _cut_slabs, _live_tiles, _sub_tile, _visit_tables,
+    block_diffusion_attention, block_diffusion_mask, block_diffusion_plan,
 )
 from persia_tpu.ops.grouped_matmul import grouped_matmul, grouped_outer  # noqa: E402
 from persia_tpu.parallel.fused_ctx import FusedTrainCtx, batch_to_fused  # noqa: E402
@@ -89,6 +91,7 @@ def one_step():
         emb_state={gname: {"acc": jnp.full(table.shape, so["initial_accumulator"], jnp.float32)}},
         emb_batch_state=jnp.ones((2,), jnp.float32), step=jnp.zeros((), jnp.int32))
     out = ctx.train_step(_persia_batch(b))
+    paths = [e["attrs"] for e in tracing.flight_snapshot() if e["kind"] == "sdar_moe.paths"]
     ref = reference.Reference(cfg, SEED, lambda keys: sdar_weights.token_rows(
         cfg, SEED, np.asarray(keys, np.int64)), how=(8, 7))
     keys = b["ids"].astype(np.uint64)
@@ -96,7 +99,7 @@ def one_step():
     uniq = np.unique(keys)
     return {"cfg": cfg, "out": out, "state": ctx.state, "table": np.asarray(ctx.state.tables[gname]),
             "acc": np.asarray(ctx.state.emb_state[gname]["acc"]), "ref": ref, "loss_ref": loss_ref,
-            "uniq": uniq, "dense0": reference.leaves_by_name(dense)}
+            "uniq": uniq, "dense0": reference.leaves_by_name(dense), "paths": paths}
 
 
 def _gap(a, b):
@@ -143,6 +146,17 @@ def test_tower_against_the_reference(one_step, what):
         picks = np.asarray(s["state"].batch_stats["expert_picks"])
         assert picks.shape == (2, 8) and np.abs(picks - ref.picks).sum() <= 0.02 * ref.picks.sum()
         assert picks.sum() > 0
+
+
+def test_the_paths_event_carries_the_attention_plan(one_step):
+    """What the entry prints to stderr: the path names, and what the kernels
+    execute a head at this length (one tile of 32 a half: three cut visits)."""
+    said = one_step["paths"][-1]
+    assert said["attention"] == "pallas_block_mask" and said["experts"] == "ragged_dot"
+    plan = block_diffusion_plan(LENGTH, TINY["block_length"])
+    assert {k: said[k] for k in plan} == {k: str(v) for k, v in plan.items()}
+    assert (plan["visits"], plan["visits_whole"], plan["sub_tile"]) == (3, 0, 8)
+    assert (plan["sub_tiles_executed"], plan["sub_tiles_visited"]) == (4 + 10 + 10, 48)
 
 
 def test_the_shares_add_up():
@@ -215,29 +229,88 @@ def test_mask_for_l8_b4_is_the_hand_written_matrix():
 
 
 def test_dead_tile_pairs_are_never_visited():
-    live, full = _live_tiles(4096, 4, 512)
+    kinds = _live_tiles(4096, 4, 512)
+    live, full = kinds != _DEAD, kinds == _WHOLE
     assert live.shape == (16, 16) and live.sum() == 80 and full.sum() == 56
     assert not live[8:, :8].any()  # clean queries read no noised key
-    rows, cols, whole, first, last = (np.asarray(x) for x in _visit_tables(live, full))
+    rows, cols, kind, first, last = (np.asarray(x) for x in _visit_tables(kinds))
     assert len(rows) == 80  # the grid's steps: the live pairs and nothing else
     assert rows.tolist() == sorted(rows.tolist()) and first.sum() == last.sum() == 16
     at = rows == 3  # a noised q tile: its own tile, then clean tiles 0 .. 3, the last one partly
-    assert cols[at].tolist() == [3, 8, 9, 10, 11] and whole[at].tolist() == [0, 1, 1, 1, 0]
+    assert cols[at].tolist() == [3, 8, 9, 10, 11]
+    assert kind[at].tolist() == [_EQ, _WHOLE, _WHOLE, _WHOLE, _LT]
     assert first[at].tolist() == [1, 0, 0, 0, 0] and last[at].tolist() == [0, 0, 0, 0, 1]
     assert cols[rows == 8].tolist() == [8]  # the first clean q tile reads its own tile alone
-    k_rows, k_cols, *_ = (np.asarray(x) for x in _visit_tables(live.T, full.T))
+    assert kind[rows == 11].tolist() == [_WHOLE, _WHOLE, _WHOLE, _LE]
+    k_rows, k_cols, k_kind, *_ = (np.asarray(x) for x in _visit_tables(kinds.T))
     assert k_cols[k_rows == 3].tolist() == [3]  # a noised k tile is read by its own q tile alone
-    assert k_cols[k_rows == 15].tolist() == [7, 15]
+    assert k_cols[k_rows == 15].tolist() == [7, 15]  # the pair's kind, whichever the rows are
+    assert k_kind[k_rows == 15].tolist() == [_LT, _LE] and k_kind[k_rows == 3].tolist() == [_EQ]
     dense = block_diffusion_mask(64, 4)
-    live, _ = _live_tiles(64, 4, 16)
-    np.testing.assert_array_equal(live, dense.reshape(8, 16, 8, 16).any(axis=(1, 3)))
+    np.testing.assert_array_equal(_live_tiles(64, 4, 16) != _DEAD,
+                                  dense.reshape(8, 16, 8, 16).any(axis=(1, 3)))
 
 
-@pytest.fixture(scope="module")
-def attention_case():
-    """32 query heads over 4 K/V heads under M, L 32, b 4, tiles of 16: the
-    kernels' output and gradients, and dense ``jax.numpy``'s."""
-    length, b, hq, hkv, d = 32, 4, 32, 4, 128
+# (seq_len, block_len, tile, sub-tile): the benchmark cell's; a cut tile of 4 x 4
+# sub-tiles; the same under blocks that are no power of two; a block wider than
+# a quarter of the tile, which is then walked as one sub-tile
+PLANS = [(4096, 4, 512, 128), (128, 4, 64, 16), (96, 6, 48, 12), (48, 12, 24, 24)]
+
+
+@pytest.mark.parametrize("by_keys", [False, True], ids=["by_query_columns", "by_key_rows"])
+@pytest.mark.parametrize("seq_len,block_len,tile,width", PLANS)
+def test_the_plan_executes_every_live_pair_and_counts_what_it_executes(
+        seq_len, block_len, tile, width, by_keys):
+    """The slabs the kernels' walk executes, rebuilt from the tiles' kinds by
+    the walk's own rule (``_cut_slabs``, the kind's test on block indices in
+    the tile), are the dense mask: every live pair is inside an executed
+    sub-tile, no sub-tile is executed twice, and what the test leaves of the
+    executed pairs is the mask and nothing else."""
+    plan = block_diffusion_plan(seq_len, block_len, tile)
+    kinds, sub = _live_tiles(seq_len, block_len, tile), _sub_tile(tile, block_len)
+    assert plan["tile"] == tile and plan["sub_tile"] == sub == width
+    n, nsub = 2 * seq_len // tile, tile // sub
+    block = np.arange(tile) // block_len
+    executed = np.zeros((n, tile, n, tile), np.int32)  # q tile, query, k tile, key: times executed
+    allowed = np.zeros(executed.shape, bool)
+    for qi, ki in zip(*np.nonzero(kinds)):
+        if kinds[qi, ki] == _WHOLE:
+            executed[qi, :, ki], allowed[qi, :, ki] = 1, True
+            continue
+        for q0, nq, k0, nk in _cut_slabs(kinds[qi, ki], nsub, by_keys):
+            queries, keys = slice(q0 * sub, (q0 + nq) * sub), slice(k0 * sub, (k0 + nk) * sub)
+            executed[qi, queries, ki, keys] += 1
+            allowed[qi, queries, ki, keys] = _CUT[kinds[qi, ki]](block[None, keys], block[queries, None])
+    dense = block_diffusion_mask(seq_len, block_len)
+    np.testing.assert_array_equal(allowed.reshape(dense.shape), dense)
+    assert executed.max() == 1 and not (dense & (executed.reshape(dense.shape) == 0)).any()
+    assert plan["pairs_executed"] == executed.sum() and plan["pairs_live"] == dense.sum()
+    assert plan["sub_tiles_visited"] == plan["visits"] * nsub * nsub
+    if seq_len == 4096:
+        counts = {k: v for k, v in plan.items() if k.startswith("visits") or k.startswith("sub_tiles")}
+        assert counts == {"visits": 80, "visits_whole": 56, "visits_noised_diagonal": 8,
+                          "visits_noised_on_clean": 8, "visits_clean_diagonal": 8,
+                          "sub_tiles_executed": 1088, "sub_tiles_visited": 1280}
+
+
+@pytest.mark.parametrize("seq_len,block_len,tile", [(32, 3, 16), (64, 12, 16), (36, 4, 24)])
+def test_a_tile_cut_off_its_diagonal_is_refused(seq_len, block_len, tile):
+    """A block that straddles two tiles cuts them in no way the kernels walk."""
+    with pytest.raises(ValueError, match="off its diagonal"):
+        _live_tiles(seq_len, block_len, tile)
+
+
+# (L, block_len, tile, query heads, K/V heads): the first case of PR 33; cut tiles of
+# 4 x 4 sub-tiles of 16 beside whole and dead tiles; the same under blocks of 6
+ATTENTION_CASES = [(32, 4, 16, 32, 4), (128, 4, 64, 4, 2), (96, 6, 48, 4, 2)]
+
+
+@pytest.fixture(scope="module", params=ATTENTION_CASES, ids=lambda c: "L{}-b{}-tile{}".format(*c[:3]))
+def attention_case(request):
+    """Query heads over K/V heads under M: the kernels' output and gradients
+    in the interpreter, and dense ``jax.numpy``'s."""
+    length, b, tile, hq, hkv = request.param
+    d = 128
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((1, 2 * length, hq, d)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((1, 2 * length, hkv, d)), jnp.float32)
@@ -252,7 +325,7 @@ def attention_case():
         return jnp.einsum("bhqk,bkhd->bqhd", p, vv, precision="highest")
 
     def kernel(q, k, v):
-        return block_diffusion_attention(q, k, v, length, b, tile=16, interpret=True)
+        return block_diffusion_attention(q, k, v, length, b, tile=tile, interpret=True)
 
     out = {}
     for name, f in (("kernel", kernel), ("dense", dense)):
