@@ -182,6 +182,26 @@ class Label(NdarrayDataBase):
     DEFAULT_NAME = "label"
 
 
+def document_starts(doc_lengths, seq_len: int) -> NonIDTypeFeature:
+    """Document boundaries of packed sequences as a side input: for ``B``
+    sequences of ``seq_len`` positions, each packed from documents of
+    ``doc_lengths[b]`` positions in their order (zeros are skipped; what the
+    documents leave of the sequence is one more document), the ``(B,
+    seq_len)`` int32 index at which each position's document starts. An
+    integer feature stays int32 on its way to the model
+    (``fused_ctx.batch_to_fused``), which reads positions and the keys a
+    position may attend to from it (``models/mellum_moe.py``)."""
+    lengths = np.asarray(doc_lengths, np.int64).reshape(len(doc_lengths), -1)
+    if lengths.min(initial=0) < 0 or lengths.sum(axis=1).max(initial=0) > seq_len:
+        raise ValueError(f"documents of {lengths.tolist()} positions do not fit {seq_len}")
+    starts = np.empty((len(lengths), seq_len), np.int32)
+    for row, of_docs in zip(starts, lengths):
+        packed = int(of_docs.sum())
+        row[:packed] = np.repeat(np.cumsum(of_docs) - of_docs, of_docs)
+        row[packed:] = packed
+    return NonIDTypeFeature(starts, name="document_starts")
+
+
 def _write_ndarray(buf: io.BytesIO, name: str, arr: np.ndarray) -> None:
     name_b = name.encode()
     buf.write(struct.pack("<H", len(name_b)))

@@ -10,9 +10,16 @@ array; raw (sequence) slots contribute a ``(gathered, mask)`` pair with
 ``nn.Sigmoid`` into ``forward``, e.g.
 `/root/reference/examples/src/adult-income/model.py:40`).
 
-``SDARMoE`` is the one tower that is no click model: a block-diffusion
-mixture-of-experts transformer over one raw slot of token rows, which states
-its own loss and outputs (``models/sdar_moe.py``).
+Two towers are no click models: mixture-of-experts transformers over one raw
+slot of token rows that state their own loss and outputs, on one shared tower
+(``models/moe_tower.py``: norms, grouped-query attention up to its kernel, the
+routed expert layer that is told which experts it holds, the scan over
+periods of layers). ``SDARMoE`` (``models/sdar_moe.py``) trains by block
+diffusion over ``[noised | clean]`` under the block-diffusion mask;
+``MellumMoE`` (``models/mellum_moe.py``) trains causally over packed
+documents, window and full layers in one period under RoPE tables of their
+own, an int32 side input (each position's document start) in ``dense``, head
+and loss in chunks of positions (``train_loss``).
 """
 
 from persia_tpu.models.dnn import DNN  # noqa: F401
@@ -21,3 +28,4 @@ from persia_tpu.models.deepfm import DeepFM  # noqa: F401
 from persia_tpu.models.dcn import DCNv2  # noqa: F401
 from persia_tpu.models.din import DIN  # noqa: F401
 from persia_tpu.models.sdar_moe import SDARMoE  # noqa: F401
+from persia_tpu.models.mellum_moe import MellumMoE  # noqa: F401

@@ -1,0 +1,301 @@
+"""What the sequence towers share: a mixture-of-experts transformer tower over
+the sparse plane (RMSNorm, grouped-query attention with RoPE and per-head q/k
+norms, a routed expert layer with no shared expert, an untied head), as one
+``lax.scan`` over stacked layers. ``models/sdar_moe.py`` (block diffusion) and
+``models/mellum_moe.py`` (causal, window and full layers over packed
+documents) state what differs: the kinds of layer a period holds, the RoPE
+table a kind, which attention kernel runs, which positions have logits, the
+loss.
+
+**The expert layer is told which experts it holds** (``first_held``,
+``n_held``): it routes over all ``n_experts`` with the published
+``experts_per_token``, and computes its own experts' part of the result for
+the tokens routed to them; what the absent experts would add is another
+chip's to add. On one chip it runs without that exchange. The parts all the
+shares give sum to the whole layer (``tests/test_sdar_moe.py``,
+``tests/test_mellum_moe.py``).
+
+Arithmetic: every matrix product takes bfloat16 operands and accumulates in
+float32, forward and backward (``_mm``, ``ops.grouped_matmul``, the attention
+kernels); parameters, residual stream, norms, RoPE, softmax, router
+probabilities and loss are float32.
+
+Not a flax module: the layers are one ``lax.scan`` over stacked parameters
+with each layer recomputed in the backward, which flax's lifted transforms
+would only wrap. ``init`` and ``apply`` keep flax's calling convention, which
+is all the fused step asks of a model.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from persia_tpu.ops.flash_attention import ATTENTION_LSE, ATTENTION_OUT
+from persia_tpu.ops.grouped_matmul import grouped_matmul, grouped_outer
+
+
+@jax.custom_vjp
+def _mm(a, w):
+    """a (..., K) x w (K, N) -> (..., N): bfloat16 operands, float32 sums,
+    float32 results; the same for both gradients (the input's in its dtype)."""
+    return _dot(a.astype(jnp.bfloat16), w.astype(jnp.bfloat16))
+
+
+def _dot(a, b):
+    # one pass whatever the process's default matmul precision: the operands are bfloat16
+    return jnp.dot(a, b, precision=jax.lax.Precision.DEFAULT, preferred_element_type=jnp.float32)
+
+
+def _mm_fwd(a, w):
+    like = jnp.zeros((0,), a.dtype)  # the input's dtype, which its gradient has to take
+    a, w = a.astype(jnp.bfloat16), w.astype(jnp.bfloat16)
+    return _dot(a, w), (a, w, like)
+
+
+def _mm_bwd(res, g):
+    a, w, like = res
+    g = g.astype(jnp.bfloat16)
+    da = _dot(g, w.T)
+    dw = _dot(a.reshape(-1, a.shape[-1]).T, g.reshape(-1, g.shape[-1]))
+    return da.astype(like.dtype), dw
+
+
+_mm.defvjp(_mm_fwd, _mm_bwd)
+
+
+def _rms(x, w, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
+
+
+def _rope(x, cos, sin):
+    """Rotate-half RoPE: x (B, T, H, D), cos and sin (T, D) or (B, T, D)."""
+    half = x.shape[-1] // 2
+    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    return x * cos[..., None, :] + rotated * sin[..., None, :]
+
+
+def _chunk(m, flat_w, order, starts, lo, size, k):
+    """The picks ``lo .. lo + size`` of the sorted order: their sizes by held
+    expert, their place among all picks, whether each is on a held expert, its
+    token, its routing weight (0 where it is not), and its token's row."""
+    sizes = jnp.clip(starts[1:] - lo, 0, size) - jnp.clip(starts[:-1] - lo, 0, size)
+    at = jax.lax.dynamic_slice(order, (lo,), (size,))  # ``order`` is padded to whole chunks
+    live = (lo + jnp.arange(size, dtype=jnp.int32)) < starts[-1]
+    token = at // k
+    return sizes, at, live, token, jnp.where(live, flat_w[at], 0.0), m[token].astype(jnp.bfloat16)
+
+
+def _swiglu(rows, gate, up, sizes):
+    with jax.named_scope("experts"):
+        g, u = grouped_matmul(rows, gate, sizes), grouped_matmul(rows, up, sizes)
+    return g, u, (jax.nn.silu(g) * u).astype(jnp.bfloat16)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _held_experts(m, flat_w, gate, up, down, order, starts, size, k):
+    """sum over the picks on held experts of weight x down_e(silu(gate_e x) *
+    up_e x), by token: m (N, d) -> (N, d). ``order`` holds the picks sorted by
+    held expert (pick i is token ``i // k``), ``starts`` (held + 1,) where each
+    expert's begin, ``flat_w`` (N * k,) the routing weights. The loop's trip
+    count is the live chunks', so autodiff cannot reverse it: the backward
+    below is written out, with the same products (bfloat16 operands, float32
+    sums) in the same chunks, recomputing a chunk's forward."""
+    return _held_experts_fwd(m, flat_w, gate, up, down, order, starts, size, k)[0]
+
+
+def _held_experts_fwd(m, flat_w, gate, up, down, order, starts, size, k):
+    gate_b, up_b, down_b = (x.astype(jnp.bfloat16) for x in (gate, up, down))
+
+    def body(c, y):
+        with jax.named_scope("dispatch"):
+            sizes, _at, live, token, w, rows = _chunk(m, flat_w, order, starts, c * size, size, k)
+        _g, _u, mid = _swiglu(rows, gate_b, up_b, sizes)
+        with jax.named_scope("experts"):
+            out = grouped_matmul(mid, down_b, sizes)
+        with jax.named_scope("combine"):
+            return y.at[token].add(jnp.where(live[:, None], out, 0.0) * w[:, None])
+
+    y = jax.lax.fori_loop(0, (starts[-1] + size - 1) // size, body, jnp.zeros(m.shape, jnp.float32))
+    return y, (m, flat_w, gate_b, up_b, down_b, order, starts)
+
+
+def _held_experts_bwd(size, k, res, dy):
+    m, flat_w, gate_b, up_b, down_b, order, starts = res
+    # the transposed weights as arrays of their own: the compiler's grouped
+    # kernel takes (G, K, N) contracted over K, nothing else
+    gate_t, up_t, down_t = (jnp.swapaxes(x, 1, 2) for x in (gate_b, up_b, down_b))
+
+    def body(c, carry):
+        dm, dw, d_gate, d_up, d_down = carry
+        with jax.named_scope("dispatch"):
+            sizes, at, live, token, w, rows = _chunk(m, flat_w, order, starts, c * size, size, k)
+        g, u, mid = _swiglu(rows, gate_b, up_b, sizes)
+        with jax.named_scope("combine"):
+            dyt = jnp.where(live[:, None], dy[token], 0.0)
+        with jax.named_scope("experts"):
+            out = grouped_matmul(mid, down_b, sizes)
+            d_out = (dyt * w[:, None]).astype(jnp.bfloat16)
+            d_mid = grouped_matmul(d_out, down_t, sizes)
+            sig = jax.nn.sigmoid(g)
+            dg = (d_mid * u * (sig * (1.0 + g * (1.0 - sig)))).astype(jnp.bfloat16)
+            du = (d_mid * (g * sig)).astype(jnp.bfloat16)
+            d_rows = grouped_matmul(dg, gate_t, sizes) + grouped_matmul(du, up_t, sizes)
+            d_gate = d_gate + grouped_outer(rows, dg, sizes)
+            d_up = d_up + grouped_outer(rows, du, sizes)
+            d_down = d_down + grouped_outer(mid, d_out, sizes)
+        with jax.named_scope("dispatch"):
+            dm = dm.at[token].add(jnp.where(live[:, None], d_rows, 0.0))
+            dw = dw.at[at].add(jnp.sum(dyt * out, axis=-1))
+        return dm, dw, d_gate, d_up, d_down
+
+    zeros = lambda x: jnp.zeros(x.shape, jnp.float32)
+    dm, dw, d_gate, d_up, d_down = jax.lax.fori_loop(
+        0, (starts[-1] + size - 1) // size, body,
+        (zeros(m), zeros(flat_w), zeros(gate_b), zeros(up_b), zeros(down_b)))
+    return dm, dw, d_gate, d_up, d_down, None, None
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+class MoETower:
+    """The tower's parameters, layers and scan, for a frozen dataclass that
+    holds its sizes (``vocab``, ``n_layers``, ``hidden``, ``n_heads``,
+    ``n_kv_heads``, ``head_dim``, ``n_experts``, ``experts_per_token``,
+    ``expert_width``, ``first_held``, ``n_held``, ``rms_eps``) and states
+    ``layer_kinds``, the kinds of layer of one period in their order: the
+    layers are the period repeated. A kind has a RoPE table and an attention
+    of its own; everything else of a layer is the same layer."""
+
+    layer_kinds: Tuple[str, ...] = ("attention",)
+
+    # ------------------------------------------------------------ parameters
+
+    def param_shapes(self) -> Dict[str, Tuple[int, ...]]:
+        """Every dense leaf; those under ``layers`` carry a leading layer axis."""
+        d, hd, n = self.hidden, self.head_dim, self.n_layers
+        e, f = self.n_held, self.expert_width
+        return {
+            "layers": {
+                "norm1": (n, d), "wq": (n, d, self.n_heads * hd), "wk": (n, d, self.n_kv_heads * hd),
+                "wv": (n, d, self.n_kv_heads * hd), "q_norm": (n, hd), "k_norm": (n, hd),
+                "wo": (n, self.n_heads * hd, d), "norm2": (n, d), "router": (n, d, self.n_experts),
+                "gate": (n, e, d, f), "up": (n, e, d, f), "down": (n, e, f, d),
+            },
+            "norm_f": (d,), "head": (d, self.vocab),
+        }
+
+    def counters(self) -> Dict[str, Any]:
+        """What the step counts on the device: the picks by layer and held expert."""
+        return {"expert_picks": jnp.zeros((self.n_layers, self.n_held), jnp.int32)}
+
+    def init(self, rng, dense, emb, train: bool = False) -> Dict[str, Any]:
+        """Normal(0, 0.02) products, unit norms, and the step's counters."""
+        shapes = self.param_shapes()
+        leaves, tree = jax.tree.flatten_with_path(shapes, is_leaf=lambda s: isinstance(s, tuple))
+        keys = jax.random.split(rng, len(leaves))
+        params = jax.tree.unflatten(tree, [
+            jnp.ones(shape, jnp.float32) if "norm" in path[-1].key
+            else 0.02 * jax.random.normal(key, shape, jnp.float32)
+            for (path, shape), key in zip(leaves, keys)])
+        return {"params": params, "batch_stats": self.counters()}
+
+    # ---------------------------------------------------------------- layers
+
+    def layers(self, params, rows, rope, attend):
+        """The residual stream after the last layer, (B, T, hidden) float32,
+        and the picks by layer and held expert. ``rope[kind]`` is the kind's
+        ``(cos, sin)``, ``attend(kind, q, k, v)`` its attention over bfloat16
+        q (B, T, Hq, D), k and v (B, T, Hkv, D).
+
+        One scan over the periods, a period's layers written out in its body.
+        Each layer is recomputed in the backward, but for its attention
+        kernel's output and row statistics, which are kept (150 MB a layer at
+        the benchmark's sequence cells against 12 ms of the forward kernel)."""
+        kinds = self.layer_kinds
+        if self.n_layers % len(kinds):
+            raise ValueError(f"{self.n_layers} layers are no whole periods of {kinds}")
+        keep = jax.checkpoint_policies.save_only_these_names(ATTENTION_OUT, ATTENTION_LSE)
+        scope = {k: "attention" if len(set(kinds)) == 1 else f"attention/{k}" for k in kinds}
+        layer = {k: jax.checkpoint(partial(self._layer, rope=rope[k], scope=scope[k],
+                                           attend=partial(attend, k)), policy=keep)
+                 for k in set(kinds)}
+
+        # a period of one layer is scanned as the stacked leaves lie: regrouping
+        # them costs the SDAR cell 0.8% of its step in slices and updates of the
+        # scan's stacked gradients (my chip runs, PR 35)
+        single = len(kinds) == 1
+
+        def period(h, p):
+            picks = []
+            for i, kind in enumerate(kinds):
+                h, got = layer[kind](p if single else jax.tree.map(lambda x: x[i], p), h)
+                picks.append(got)
+            return h, picks[0] if single else jnp.stack(picks)
+
+        by_period = params["layers"] if single else jax.tree.map(
+            lambda x: x.reshape(-1, len(kinds), *x.shape[1:]), params["layers"])
+        h, picks = jax.lax.scan(period, rows.astype(jnp.float32), by_period)
+        return h, picks.reshape(self.n_layers, self.n_held)
+
+    def _layer(self, p, h, rope, scope, attend):
+        b, t, d = h.shape
+        hd = self.head_dim
+        cos, sin = rope
+        with jax.named_scope(scope):
+            a = _rms(h, p["norm1"], self.rms_eps)
+            q = _mm(a, p["wq"]).reshape(b, t, self.n_heads, hd)
+            k = _mm(a, p["wk"]).reshape(b, t, self.n_kv_heads, hd)
+            v = _mm(a, p["wv"]).reshape(b, t, self.n_kv_heads, hd)
+            q = _rope(_rms(q, p["q_norm"], self.rms_eps), cos, sin)
+            k = _rope(_rms(k, p["k_norm"], self.rms_eps), cos, sin)
+            o = attend(q.astype(jnp.bfloat16), k.astype(jnp.bfloat16), v.astype(jnp.bfloat16))
+            # bfloat16 as the kernel leaves it: what the product takes, and the
+            # gradient comes back in what the kernel's backward takes
+            h = h + _mm(o.reshape(b, t, -1), p["wo"])
+        with jax.named_scope("moe"):
+            m = _rms(h, p["norm2"], self.rms_eps)
+            y, picks = self.experts(p, m.reshape(b * t, d))
+            h = h + y.reshape(b, t, d)
+        return h, picks
+
+    def pick_chunk(self, n_tokens: int) -> int:
+        """Picks the expert layer handles at a time: an eighth over what an
+        even router sends the held experts of ``n_tokens`` tokens, in whole
+        tiles of 512 rows. The loop over chunks ends with the last live one,
+        so no token is dropped whatever the load and nothing larger than a
+        chunk's rows is held; near an even load one trip does."""
+        picks = n_tokens * self.experts_per_token
+        even = -(-picks * self.n_held // self.n_experts)
+        return min(picks, -(-(even + even // 8) // 512) * 512)
+
+    def experts(self, p, m):
+        """This chip's experts' part of the layer's result for tokens
+        ``m`` (N, hidden), and the picks each held expert got, (n_held,).
+        ``p`` holds ``router`` (hidden, n_experts) and the held experts'
+        ``gate``, ``up`` (n_held, hidden, width) and ``down``."""
+        n, d = m.shape
+        k, held = self.experts_per_token, self.n_held
+        with jax.named_scope("router"):
+            probs = jax.nn.softmax(_mm(m, p["router"]), axis=-1)
+            top_p, top_e = jax.lax.top_k(probs, k)
+            weight = top_p / jnp.sum(top_p, axis=-1, keepdims=True)  # norm_topk_prob
+        with jax.named_scope("dispatch"):
+            local = top_e - self.first_held
+            local = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
+            # picks sorted by held expert, the others last: pick i is token i // k
+            sorted_e, order = jax.lax.sort(
+                (local, jnp.arange(n * k, dtype=jnp.int32)), num_keys=1, is_stable=True)
+            starts = jnp.searchsorted(sorted_e, jnp.arange(held + 1, dtype=jnp.int32)).astype(jnp.int32)
+        chunk = self.pick_chunk(n)
+        order = jnp.pad(order, (0, -(n * k) % chunk))  # a last chunk may run past the picks
+        y = _held_experts(m, weight.reshape(-1), p["gate"], p["up"], p["down"], order, starts, chunk, k)
+        return y, starts[1:] - starts[:-1]
+
+    def head(self, params, h):
+        """Logits of positions ``h`` (..., hidden), float32 over the ids held here."""
+        return _mm(_rms(h, params["norm_f"], self.rms_eps), params["head"])

@@ -2,24 +2,37 @@
 
 Tiled online-softmax attention: the [L, L] score matrix is never
 materialized in HBM. fp32 accumulation regardless of input dtype; MXU matmuls
-via ``preferred_element_type``. Two entry points:
+via ``preferred_element_type``. One mechanism under two masks, and one older
+forward kernel:
 
-``flash_attention`` (causal or full, one K/V head a query head): grid =
-(B*H, q_blocks, k_blocks); the innermost grid dimension is sequential on TPU,
-so VMEM scratch carries the (m, l, acc) online-softmax state across k blocks
-and the output block is written once on the last k step. Its backward
-recomputes attention densely under XLA (``@jax.custom_vjp``): exact
-gradients, O(L^2) memory on the backward only, so it is for sequences whose
-dense scores fit. No model of the zoo calls it; ``parallel/sequence.py``'s
-ring attention takes dense blocks of its own.
+**Interval attention** (``interval_attention``): query ``i`` reads the keys
+``[lo_i, i]``, ``lo`` an int32 array that comes with the batch. A causal
+sequence (``lo`` = 0), a window (``window``: no key before ``i - window + 1``)
+and packed documents (``lo_i`` = the start of ``i``'s document) are this one
+thing. K/V heads are shared by groups of query heads. Which (q tile, k tile)
+pairs are dead, whole or cut is reduced on the device a call from each q
+tile's least and greatest ``lo`` and compacted into visit lists that the
+kernels take as prefetched scalars: a dead pair is neither fetched nor
+computed. Forward, dq and dk/dv are all kernels and only the log-sum-exp a
+row is kept between them, so nothing is L^2 anywhere. ``models/mellum_moe.py``
+runs on it, at one packed sequence of 16,384 positions, 32 query heads over 4
+K/V heads, in the benchmark's cell ``mellum2-ep4-pack16k``.
 
-``block_diffusion_attention`` (K/V heads shared by groups of query heads, the
-block-diffusion mask of ``(seq_len, block_len)``, dead tile pairs never
-visited, a tile the mask cuts walked by sub-tiles over what it holds):
-forward, dq and dk/dv are all kernels and only the log-sum-exp a row is kept
-between them, so nothing is L^2 anywhere. ``models/sdar_moe.py`` runs on it,
-at 2 x 8,192 positions and 32 query heads over 4 K/V heads in the
-benchmark's sequence cell.
+**The block-diffusion mask** (``block_diffusion_attention``) beside it: the
+same three kernel bodies, the mask given by ``(seq_len, block_len)``, so its
+visit tables are numpy made when the step is traced; a tile the mask cuts is
+walked by sub-tiles over what it holds. ``models/sdar_moe.py`` runs on it, at
+2 x 8,192 positions in the cell ``sdar-ep8-bd4-seq4k``. The interval kernels
+walk the tile on their diagonal the same way.
+
+``flash_attention`` (one K/V head a query head, any length and head size):
+``causal=True`` pads to whole tiles and lanes and runs the interval kernels,
+forward and backward. Full attention keeps the first forward kernel (grid =
+(B*H, q_blocks, k_blocks), VMEM scratch carries the online-softmax state
+across k blocks) and a backward that recomputes attention densely under XLA:
+O(L^2) memory on the backward only, for sequences whose dense scores fit. No
+model of the zoo calls it; ``parallel/sequence.py``'s ring attention takes
+dense blocks of its own.
 
 The kernels are compiled by Mosaic unless the caller asks for
 ``interpret=True`` (the CPU tests do); nothing picks the interpreter on the
@@ -44,9 +57,7 @@ _NEG_BIG = -1e30
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-               *, scale: float, causal: bool, block_q: int, block_k: int,
-               seq_len: int):
-    qi = pl.program_id(1)
+               *, scale: float, block_q: int, block_k: int, seq_len: int):
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -56,39 +67,28 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # Causal: blocks entirely above the diagonal contribute nothing — skip
-    # their compute (their DMA is already pipelined; compute is the cost).
-    block_live = True
-    if causal:
-        block_live = ki * block_k <= qi * block_q + block_q - 1
+    q = q_ref[0].astype(jnp.float32)  # [block_q, d]
+    k = k_ref[0].astype(jnp.float32)  # [block_k, d]
+    v = v_ref[0].astype(jnp.float32)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale  # [block_q, block_k]
 
-    @pl.when(block_live)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [block_q, d]
-        k = k_ref[0].astype(jnp.float32)  # [block_k, d]
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [block_q, block_k]
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    mask = k_pos < seq_len  # padded keys never attend
+    s = jnp.where(mask, s, _NEG_BIG)
 
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        mask = k_pos < seq_len  # padded keys never attend
-        if causal:
-            mask = mask & (k_pos <= q_pos)
-        s = jnp.where(mask, s, _NEG_BIG)
-
-        m_prev = m_ref[:]                       # [block_q, 1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)          # [block_q, 1]
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_ref[:] = m_new
+    m_prev = m_ref[:]                       # [block_q, 1]
+    m_cur = jnp.max(s, axis=1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    p = jnp.exp(s - m_new)
+    p = jnp.where(mask, p, 0.0)
+    corr = jnp.exp(m_prev - m_new)          # [block_q, 1]
+    l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    m_ref[:] = m_new
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -99,7 +99,7 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def _fa_forward(q, k, v, scale, causal, block_q, block_k, interpret):
+def _fa_forward(q, k, v, scale, block_q, block_k, interpret):
     b, l, h, d = q.shape
     # Snap the block cap to a power of two so clamping can't produce a block
     # that fails to divide the padded length; pad to lcm(bq, bk) so BOTH
@@ -118,10 +118,7 @@ def _fa_forward(q, k, v, scale, causal, block_q, block_k, interpret):
     qf, kf, vf = prep(q), prep(k), prep(v)
     grid = (b * h, lp // bq, lp // bk)
     out = pl.pallas_call(
-        functools.partial(
-            _fa_kernel, scale=scale, causal=causal,
-            block_q=bq, block_k=bk, seq_len=l,
-        ),
+        functools.partial(_fa_kernel, scale=scale, block_q=bq, block_k=bk, seq_len=l),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
@@ -140,26 +137,37 @@ def _fa_forward(q, k, v, scale, causal, block_q, block_k, interpret):
     return jnp.moveaxis(out[:, :l, :].reshape(b, h, l, d), 1, 2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
-    return _fa_forward(q, k, v, scale, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, scale, block_q, block_k, interpret):
+    return _fa_forward(q, k, v, scale, block_q, block_k, interpret)
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
-    return _fa_forward(q, k, v, scale, causal, block_q, block_k, interpret), (q, k, v)
+def _flash_fwd(q, k, v, scale, block_q, block_k, interpret):
+    return _fa_forward(q, k, v, scale, block_q, block_k, interpret), (q, k, v)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(scale, block_q, block_k, interpret, res, g):
     from persia_tpu.parallel.sequence import reference_attention
 
     q, k, v = res
-    _, vjp = jax.vjp(
-        lambda q, k, v: reference_attention(q, k, v, causal=causal, scale=scale), q, k, v
-    )
+    _, vjp = jax.vjp(lambda q, k, v: reference_attention(q, k, v, scale=scale), q, k, v)
     return vjp(g)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _causal(q, k, v, scale, tile, interpret):
+    """A causal sequence through ``interval_attention`` (``lo`` = 0): the
+    length padded to whole tiles (padded keys lie after every real query) and
+    the head size to whole lanes (zeros add nothing to a score)."""
+    b, l, h, d = q.shape
+    tile = min(_round_up(tile, 8), _round_up(l, 8))
+    pad = ((0, 0), (0, _round_up(l, tile) - l), (0, 0), (0, _round_up(d, 128) - d))
+    out = interval_attention(jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad),
+                             jnp.zeros((b, l + pad[1][1]), jnp.int32), scale=scale, tile=tile,
+                             interpret=interpret)
+    return out[:, :l, :, :d]
 
 
 def flash_attention(
@@ -172,16 +180,20 @@ def flash_attention(
     block_k: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    """Tiled attention: q, k, v [B, L, H, D] → [B, L, H, D].
+    """Tiled attention: q, k, v [B, L, H, D] → [B, L, H, D]. ``causal`` runs
+    the interval kernels (square tiles of the smaller block, forward and
+    backward); full attention the forward kernel above.
 
-    ``interpret=True`` runs the kernel in the Pallas interpreter (CPU
-    tests); the default compiles it, which needs a TPU.
+    ``interpret=True`` runs the kernels in the Pallas interpreter (CPU
+    tests); the default compiles them, which needs a TPU.
     """
     if q.ndim != 4:
         raise ValueError(f"expected [B, L, H, D], got shape {q.shape}")
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    return _flash(q, k, v, scale, causal, block_q, block_k, interpret)
+    if causal:
+        return _causal(q, k, v, scale, min(block_q, block_k), interpret)
+    return _flash(q, k, v, scale, block_q, block_k, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -207,6 +219,7 @@ ATTENTION_OUT, ATTENTION_LSE = "block_diffusion_attention_out", "block_diffusion
 # of one of three kinds, named by the test a key's block passes against the
 # query's: the noised diagonal, noised queries on clean keys, the clean diagonal.
 _DEAD, _WHOLE, _EQ, _LT, _LE = range(5)
+_LO = 5  # interval attention: a tile the batch's intervals cut anywhere but along its own diagonal
 _CUT = {_EQ: operator.eq, _LT: operator.lt, _LE: operator.le}  # key block ? query block
 _KIND_NAMES = {_WHOLE: "whole", _EQ: "noised_diagonal", _LT: "noised_on_clean", _LE: "clean_diagonal"}
 
@@ -339,39 +352,48 @@ def _bd_scores(q, k, scale, test, block_len, q_at, k_at):
                      s, _NEG_BIG)
 
 
-def _walk(kind, visit, tile, sub, by_keys):
+def _walk(kind, visit, tile, sub, by_keys, cuts=_CUT, by_lo=False):
     """Runs ``visit(queries, keys, test)`` over what a tile pair of ``kind`` (a
-    prefetched scalar) holds: a whole tile in one visit; a cut tile in the
-    slabs of ``_cut_slabs``, by query columns in the kernels that keep sums
-    a query and by key rows in the one that keeps sums a key, so that each sum
-    runs over the operands it ran over before, in their order, less the exact
-    zeros of what no query of the slab can read: those are never computed."""
+    prefetched scalar) holds: a whole tile in one visit; a cut tile of
+    ``cuts`` in the slabs of ``_cut_slabs``, by query columns in the kernels
+    that keep sums a query and by key rows in the one that keeps sums a key,
+    so that each sum runs over the operands it ran over before, in their
+    order, less the exact zeros of what no query of the slab can read: those
+    are never computed. ``by_lo``: a tile the batch's intervals cut off the
+    diagonal (``_LO``) is one visit, its mask the visit's own to make."""
     pl.when(kind == _WHOLE)(lambda: visit(pl.ds(0, tile), pl.ds(0, tile), None))
-    for cut, test in _CUT.items():
+    for cut, test in cuts.items():
         @pl.when(kind == cut)
         def _cut(cut=cut, test=test):
             for q0, nq, k0, nk in _cut_slabs(cut, tile // sub, by_keys):
                 visit(pl.ds(q0 * sub, nq * sub), pl.ds(k0 * sub, nk * sub), test)
+    if by_lo:
+        pl.when(kind == _LO)(lambda: visit(pl.ds(0, tile), pl.ds(0, tile), _LO))
 
 
-def _bd_fwd_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref,
-                   o_ref, lse_ref, m_ref, l_ref, acc_ref, *, scale, tile, sub, block_len):
-    p = pl.program_id(2)
+# The three kernels' bodies, shared by the two masks. ``at`` is the grid
+# step's place in the prefetched tables, ``scores(q, k, test, queries, keys)``
+# the mask's scaled and masked scores of a slab (keys down, queries across),
+# ``walk(kind, visit, by_keys=...)`` its walk over a tile pair of that kind.
 
-    @pl.when(first_ref[p] == 1)
+def _fwd_body(at, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+              m_ref, l_ref, acc_ref, scores, walk):
+    @pl.when(first_ref[at] == 1)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, _NEG_BIG)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     def visit(queries, keys, test):
-        st = _bd_scores(q_ref[0, queries], k_ref[0, keys], scale, test, block_len,
-                        queries.start, keys.start)
+        st = scores(q_ref[0, queries], k_ref[0, keys], test, queries, keys)
         m_prev = m_ref[:1, queries]
         m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
-        # the first keys a query visits hold a key it may read (its own block,
-        # or block 0 of the clean half), so m_new is a real score from then on
-        # and a masked score's exp is 0
+        # once a query has met a key it may read, m_new is a real score and a
+        # masked score's exp is 0. Under the block-diffusion mask its first keys
+        # hold one (its own block, or block 0 of the clean half); under
+        # intervals they may not (a document that starts inside the tile):
+        # what it sums until then is finite and leaves with corr = 0 at the
+        # first real score, which its own key on the diagonal is at the latest
         pt = jnp.exp(st - m_new)
         corr = jnp.exp(m_prev - m_new)
         l = l_ref[:1, queries] * corr + jnp.sum(pt, axis=0, keepdims=True)
@@ -383,25 +405,23 @@ def _bd_fwd_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, q_ref, k_ref
             preferred_element_type=jnp.float32, precision=_ONE_PASS)
         m_ref[:, queries] = jnp.broadcast_to(m_new, (_STAT_ROWS, queries.size))
 
-    _walk(kind_ref[p], visit, tile, sub, by_keys=False)
+    walk(kind_ref[at], visit, by_keys=False)
 
-    @pl.when(last_ref[p] == 1)
+    @pl.when(last_ref[at] == 1)
     def _finalize():
         o_ref[0] = jnp.transpose(acc_ref[:] / l_ref[:1]).astype(o_ref.dtype)
         lse_ref[0, 0] = m_ref[:1] + jnp.log(l_ref[:1])
 
 
-def _bd_dq_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref,
-                  lse_ref, delta_ref, dq_ref, acc_ref, *, scale, tile, sub, block_len):
-    p = pl.program_id(2)
-
-    @pl.when(first_ref[p] == 1)
+def _dq_body(at, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+             dq_ref, acc_ref, scores, walk, scale):
+    @pl.when(first_ref[at] == 1)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     def visit(queries, keys, test):
         k, do = k_ref[0, keys], do_ref[0, queries]
-        st = _bd_scores(q_ref[0, queries], k, scale, test, block_len, queries.start, keys.start)
+        st = scores(q_ref[0, queries], k, test, queries, keys)
         pt = jnp.exp(st - lse_ref[0, 0, :, queries])
         dpt = jax.lax.dot_general(v_ref[0, keys], do, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32, precision=_ONE_PASS)
@@ -410,29 +430,28 @@ def _bd_dq_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, q_ref, k_ref,
                                                 preferred_element_type=jnp.float32,
                                                 precision=_ONE_PASS)
 
-    _walk(kind_ref[p], visit, tile, sub, by_keys=False)
+    walk(kind_ref[at], visit, by_keys=False)
 
-    @pl.when(last_ref[p] == 1)
+    @pl.when(last_ref[at] == 1)
     def _finalize():
         dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
-def _bd_dkv_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref,
-                   lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, tile, sub, group,
-                   block_len):
+def _dkv_body(at, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+              dk_ref, dv_ref, dk_acc, dv_acc, scores, walk, scale, group):
     # here a "row" of the tables is a k tile and its "columns" the q tiles that
     # read it; a pair's kind is the same pair's, and a cut tile is walked by
     # key rows, each over the query columns that read it
-    p, g = pl.program_id(2), pl.program_id(3)
+    g = pl.program_id(3)
 
-    @pl.when((first_ref[p] == 1) & (g == 0))
+    @pl.when((first_ref[at] == 1) & (g == 0))
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     def visit(queries, keys, test):
         q, do = q_ref[0, queries], do_ref[0, queries]
-        st = _bd_scores(q, k_ref[0, keys], scale, test, block_len, queries.start, keys.start)
+        st = scores(q, k_ref[0, keys], test, queries, keys)
         pt = jnp.exp(st - lse_ref[0, 0, :, queries])
         dv_acc[keys] += jax.lax.dot_general(pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
                                             preferred_element_type=jnp.float32, precision=_ONE_PASS)
@@ -442,12 +461,38 @@ def _bd_dkv_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, q_ref, k_ref
         dk_acc[keys] += jax.lax.dot_general(dst, q, (((1,), (0,)), ((), ())),
                                             preferred_element_type=jnp.float32, precision=_ONE_PASS)
 
-    _walk(kind_ref[p], visit, tile, sub, by_keys=True)
+    walk(kind_ref[at], visit, by_keys=True)
 
-    @pl.when((last_ref[p] == 1) & (g == group - 1))
+    @pl.when((last_ref[at] == 1) & (g == group - 1))
     def _finalize():
         dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _bd_mask(scale, tile, sub, block_len):
+    """The block-diffusion mask's ``scores`` and ``walk`` for the bodies above."""
+    def scores(q, k, test, queries, keys):
+        return _bd_scores(q, k, scale, test, block_len, queries.start, keys.start)
+
+    return scores, functools.partial(_walk, tile=tile, sub=sub)
+
+
+def _bd_fwd_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, *refs, scale, tile, sub,
+                   block_len):
+    _fwd_body(pl.program_id(2), kind_ref, first_ref, last_ref, *refs,
+              *_bd_mask(scale, tile, sub, block_len))
+
+
+def _bd_dq_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, *refs, scale, tile, sub,
+                  block_len):
+    _dq_body(pl.program_id(2), kind_ref, first_ref, last_ref, *refs,
+             *_bd_mask(scale, tile, sub, block_len), scale)
+
+
+def _bd_dkv_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, *refs, scale, tile, sub, group,
+                   block_len):
+    _dkv_body(pl.program_id(2), kind_ref, first_ref, last_ref, *refs,
+              *_bd_mask(scale, tile, sub, block_len), scale, group)
 
 
 _STAT_ROWS = 8  # the forward's running max and sum a query: one row, kept in a whole (8, tile) tile
@@ -462,28 +507,34 @@ def _bd_plan(q, k, seq_len, block_len, tile):
     if seq_len % tile or tile % 8 or d % 128:
         raise ValueError(f"seq_len {seq_len} must be a multiple of the tile {tile}, the tile of "
                          f"8, and the head size {d} of 128")
-    return b, t, hq, hkv, d, tile, _sub_tile(tile, block_len), _live_tiles(seq_len, block_len, tile)
+    return tile, _sub_tile(tile, block_len), _live_tiles(seq_len, block_len, tile)
 
 
-def _bd_forward(q, k, v, seq_len, block_len, scale, tile, interpret):
-    b, t, hq, hkv, d, tile, sub, kinds = _bd_plan(q, k, seq_len, block_len, tile)
-    group = hq // hkv
-    tables = _visit_tables(kinds)
+def _forward_call(kernel, name, tables, per_batch, q, k, v, lo, tile, interpret):
+    """The forward kernel over the visits of ``tables``: ``per_batch`` 0 where
+    one list of visits serves every sequence (the block-diffusion mask), else
+    the visits a sequence, each with a list of its own; ``lo`` (B, 1, T) the
+    intervals' starts where the kernel takes them, else None."""
+    b, t, hq, d = q.shape
+    group = hq // k.shape[2]
+    at = (lambda bi, p: p) if not per_batch else (lambda bi, p: bi * per_batch + p)
     # heads stay where the projections left them: a head is a 128-lane column
     # block of the (B, T, H * D) array, so nothing is transposed in HBM
     q2, k2, v2 = (x.reshape(b, t, -1) for x in (q, k, v))
-    q_at = lambda bi, h, p, row, col, *_: (bi, row[p], h)
-    kv_at = lambda bi, h, p, row, col, *_: (bi, col[p], h // group)
+    q_at = lambda bi, h, p, row, col, *_: (bi, row[at(bi, p)], h)
+    kv_at = lambda bi, h, p, row, col, *_: (bi, col[at(bi, p)], h // group)
+    lo_in = [] if lo is None else [
+        pl.BlockSpec((1, 1, tile), lambda bi, h, p, row, *_: (bi, 0, row[at(bi, p)]))]
     out, lse = pl.pallas_call(
-        functools.partial(_bd_fwd_kernel, scale=scale, tile=tile, sub=sub,
-                          block_len=block_len),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
-            grid=(b, hq, tables[0].shape[0]),
-            in_specs=[pl.BlockSpec((1, tile, d), q_at), pl.BlockSpec((1, tile, d), kv_at),
-                      pl.BlockSpec((1, tile, d), kv_at)],
+            grid=(b, hq, per_batch or tables[0].shape[0]),
+            in_specs=lo_in + [pl.BlockSpec((1, tile, d), q_at), pl.BlockSpec((1, tile, d), kv_at),
+                              pl.BlockSpec((1, tile, d), kv_at)],
             out_specs=[pl.BlockSpec((1, tile, d), q_at),
-                       pl.BlockSpec((1, 1, 1, tile), lambda bi, h, p, row, *_: (bi, h, 0, row[p]))],
+                       pl.BlockSpec((1, 1, 1, tile),
+                                    lambda bi, h, p, row, *_: (bi, h, 0, row[at(bi, p)]))],
             scratch_shapes=[pltpu.VMEM((_STAT_ROWS, tile), jnp.float32),
                             pltpu.VMEM((_STAT_ROWS, tile), jnp.float32),
                             pltpu.VMEM((d, tile), jnp.float32)],
@@ -493,34 +544,42 @@ def _bd_forward(q, k, v, seq_len, block_len, scale, tile, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="block_diffusion_attention_fwd",
-    )(*tables, q2, k2, v2)
+        name=f"{name}_fwd",
+    )(*tables, *([] if lo is None else [lo]), q2, k2, v2)
     return out.reshape(b, t, hq, d), lse
 
 
-def _bd_backward(q, k, v, out, lse, do, seq_len, block_len, scale, tile, interpret):
-    b, t, hq, hkv, d, tile, sub, kinds = _bd_plan(q, k, seq_len, block_len, tile)
+def _backward_call(dq_kernel, dkv_kernel, name, tables, tables_t, per_batch, q, k, v, out, lse, do,
+                   lo, tile, interpret):
+    """dq over ``tables`` (a q tile's k tiles) and dk, dv over ``tables_t`` (a k
+    tile's q tiles, the group's query heads innermost so that a k tile's sums
+    stay in VMEM); the arguments as ``_forward_call``'s."""
+    b, t, hq, d = q.shape
+    hkv = k.shape[2]
     group = hq // hkv
+    at = (lambda bi, p: p) if not per_batch else (lambda bi, p: bi * per_batch + p)
+    visits = per_batch or tables[0].shape[0]
     # sum_j P_ij dP_ij = o_i . do_i: a row statistic too, lane-dense like lse
     delta = jnp.einsum("bthd,bthd->bht", out.astype(jnp.float32),
                        do.astype(jnp.float32))[:, :, None, :]
     do = do.astype(q.dtype)
     q2, k2, v2, do2 = (x.reshape(b, t, -1) for x in (q, k, v, do))
+    lo_arg = [] if lo is None else [lo]
 
-    tables = _visit_tables(kinds)
-    q_at = lambda bi, h, p, row, col, *_: (bi, row[p], h)
-    kv_at = lambda bi, h, p, row, col, *_: (bi, col[p], h // group)
-    stat_at = lambda bi, h, p, row, *_: (bi, h, 0, row[p])
+    q_at = lambda bi, h, p, row, col, *_: (bi, row[at(bi, p)], h)
+    kv_at = lambda bi, h, p, row, col, *_: (bi, col[at(bi, p)], h // group)
+    stat_at = lambda bi, h, p, row, *_: (bi, h, 0, row[at(bi, p)])
+    lo_in = [] if lo is None else [
+        pl.BlockSpec((1, 1, tile), lambda bi, h, p, row, *_: (bi, 0, row[at(bi, p)]))]
     dq = pl.pallas_call(
-        functools.partial(_bd_dq_kernel, scale=scale, tile=tile, sub=sub,
-                          block_len=block_len),
+        dq_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
-            grid=(b, hq, tables[0].shape[0]),
-            in_specs=[pl.BlockSpec((1, tile, d), q_at), pl.BlockSpec((1, tile, d), kv_at),
-                      pl.BlockSpec((1, tile, d), kv_at), pl.BlockSpec((1, tile, d), q_at),
-                      pl.BlockSpec((1, 1, 1, tile), stat_at),
-                      pl.BlockSpec((1, 1, 1, tile), stat_at)],
+            grid=(b, hq, visits),
+            in_specs=lo_in + [pl.BlockSpec((1, tile, d), q_at), pl.BlockSpec((1, tile, d), kv_at),
+                              pl.BlockSpec((1, tile, d), kv_at), pl.BlockSpec((1, tile, d), q_at),
+                              pl.BlockSpec((1, 1, 1, tile), stat_at),
+                              pl.BlockSpec((1, 1, 1, tile), stat_at)],
             out_specs=pl.BlockSpec((1, tile, d), q_at),
             scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)],
         ),
@@ -528,25 +587,23 @@ def _bd_backward(q, k, v, out, lse, do, seq_len, block_len, scale, tile, interpr
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="block_diffusion_attention_dq",
-    )(*tables, q2, k2, v2, do2, lse, delta)
+        name=f"{name}_dq",
+    )(*tables, *lo_arg, q2, k2, v2, do2, lse, delta)
 
-    # for a k tile, the q tiles that read it: the transposed tables; the
-    # group's query heads innermost, so that a k tile's sums stay in VMEM
-    tables = _visit_tables(kinds.T)
-    kv_at = lambda bi, hk, p, g, row, col, *_: (bi, row[p], hk)
-    q_at = lambda bi, hk, p, g, row, col, *_: (bi, col[p], hk * group + g)
-    stat_at = lambda bi, hk, p, g, row, col, *_: (bi, hk * group + g, 0, col[p])
+    kv_at = lambda bi, hk, p, g, row, col, *_: (bi, row[at(bi, p)], hk)
+    q_at = lambda bi, hk, p, g, row, col, *_: (bi, col[at(bi, p)], hk * group + g)
+    stat_at = lambda bi, hk, p, g, row, col, *_: (bi, hk * group + g, 0, col[at(bi, p)])
+    lo_in = [] if lo is None else [
+        pl.BlockSpec((1, 1, tile), lambda bi, hk, p, g, row, col, *_: (bi, 0, col[at(bi, p)]))]
     dk, dv = pl.pallas_call(
-        functools.partial(_bd_dkv_kernel, scale=scale, tile=tile, sub=sub, group=group,
-                          block_len=block_len),
+        dkv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
-            grid=(b, hkv, tables[0].shape[0], group),
-            in_specs=[pl.BlockSpec((1, tile, d), q_at), pl.BlockSpec((1, tile, d), kv_at),
-                      pl.BlockSpec((1, tile, d), kv_at), pl.BlockSpec((1, tile, d), q_at),
-                      pl.BlockSpec((1, 1, 1, tile), stat_at),
-                      pl.BlockSpec((1, 1, 1, tile), stat_at)],
+            grid=(b, hkv, visits, group),
+            in_specs=lo_in + [pl.BlockSpec((1, tile, d), q_at), pl.BlockSpec((1, tile, d), kv_at),
+                              pl.BlockSpec((1, tile, d), kv_at), pl.BlockSpec((1, tile, d), q_at),
+                              pl.BlockSpec((1, 1, 1, tile), stat_at),
+                              pl.BlockSpec((1, 1, 1, tile), stat_at)],
             out_specs=[pl.BlockSpec((1, tile, d), kv_at), pl.BlockSpec((1, tile, d), kv_at)],
             scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32),
                             pltpu.VMEM((tile, d), jnp.float32)],
@@ -556,9 +613,26 @@ def _bd_backward(q, k, v, out, lse, do, seq_len, block_len, scale, tile, interpr
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-        name="block_diffusion_attention_dkv",
-    )(*tables, q2, k2, v2, do2, lse, delta)
+        name=f"{name}_dkv",
+    )(*tables_t, *lo_arg, q2, k2, v2, do2, lse, delta)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+def _bd_forward(q, k, v, seq_len, block_len, scale, tile, interpret):
+    tile, sub, kinds = _bd_plan(q, k, seq_len, block_len, tile)
+    kernel = functools.partial(_bd_fwd_kernel, scale=scale, tile=tile, sub=sub, block_len=block_len)
+    return _forward_call(kernel, "block_diffusion_attention", _visit_tables(kinds), 0,
+                         q, k, v, None, tile, interpret)
+
+
+def _bd_backward(q, k, v, out, lse, do, seq_len, block_len, scale, tile, interpret):
+    tile, sub, kinds = _bd_plan(q, k, seq_len, block_len, tile)
+    how = dict(scale=scale, tile=tile, sub=sub, block_len=block_len)
+    return _backward_call(
+        functools.partial(_bd_dq_kernel, **how),
+        functools.partial(_bd_dkv_kernel, group=q.shape[2] // k.shape[2], **how),
+        "block_diffusion_attention", _visit_tables(kinds), _visit_tables(kinds.T), 0,
+        q, k, v, out, lse, do, None, tile, interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -615,4 +689,215 @@ def block_diffusion_attention(
     if q.ndim != 4:
         raise ValueError(f"expected [B, 2L, H, D], got shape {q.shape}")
     return _bd_attention(q, k, v, int(seq_len), int(block_len), q.shape[-1] ** -0.5, int(tile),
+                         interpret)
+
+
+# ---------------------------------------------------------------------------
+# Interval attention: query i reads the keys [lo_i, i], and ``lo`` comes with
+# the batch. Causal (lo = 0), windowed (lo_i = i - w + 1) and packed documents
+# (lo_i = the start of i's document) are this one mechanism; which tile pairs
+# are dead, whole or cut is worked out on the device a call.
+# ---------------------------------------------------------------------------
+
+_DIAGONAL = {_LE: operator.le}  # a tile cut by its own diagonal alone: key position <= query position
+
+
+def _interval_lo(lo, window):
+    """``lo`` (B, T) clipped to what a query may read at all: not before 0,
+    not after itself, not before ``i - window + 1`` under a window."""
+    at = jnp.arange(lo.shape[1], dtype=jnp.int32)[None, :]
+    floor = 0 if window is None else jnp.maximum(at - (window - 1), 0)
+    return jnp.clip(lo.astype(jnp.int32), floor, at)
+
+
+def _interval_kinds(lo, tile):
+    """The kind of every (q tile, k tile) pair, (B, n, n) int32, from the q
+    tile's least and greatest ``lo`` against the k tile's ends: dead above
+    the diagonal and where the k tile ends before any query's interval
+    starts; whole below the diagonal where it starts after every query's
+    ``lo``; the tile on the diagonal that no ``lo`` enters is cut by the
+    diagonal alone (``_LE``), every other by ``lo`` (``_LO``). With square
+    tiles a pair that is not dead holds a pair some query reads (the query
+    with the least ``lo`` and the later of that ``lo`` and the k tile's first
+    key), so nothing dead is ever visited."""
+    b, t = lo.shape
+    n = t // tile
+    by_tile = lo.reshape(b, n, tile)
+    lo_min, lo_max = by_tile.min(axis=-1)[:, :, None], by_tile.max(axis=-1)[:, :, None]
+    qi = jnp.arange(n, dtype=jnp.int32)[None, :, None]
+    ki = jnp.arange(n, dtype=jnp.int32)[None, None, :]
+    k0 = ki * tile
+    dead = (ki > qi) | (k0 + tile - 1 < lo_min)
+    whole = (ki < qi) & (k0 >= lo_max)
+    cut = jnp.where((ki == qi) & (lo_max <= k0), _LE, _LO)
+    return jnp.where(dead, _DEAD, jnp.where(whole, _WHOLE, cut)).astype(jnp.int32)
+
+
+def interval_visits(n_tiles: int, tile: int, window: Optional[int] = None) -> int:
+    """The most tile pairs a sequence of ``n_tiles`` tiles can have live,
+    whatever ``lo`` is: the triangle, or under a window the k tiles a q
+    tile's window reaches. The kernels' grids are this long; a sequence's
+    live pairs come first and the steps after them neither fetch nor compute."""
+    reach = n_tiles if window is None else -(-(window - 1) // tile) + 1
+    return sum(min(qi + 1, reach) for qi in range(n_tiles))
+
+
+def _interval_tables(kinds, visits):
+    """``_visit_tables`` made on the device, a sequence: the live pairs of
+    ``kinds`` (B, n, n) in row-major order, padded to ``visits`` with dead
+    steps at the last live pair's place (the same blocks: nothing is fetched
+    for them), as flat (B * visits,) int32 arrays for scalar prefetch."""
+    b, n, _ = kinds.shape
+    flat = kinds.reshape(b, n * n)
+    count = jnp.sum(flat != _DEAD, axis=1, dtype=jnp.int32)[:, None]
+    at = jax.vmap(lambda f: jnp.nonzero(f, size=visits, fill_value=0)[0])(flat).astype(jnp.int32)
+    p = jnp.arange(visits, dtype=jnp.int32)[None, :]
+    live = p < count
+    at = jnp.where(live, at, jnp.take_along_axis(at, count - 1, axis=1))
+    rows, cols = at // n, at % n
+    kind = jnp.where(live, jnp.take_along_axis(flat, at, axis=1), _DEAD)
+    edge = jnp.full((b, 1), -1, jnp.int32)
+    first = live & (rows != jnp.concatenate([edge, rows[:, :-1]], axis=1))
+    last = live & ((rows != jnp.concatenate([rows[:, 1:], edge], axis=1)) | (p == count - 1))
+    return tuple(x.reshape(-1).astype(jnp.int32) for x in (rows, cols, kind, first, last))
+
+
+def interval_tile_counts(lo, window: Optional[int] = None, tile: int = BLOCK_DIFFUSION_TILE):
+    """(tile pairs the kernels visit, tile pairs that hold a pair some query
+    reads) of one head over the batch, int32 scalars on the device: the first
+    from the kinds the visit lists are made of, the second from each query's
+    own first and last k tile. A counter for the caller to keep."""
+    tile = min(tile, lo.shape[1])
+    lo = _interval_lo(lo, window)
+    visited = jnp.sum(_interval_kinds(lo, tile) != _DEAD, dtype=jnp.int32)
+    first_tile = (lo // tile).reshape(lo.shape[0], -1, tile).min(axis=-1)  # (B, n)
+    own = jnp.arange(first_tile.shape[1], dtype=jnp.int32)[None, :]
+    return visited, jnp.sum(own - first_tile + 1, dtype=jnp.int32)
+
+
+def _iv_mask(scale, tile, sub, lo_ref, q_tile, k_tile):
+    """The interval mask's ``scores`` and ``walk``: a tile cut by its diagonal
+    alone goes the block-diffusion kernels' way (blocks of one position); any
+    other cut tile takes ``lo_j <= key <= query`` from the keys' positions (a
+    column), the queries' (a row) and the queries' ``lo`` (a row)."""
+    def scores(q, k, test, queries, keys):
+        if test is None or callable(test):
+            return _bd_scores(q, k, scale, test, 1, queries.start, keys.start)
+        s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32, precision=_ONE_PASS) * scale
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, (k.shape[0], 1), 0) + (k_tile * tile + keys.start)
+        q_pos = jax.lax.broadcasted_iota(jnp.int32, (1, q.shape[0]), 1) + (q_tile * tile + queries.start)
+        return jnp.where((k_pos >= lo_ref[0, :, queries]) & (k_pos <= q_pos), s, _NEG_BIG)
+
+    return scores, functools.partial(_walk, tile=tile, sub=sub, cuts=_DIAGONAL, by_lo=True)
+
+
+def _iv_fwd_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, lo_ref, *refs, scale, tile, sub,
+                   visits):
+    at = pl.program_id(0) * visits + pl.program_id(2)
+    _fwd_body(at, kind_ref, first_ref, last_ref, *refs,
+              *_iv_mask(scale, tile, sub, lo_ref, row_ref[at], col_ref[at]))
+
+
+def _iv_dq_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, lo_ref, *refs, scale, tile, sub,
+                  visits):
+    at = pl.program_id(0) * visits + pl.program_id(2)
+    _dq_body(at, kind_ref, first_ref, last_ref, *refs,
+             *_iv_mask(scale, tile, sub, lo_ref, row_ref[at], col_ref[at]), scale)
+
+
+def _iv_dkv_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, lo_ref, *refs, scale, tile, sub,
+                   visits, group):
+    at = pl.program_id(0) * visits + pl.program_id(2)  # rows are k tiles here, columns q tiles
+    _dkv_body(at, kind_ref, first_ref, last_ref, *refs,
+              *_iv_mask(scale, tile, sub, lo_ref, col_ref[at], row_ref[at]), scale, group)
+
+
+def _iv_plan(q, k, lo, tile):
+    b, t, hq, d = q.shape
+    hkv = k.shape[2]
+    if k.shape != (b, t, hkv, d) or hq % hkv or lo.shape != (b, t):
+        raise ValueError(f"q {q.shape}, k {k.shape} and lo {lo.shape} do not fit each other")
+    tile = min(tile, t)
+    if t % tile or tile % 8 or d % 128:
+        raise ValueError(f"the length {t} must be a multiple of the tile {tile}, the tile of 8, "
+                         f"and the head size {d} of 128")
+    return tile, _sub_tile(tile, 1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _iv_attention(q, k, v, lo, window, scale, tile, interpret):
+    return _iv_attention_fwd(q, k, v, lo, window, scale, tile, interpret)[0]
+
+
+def _iv_attention_fwd(q, k, v, lo, window, scale, tile, interpret):
+    tile, sub = _iv_plan(q, k, lo, tile)
+    visits = interval_visits(q.shape[1] // tile, tile, window)
+    lo = _interval_lo(lo, window)
+    kinds = _interval_kinds(lo, tile)
+    tables = _interval_tables(kinds, visits)
+    tables_t = _interval_tables(jnp.swapaxes(kinds, 1, 2), visits)
+    lo = lo[:, None, :]  # lane-dense, as the row statistics are
+    kernel = functools.partial(_iv_fwd_kernel, scale=scale, tile=tile, sub=sub, visits=visits)
+    out, lse = _forward_call(kernel, "interval_attention", tables, visits, q, k, v, lo, tile,
+                             interpret)
+    # named as the block-diffusion kernels' are: a caller that recomputes its
+    # layer keeps these two and does not run the forward kernel a second time
+    out, lse = checkpoint_name(out, ATTENTION_OUT), checkpoint_name(lse, ATTENTION_LSE)
+    return out, (q, k, v, out, lse, lo, tables, tables_t)
+
+
+def _iv_attention_bwd(window, scale, tile, interpret, res, do):
+    q, k, v, out, lse, lo, tables, tables_t = res
+    tile, sub = _iv_plan(q, k, lo[:, 0], tile)
+    visits = interval_visits(q.shape[1] // tile, tile, window)
+    how = dict(scale=scale, tile=tile, sub=sub, visits=visits)
+    dq, dk, dv = _backward_call(
+        functools.partial(_iv_dq_kernel, **how),
+        functools.partial(_iv_dkv_kernel, group=q.shape[2] // k.shape[2], **how),
+        "interval_attention", tables, tables_t, visits, q, k, v, out, lse, do, lo, tile, interpret)
+    return dq, dk, dv, None
+
+
+_iv_attention.defvjp(_iv_attention_fwd, _iv_attention_bwd)
+
+
+def interval_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    lo: jax.Array,
+    window: Optional[int] = None,
+    scale: Optional[float] = None,
+    tile: int = BLOCK_DIFFUSION_TILE,
+    interpret: bool = False,
+) -> jax.Array:
+    """Attention in which query ``i`` reads the keys ``[lo_i, i]``: q [B, T,
+    Hq, D], k and v [B, T, Hkv, D], ``lo`` [B, T] int32 -> [B, T, Hq, D];
+    query head ``g`` reads K/V head ``g // (Hq // Hkv)``. ``lo`` is data: 0
+    for a causal sequence, the start of each position's document for packed
+    documents. With ``window`` a query reads no key before ``i - window + 1``
+    either (static: it also bounds the kernels' grids).
+
+    Forward, dq and dk/dv are the block-diffusion kernels' bodies under
+    another mask, over square tiles of ``tile`` positions. A tile pair's kind
+    is reduced on the device from the q tile's least and greatest ``lo``
+    (``_interval_kinds``) and the live pairs are compacted into a visit list
+    a sequence (``_interval_tables``), handed to the kernels as prefetched
+    scalars: a dead pair is neither fetched nor computed, a whole pair runs
+    unmasked, the tile on the diagonal is walked by sub-tiles as the
+    block-diffusion mask's clean diagonal is, and a tile that ``lo`` cuts (a
+    window's trailing edge, a document's first token) takes its mask from
+    ``lo`` and the positions on vectors. The grids run over the worst case
+    (``interval_visits``: the triangle, or the window's reach); the steps past
+    a sequence's live pairs stay on the last pair's blocks and do nothing.
+    Nothing of size T x T is ever built: the backward recomputes the
+    probabilities from the forward's log-sum-exp a row. Products take the
+    inputs' dtype as operands and accumulate in float32; the softmax is
+    float32, over scores scaled by ``scale`` (``D ** -0.5``).
+    """
+    if q.ndim != 4:
+        raise ValueError(f"expected [B, T, H, D], got shape {q.shape}")
+    scale = q.shape[-1] ** -0.5 if scale is None else float(scale)
+    return _iv_attention(q, k, v, lo, None if window is None else int(window), scale, int(tile),
                          interpret)

@@ -88,16 +88,18 @@ def batch_to_fused(
                 padded[i, :c] = flat[off:off + c]
                 off += c
             ids[f.name] = padded
+    # integers stay integers: a sequence model's target ids among the labels,
+    # its per-position side inputs (where each position's document starts)
+    # among the non-id features; everything else is float32
+    def _staged(x) -> np.ndarray:
+        return np.asarray(x, np.int32 if np.issubdtype(x.dtype, np.integer) else np.float32)
+
     out = {
-        "dense": [np.asarray(d.data, np.float32) for d in batch.non_id_type_features],
+        "dense": [_staged(d.data) for d in batch.non_id_type_features],
         "ids": ids,
     }
     if batch.labels:
-        # integer labels (a sequence model's target ids) stay integers
-        out["labels"] = [
-            np.asarray(l.data, np.int32 if np.issubdtype(l.data.dtype, np.integer) else np.float32)
-            for l in batch.labels
-        ]
+        out["labels"] = [_staged(l.data) for l in batch.labels]
     return out
 
 

@@ -348,9 +348,12 @@ def build_fused_train_step(
     groups = group_stacked_specs(specs, slot_order) if stack else None
     # a model may state its own loss, over all of the batch's labels, and what
     # a step hands back beside it (``models/sdar_moe.py``); the click models
-    # state neither and keep ``loss_fn`` on the first label and the sigmoid
+    # state neither and keep ``loss_fn`` on the first label and the sigmoid.
+    # A model whose logits must never stand whole states ``train_loss``
+    # (``models/mellum_moe.py``): loss, outputs and counters in one call
     model_loss = getattr(model, "loss", None)
     model_outputs = getattr(model, "outputs", jax.nn.sigmoid)
+    model_train_loss = getattr(model, "train_loss", None)
 
     def step(state: FusedTrainState, batch: Dict):
         ids = batch["ids"]
@@ -367,6 +370,12 @@ def build_fused_train_step(
             with jax.named_scope("pool"):
                 model_emb = _model_inputs(specs, slot_order, gathered, ids)
             variables = {"params": params}
+            if model_train_loss is not None:
+                if state.batch_stats:
+                    variables["batch_stats"] = state.batch_stats
+                loss, outs, new_stats = model_train_loss(
+                    variables, batch["dense"], model_emb, batch["labels"])
+                return loss, (outs, new_stats if state.batch_stats else state.batch_stats)
             if state.batch_stats:
                 variables["batch_stats"] = state.batch_stats
                 logits, updates = model.apply(
@@ -451,7 +460,7 @@ def build_fused_train_step(
             emb_batch_state=batch_state,
             step=state.step + 1,
         )
-        return new_state, (loss, model_outputs(logits))
+        return new_state, (loss, logits if model_train_loss is not None else model_outputs(logits))
 
     if not jit:
         return step

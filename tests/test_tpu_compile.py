@@ -157,6 +157,50 @@ def test_block_diffusion_attention_compiles_at_the_cells_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 600 * 2**20
 
 
+# ---- the packed-documents cell's kernels (mellum2-ep4-pack16k, ISSUE 35) ----
+
+@pytest.mark.parametrize("what,window", [("forward", None), ("backward", None), ("backward", 1024)],
+                         ids=["forward-full", "backward-full", "backward-sliding"])
+def test_interval_attention_compiles_at_the_cells_widths(
+        one_chip, no_compile_cache, highest_by_default, what, window):
+    """32 query heads over 4 K/V heads of 128, one sequence of 16,384
+    positions, ``lo`` an argument, tiles of 512: three Mosaic kernels whose
+    visit lists are made on the device, nothing T x T in HBM."""
+    from persia_tpu.ops.flash_attention import interval_attention, interval_visits
+
+    length = 16384
+    q = jax.ShapeDtypeStruct((1, length, 32, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, length, 4, 128), jnp.bfloat16, sharding=one_chip)
+    lo = jax.ShapeDtypeStruct((1, length), jnp.int32, sharding=one_chip)
+
+    def forward(q, k, v, lo):
+        return interval_attention(q, k, v, lo, window=window)
+
+    def backward(q, k, v, lo):
+        return jax.grad(lambda *a: forward(*a, lo).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(forward if what == "forward" else backward).lower(q, kv, kv, lo).compile()
+    text = compiled.as_text()
+    kernels = sorted(set(re.findall(r"interval_attention_\w+", text)))
+    want = ["interval_attention_fwd"] if what == "forward" else [
+        "interval_attention_dkv", "interval_attention_dq", "interval_attention_fwd"]
+    assert [k for k in want if any(k in name for name in kernels)] == want, kernels
+    # the scores of one head alone would be 1 GB in float32
+    assert compiled.memory_analysis().temp_size_in_bytes < 600 * 2**20
+    assert interval_visits(32, 512, window) == (528 if window is None else 93)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_causal_flash_attention_compiles_through_the_interval_kernels(one_chip, no_compile_cache, dtype):
+    """``flash_attention(causal=True)`` as ``chip_smoke.py`` calls it (L 1000, two
+    heads of 64, either dtype): padded to tiles of 256 and lanes of 128."""
+    from persia_tpu.ops import flash_attention
+
+    x = jax.ShapeDtypeStruct((1, 1000, 2, 64), dtype, sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True)).lower(x, x, x).compile()
+    assert "interval_attention_fwd" in compiled.as_text()
+
+
 def test_rows_wider_than_one_tile_column_keep_the_scatter(one_chip, no_compile_cache):
     """An 8 KB row is no contiguous piece of the (8, 128) tiling: Mosaic
     refuses the one-row slice the row-write kernel copies, at 256 lanes as at
