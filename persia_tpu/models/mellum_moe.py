@@ -34,6 +34,7 @@ from persia_tpu.models.moe_tower import MoETower
 from persia_tpu.ops.flash_attention import (
     BLOCK_DIFFUSION_TILE, interval_attention, interval_tile_counts, interval_visits,
 )
+from persia_tpu.ops.qk_prep import qk_prep_tile
 from persia_tpu.tracing import record_event
 
 SLIDING, FULL = "sliding", "full"
@@ -138,6 +139,7 @@ class MellumMoE(MoETower):
         record_event("mellum_moe.paths", attention="pallas_interval", experts="ragged_dot",
                      seq_len=t, window=self.sliding_window, tile=tile, head_chunk=self.head_chunk,
                      held=self.n_held, pick_chunk=self.pick_chunk(b * t),
+                     qk_prep="pallas_rows", qk_prep_tile=qk_prep_tile(t),
                      **{f"grid_{k}": interval_visits(t // tile, tile, w) for k, w in window.items()})
 
         def attend(kind, q, k, v):

@@ -18,7 +18,9 @@ shares give sum to the whole layer (``tests/test_sdar_moe.py``,
 Arithmetic: every matrix product takes bfloat16 operands and accumulates in
 float32, forward and backward (``_mm``, ``ops.grouped_matmul``, the attention
 kernels); parameters, residual stream, norms, RoPE, softmax, router
-probabilities and loss are float32.
+probabilities and loss are float32. q and k go from their projections to the
+attention kernels through one pass in the projections' own layout
+(``ops.qk_prep.qk_norm_rope``: per-head norm, RoPE, the cast to bfloat16).
 
 Not a flax module: the layers are one ``lax.scan`` over stacked parameters
 with each layer recomputed in the backward, which flax's lifted transforms
@@ -36,6 +38,7 @@ import jax.numpy as jnp
 
 from persia_tpu.ops.flash_attention import ATTENTION_LSE, ATTENTION_OUT
 from persia_tpu.ops.grouped_matmul import grouped_matmul, grouped_outer
+from persia_tpu.ops.qk_prep import qk_norm_rope
 
 
 @jax.custom_vjp
@@ -69,13 +72,6 @@ _mm.defvjp(_mm_fwd, _mm_bwd)
 
 def _rms(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
-
-
-def _rope(x, cos, sin):
-    """Rotate-half RoPE: x (B, T, H, D), cos and sin (T, D) or (B, T, D)."""
-    half = x.shape[-1] // 2
-    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-    return x * cos[..., None, :] + rotated * sin[..., None, :]
 
 
 def _chunk(m, flat_w, order, starts, lo, size, k):
@@ -166,7 +162,8 @@ class MoETower:
     """The tower's parameters, layers and scan, for a frozen dataclass that
     holds its sizes (``vocab``, ``n_layers``, ``hidden``, ``n_heads``,
     ``n_kv_heads``, ``head_dim``, ``n_experts``, ``experts_per_token``,
-    ``expert_width``, ``first_held``, ``n_held``, ``rms_eps``) and states
+    ``expert_width``, ``first_held``, ``n_held``, ``rms_eps``, ``interpret``
+    for the Pallas kernels) and states
     ``layer_kinds``, the kinds of layer of one period in their order: the
     layers are the period repeated. A kind has a RoPE table and an attention
     of its own; everything else of a layer is the same layer."""
@@ -248,12 +245,16 @@ class MoETower:
         cos, sin = rope
         with jax.named_scope(scope):
             a = _rms(h, p["norm1"], self.rms_eps)
-            q = _mm(a, p["wq"]).reshape(b, t, self.n_heads, hd)
-            k = _mm(a, p["wk"]).reshape(b, t, self.n_kv_heads, hd)
-            v = _mm(a, p["wv"]).reshape(b, t, self.n_kv_heads, hd)
-            q = _rope(_rms(q, p["q_norm"], self.rms_eps), cos, sin)
-            k = _rope(_rms(k, p["k_norm"], self.rms_eps), cos, sin)
-            o = attend(q.astype(jnp.bfloat16), k.astype(jnp.bfloat16), v.astype(jnp.bfloat16))
+            # norm, RoPE and the cast as one pass over the projections as they
+            # come, a head a column block; the kernels read them so, and the
+            # reshapes at their boundary move nothing
+            q = qk_norm_rope(_mm(a, p["wq"]), p["q_norm"], cos, sin, self.n_heads, self.rms_eps,
+                             interpret=self.interpret)
+            k = qk_norm_rope(_mm(a, p["wk"]), p["k_norm"], cos, sin, self.n_kv_heads, self.rms_eps,
+                             interpret=self.interpret)
+            v = _mm(a, p["wv"]).astype(jnp.bfloat16)
+            o = attend(q.reshape(b, t, self.n_heads, hd), k.reshape(b, t, self.n_kv_heads, hd),
+                       v.reshape(b, t, self.n_kv_heads, hd))
             # bfloat16 as the kernel leaves it: what the product takes, and the
             # gradient comes back in what the kernel's backward takes
             h = h + _mm(o.reshape(b, t, -1), p["wo"])

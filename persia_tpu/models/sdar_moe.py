@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from persia_tpu.models.moe_tower import MoETower
 from persia_tpu.ops.flash_attention import block_diffusion_attention, block_diffusion_plan
+from persia_tpu.ops.qk_prep import qk_prep_tile
 from persia_tpu.tracing import record_event
 
 
@@ -63,6 +64,7 @@ class SDARMoE(MoETower):
         record_event("sdar_moe.paths", attention="pallas_block_mask",
                      experts="ragged_dot", seq_len=seq_len, block_len=self.block_len,
                      held=self.n_held, pick_chunk=self.pick_chunk(b * t),
+                     qk_prep="pallas_rows", qk_prep_tile=qk_prep_tile(t),
                      **block_diffusion_plan(seq_len, self.block_len))
 
         def attend(kind, q, k, v):

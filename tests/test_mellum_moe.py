@@ -163,6 +163,8 @@ def test_tower_against_the_reference(one_step, what):
         assert said["attention"] == "pallas_interval" and said["experts"] == "ragged_dot"
         assert (said["window"], said["tile"], said["head_chunk"], said["seq_len"]) == ("8", "16", "32", "64")
         assert (said["grid_sliding"], said["grid_full"]) == (str(1 + 2 * 3), str(10))
+        # q/k norm, RoPE and the cast as one pass, a sequence's positions one block
+        assert (said["qk_prep"], said["qk_prep_tile"]) == ("pallas_rows", str(LENGTH))
 
 
 def test_the_shares_add_up():

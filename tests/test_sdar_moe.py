@@ -153,6 +153,8 @@ def test_the_paths_event_carries_the_attention_plan(one_step):
     execute a head at this length (one tile of 32 a half: three cut visits)."""
     said = one_step["paths"][-1]
     assert said["attention"] == "pallas_block_mask" and said["experts"] == "ragged_dot"
+    # q/k norm, RoPE and the cast as one pass, the 2L positions of a sequence one block
+    assert (said["qk_prep"], said["qk_prep_tile"]) == ("pallas_rows", str(2 * LENGTH))
     plan = block_diffusion_plan(LENGTH, TINY["block_length"])
     assert {k: said[k] for k in plan} == {k: str(v) for k, v in plan.items()}
     assert (plan["visits"], plan["visits_whole"], plan["sub_tile"]) == (3, 0, 8)

@@ -8,6 +8,7 @@ started, never at import, and every such test lives here.
 """
 
 import functools
+import math
 import os
 import re
 
@@ -224,3 +225,61 @@ def test_rows_wider_than_one_tile_column_keep_the_scatter(one_chip, no_compile_c
     events = [e["attrs"] for e in tracing.flight_snapshot() if e["kind"] == "sparse_update.row_write"]
     assert [(e["array"], e["path"]) for e in events] == [("table", "scatter"), ("acc", "scatter")]
     assert compiled.memory_analysis().alias_size_in_bytes >= 2 * vocab * dim * 4
+
+
+# ---- the sequence towers' q/k pass (ISSUE 36) ----
+
+def _writes_under(text, scope):
+    """(bytes written, name, opcode) of every instruction outside a fused
+    computation whose ``op_name`` holds ``scope``: what the program writes to
+    HBM there, a fusion counted once by its result."""
+    sizes = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "pred": 1}
+    free = {"bitcast", "get-tuple-element", "parameter", "tuple", "constant", "while", "call"}
+    out, computation = [], ""
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            computation = head.group(1)
+            continue
+        at = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if not at or "fused" in computation or at.group(3) in free:
+            continue
+        name, shape, opcode = at.groups()
+        if scope not in (re.search(r'op_name="([^"]*)"', line) or [""])[0]:
+            continue
+        n = sum(sizes[dtype] * math.prod(int(x) for x in dims.split(",") if x)
+                for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]", shape) if dtype in sizes)
+        out.append((n, name, opcode))
+    return out
+
+
+@pytest.mark.time_limit(600)
+def test_an_sdar_layer_keeps_q_and_k_in_the_projections_layout(one_chip, no_compile_cache, highest_by_default):
+    """One layer of the SDAR tower, forward and backward, at the cell's widths
+    (rows (2, 8192, 2048), 32 / 4 heads of 128, 16 held experts): the q/k pass
+    compiles as two Mosaic kernels, and under the ``attention`` scope nothing
+    copies, broadcasts, pads or slices an array of 100 MB or more. With q and
+    k reshaped to (B, T, H, D) before their norm and RoPE the recomputed
+    forward alone held 3 such copies (every reshape between the (T, 4096) and
+    (32, 128) tilings moves the array), the tables broadcast over the heads
+    (3), rotate-half's pad and its slices: 37 instructions of 100 MB or more
+    and 7.5 GB written a layer, 17 and 3.0 GB now."""
+    from persia_tpu.models.sdar_moe import SDARMoE
+
+    tower = SDARMoE(vocab=128, n_layers=1, block_len=4, n_held=16)
+    shaped = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    params = jax.tree.map(shaped, tower.param_shapes(), is_leaf=lambda s: isinstance(s, tuple))
+
+    def loss(params, rows):
+        return jnp.sum(tower.apply({"params": params}, [], [(rows, None)]))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, shaped((2, 8192, 2048))).compile().as_text()
+    assert {"qk_norm_rope_fwd", "qk_norm_rope_bwd"} <= set(re.findall(r"qk_norm_rope_[a-z]+", text))
+    large = [w for w in _writes_under(text, "/attention") if w[0] >= 100e6]
+    moved = [w for w in large if re.search(r"copy|broadcast|pad|slice", w[1] + " " + w[2])]
+    assert not moved, moved
+    # the forward q pass once and once recomputed, the backward's once; the k
+    # passes are an eighth of the size
+    assert sorted(w[1].split(".")[0] for w in large if "qk_norm_rope" in w[1]) == [
+        "qk_norm_rope_bwd", "qk_norm_rope_fwd", "qk_norm_rope_fwd"]
+    assert len(large) <= 20 and sum(w[0] for w in large) < 3.5e9, (len(large), large)
