@@ -404,8 +404,12 @@ def run_train_stream(
         native hazard-ledger probe) + PS probe."""
         seq = 0
         try:
-            for batch in batches:
-                if stop.is_set() or errors:
+            source = iter(batches)
+            while True:
+                # the caller's iterator: the thread's time outside stream.prep
+                with wait_span("stream.source_wait", seq=seq):
+                    batch = next(source, SENTINEL)
+                if batch is SENTINEL or stop.is_set() or errors:
                     break
                 if (
                     (job_mgr is not None or fence_callback is not None)

@@ -33,7 +33,7 @@ from persia_tpu.parallel.fused_step import (
     init_fused_state,
 )
 from persia_tpu.parallel.train_step import _note_nonfinite_loss
-from persia_tpu.tracing import stage_span
+from persia_tpu.tracing import stage_span, wait_span
 
 logger = get_default_logger("persia_tpu.fused_ctx")
 
@@ -178,8 +178,9 @@ class FusedTrainCtx:
         self._last = (loss, preds)
         if not fetch_metrics:
             return {}
-        return {"loss": _note_nonfinite_loss(float(loss)),
-                "preds": np.asarray(preds)}
+        with wait_span("fused.fetch", seq=seq):  # the d2h waits for the step
+            return {"loss": _note_nonfinite_loss(float(loss)),
+                    "preds": np.asarray(preds)}
 
     def train_pipelined(
         self,

@@ -46,6 +46,20 @@ it for tracing. The two clocks differ by construction (the profiler counts
 from its session's start, the ring stamps ``time.time()``); a span seen in
 both, by name and ``seq``, is the anchor that places ring-only spans
 (:func:`record_span`) on the device trace.
+
+A live profiler session is also a window. A stage or wait span asks the
+annotation class ``is_enabled()`` as it opens; one that opened inside a
+session asks again as it closes, and if the session is still live adds its
+busy or wait time to one process-wide :class:`StageAccumulator`, by the rule
+of a thread's bound one (a work span's busy time leaves out the waits nested
+in it). :func:`session_totals` gives the last session's tables and its
+``wall_s`` (the first such span's start to the last counted span's end):
+the program's stage and wait totals for exactly the seconds the device
+trace holds host events for, to whoever opens a session on a live trainer
+(``jax.profiler.start_trace``, the profiler server), without parsing the
+trace. With no session a stage or wait span pays that one ``is_enabled()``
+call (0.02 us here, under 0.1 us on the benchmark's host) and nothing else:
+no object is kept, no lock taken, no callback registered.
 """
 
 from __future__ import annotations
@@ -233,15 +247,63 @@ def accumulate(acc: Optional[StageAccumulator]):
         _tls.acc = prev
 
 
+# The profiler session as a window: the totals of the stage and wait spans
+# that opened and closed inside the last ``jax.profiler`` session this
+# process's spans saw, with the session's extent as they saw it (the first
+# such span's start, the last counted span's end, on ``time.perf_counter``).
+_session: Optional[StageAccumulator] = None
+_session_live = False  # what the last stage or wait span to ask was told
+_session_t0 = _session_t1 = 0.0
+
+
+def _session_seen(live: bool) -> Optional[StageAccumulator]:
+    """A stage or wait span (or :func:`session_totals`) found a profiler
+    session live, or found none where the last one to ask found one: a
+    session that begins gets a fresh accumulator, one that ends keeps its
+    totals for the reader. Returns the live session's accumulator."""
+    global _session, _session_live, _session_t0, _session_t1
+    with _lock:
+        if live and not _session_live:
+            _session = StageAccumulator()
+            _session_t0 = _session_t1 = time.perf_counter()
+        _session_live = live
+        return _session if live else None
+
+
+def session_totals() -> Optional[Dict[str, Any]]:
+    """``{"wall_s", "stages": {name: {n, busy_s, max_s}}, "waits": {name:
+    {n, wait_s, max_s}}}`` of the last profiler session (live or ended)
+    that a stage or wait span of this process saw, or ``None`` where none
+    was: ``stream_stats()``'s two tables over every thread, for exactly
+    the seconds the device trace holds host events for. A span that
+    straddles either end of the session counts for nothing. Two sessions
+    are told apart by a stage or wait span, or a call of this function,
+    that found the profiler off between them."""
+    if _session_live:
+        ann = _trace_annotation()
+        if ann is None or not ann.is_enabled():
+            _session_seen(False)
+    with _lock:
+        acc, wall_s = _session, _session_t1 - _session_t0
+    if acc is None:
+        return None
+    with acc._lock:
+        return {"wall_s": wall_s,
+                "stages": {k: dict(v) for k, v in acc.stages.items()},
+                "waits": {k: dict(v) for k, v in acc.waits.items()}}
+
+
 _PLAIN, _STAGE, _WAIT = 0, 1, 2
 
 
 class _Span:
     """The one span primitive. Every kind opens a profiler annotation and,
     while the tracer is enabled, records itself in the ring; the stage
-    kinds also feed the stage histogram and the thread's accumulator."""
+    kinds also feed the stage histogram, the thread's accumulator and,
+    while a profiler session is live, the session's."""
 
-    __slots__ = ("name", "attrs", "kind", "_ann", "_ring", "_acc", "_clock", "_t0", "_w0")
+    __slots__ = ("name", "attrs", "kind", "_ann", "_ring", "_acc", "_clock", "_t0", "_w0",
+                 "_sess")
 
     def __init__(self, name: str, attrs: Dict[str, Any], kind: int):
         self.name, self.attrs, self.kind = name, attrs, kind
@@ -266,6 +328,11 @@ class _Span:
             acc = self._acc = getattr(_tls, "acc", None)
             self._clock = time.perf_counter if acc is None else acc.clock
             self._w0 = getattr(_tls, "wait_s", 0.0)
+            self._sess = None
+            if ann is not None:
+                live = ann.is_enabled()
+                if live or _session_live:
+                    self._sess = _session_seen(live)
             self._t0 = self._clock()
         elif self._ring is not None:
             self._clock = time.perf_counter
@@ -276,13 +343,17 @@ class _Span:
         if self.kind or self._ring is not None:
             dur = self._clock() - self._t0
             if self.kind:
-                acc = self._acc
-                if self.kind == _WAIT:
+                acc, sess = self._acc, self._sess
+                wait = self.kind == _WAIT
+                if wait:
                     _tls.wait_s = self._w0 + dur
+                if acc is not None or sess is not None:
+                    # a work span's busy time leaves out the waits nested in it
+                    secs = dur if wait else dur - (getattr(_tls, "wait_s", 0.0) - self._w0)
                     if acc is not None:
-                        acc.add(self.name, dur, wait=True)
-                elif acc is not None:
-                    acc.add(self.name, dur - (getattr(_tls, "wait_s", 0.0) - self._w0))
+                        acc.add(self.name, secs, wait)
+                    if sess is not None:
+                        self._to_session(secs, dur)
             if self._ring is not None:
                 self._record(dur)
             h = _get_histogram()
@@ -298,6 +369,18 @@ class _Span:
         self.attrs.update(attrs)
         if self._ann is not None:
             self._ann.set_metadata(**attrs)
+
+    def _to_session(self, secs: float, dur: float) -> None:
+        """The span opened inside a profiler session: it counts for that
+        session if it closes inside it too (and was timed by the
+        session's clock, as every span is but a test's)."""
+        global _session_t1
+        if not self._ann.is_enabled():
+            _session_seen(False)
+        elif self._sess is _session and self._clock is time.perf_counter:
+            self._sess.add(self.name, secs, self.kind == _WAIT)
+            with _lock:
+                _session_t1 = max(_session_t1, self._t0 + dur)
 
     def _record(self, dur: float) -> None:
         trace_id, span_id, parent, ts_us = self._ring

@@ -1,8 +1,8 @@
 """The one span primitive and what rests on it: a span lands in a
 ``jax.profiler`` trace under its name, costs next to nothing with no session,
 feeds one per-stream accounting of busy and wait time that leaves no hole in
-the dispatcher's thread, and the device steps carry the named scopes a device
-trace attributes time by."""
+the dispatcher's thread or the feeder's, and the device steps carry the named
+scopes a device trace attributes time by."""
 
 import glob
 import os
@@ -144,7 +144,7 @@ def test_sharded_walk_has_one_live_span_around_the_native_call():
 
 # ------------------------------- (c), (d) the stream's one time accounting
 
-def _stream_stats(k, slow_s, uniform=False):
+def _stream_stats(k, slow_s, uniform=False, source_s=0.0):
     from test_hbm_cache import UNIFORM_STREAM, _block_batches, _one_slot_ctx
 
     cfg, batches = _block_batches(36, **(UNIFORM_STREAM if uniform else {}))
@@ -159,7 +159,9 @@ def _stream_stats(k, slow_s, uniform=False):
 
     def late_start():  # the dispatcher finds nothing staged at first
         time.sleep(0.05)
-        yield from batches
+        for b in batches:
+            time.sleep(source_s)  # a source the feeder has to wait for
+            yield b
 
     with ctx:
         ctx.train_stream(late_start(), dispatch_k=k, wb_flush_steps=2, prefetch=2)
@@ -219,6 +221,21 @@ def test_dispatcher_thread_has_no_hole():
     assert 0.85 * st["wall_s"] <= busy + wait <= st["wall_s"], (busy, wait, st["wall_s"])
     # the closing drain is a wait of its own, after wall_s is taken
     assert st["waits"]["stream.drain"]["n"] >= 1
+
+
+def test_feeder_thread_has_no_hole():
+    """Pulling the caller's iterator, busy in ``stream.prep`` and blocked on
+    the full queue downstream or on a ring is the feeder's whole stream."""
+    st = _stream_stats(1, slow_s=0.0, source_s=0.03)
+    waits = st["waits"]
+    assert waits["stream.source_wait"]["n"] == 36 + 1  # every batch, and the pull that found the end
+    assert waits["stream.source_wait"]["wait_s"] >= 0.05 + 36 * 0.03
+    covered = st["stages"]["stream.prep"]["busy_s"] + sum(
+        waits.get(n, {"wait_s": 0.0})["wait_s"]
+        for n in ("stream.source_wait", "stream.prep_put_wait", "stream.ring_wait"))
+    assert 0.85 * st["wall_s"] <= covered <= st["wall_s"], (covered, st["wall_s"], waits)
+    # feeder_busy_s keeps its meaning: stream.prep alone
+    assert st["feeder_busy_s"] == st["stages"]["stream.prep"]["busy_s"]
 
 
 # ------------------------------------------- (e) named scopes in the steps

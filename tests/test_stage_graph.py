@@ -184,6 +184,7 @@ def test_stream_carries_nothing_of_the_removed_regime(monkeypatch):
     assert set(st["waits"]) <= {
         "stream.ring_wait", "stream.prep_put_wait", "stream.stage_get_wait",
         "stream.stage_put_wait", "stream.dispatch_get_wait", "stream.drain",
+        "stream.source_wait",
     }, set(st["waits"])
     assert set(st["stage_wall_s"]) == {"feed", "dense", "psgrad"}
 
