@@ -136,9 +136,8 @@ class MellumMoE(MoETower):
                                for f in (jnp.cos, jnp.sin))
         window = {SLIDING: self.sliding_window, FULL: None}
         tile = min(self.tile, t)
-        record_event("mellum_moe.paths", attention="pallas_interval", experts="ragged_dot",
+        record_event("mellum_moe.paths", attention="pallas_interval", **self.expert_paths(b * t),
                      seq_len=t, window=self.sliding_window, tile=tile, head_chunk=self.head_chunk,
-                     held=self.n_held, pick_chunk=self.pick_chunk(b * t),
                      qk_prep="pallas_rows", qk_prep_tile=qk_prep_tile(t),
                      **{f"grid_{k}": interval_visits(t // tile, tile, w) for k, w in window.items()})
 

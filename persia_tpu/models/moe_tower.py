@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from persia_tpu.ops.flash_attention import ATTENTION_LSE, ATTENTION_OUT
-from persia_tpu.ops.grouped_matmul import grouped_matmul, grouped_outer
+from persia_tpu.ops.grouped_matmul import grouped_matmul, grouped_outer, grouped_tiles
 from persia_tpu.ops.qk_prep import qk_norm_rope
 
 
@@ -85,33 +85,41 @@ def _chunk(m, flat_w, order, starts, lo, size, k):
     return sizes, at, live, token, jnp.where(live, flat_w[at], 0.0), m[token].astype(jnp.bfloat16)
 
 
-def _swiglu(rows, gate, up, sizes):
+def _swiglu(rows, gate, up, sizes, interpret):
     with jax.named_scope("experts"):
-        g, u = grouped_matmul(rows, gate, sizes), grouped_matmul(rows, up, sizes)
+        g = grouped_matmul(rows, gate, sizes, interpret=interpret)
+        u = grouped_matmul(rows, up, sizes, interpret=interpret)
     return g, u, (jax.nn.silu(g) * u).astype(jnp.bfloat16)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _held_experts(m, flat_w, gate, up, down, order, starts, size, k):
+@partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _held_experts(m, flat_w, gate, up, down, order, starts, size, k, interpret):
     """sum over the picks on held experts of weight x down_e(silu(gate_e x) *
     up_e x), by token: m (N, d) -> (N, d). ``order`` holds the picks sorted by
     held expert (pick i is token ``i // k``), ``starts`` (held + 1,) where each
     expert's begin, ``flat_w`` (N * k,) the routing weights. The loop's trip
-    count is the live chunks', so autodiff cannot reverse it: the backward
-    below is written out, with the same products (bfloat16 operands, float32
-    sums) in the same chunks, recomputing a chunk's forward."""
-    return _held_experts_fwd(m, flat_w, gate, up, down, order, starts, size, k)[0]
+    count is the live chunks', so autodiff cannot reverse it, and the grouped
+    products are Pallas kernels, which have no derivative of their own: the
+    backward below is written out, with the same products (bfloat16 operands,
+    float32 sums) in the same chunks, recomputing a chunk's forward. (While
+    the products were the compiler's ``lax.ragged_dot``, autodiff also gave
+    their gradients rounded to bfloat16 and wanted the weights transposed as
+    arrays of their own; ``ops.grouped_matmul`` contracts the weights over
+    either axis and returns float32.) Every product gives 0 for a row of no
+    group, which ``dw`` in the backward leans on: 0 x an unwritten row could
+    be NaN."""
+    return _held_experts_fwd(m, flat_w, gate, up, down, order, starts, size, k, interpret)[0]
 
 
-def _held_experts_fwd(m, flat_w, gate, up, down, order, starts, size, k):
+def _held_experts_fwd(m, flat_w, gate, up, down, order, starts, size, k, interpret):
     gate_b, up_b, down_b = (x.astype(jnp.bfloat16) for x in (gate, up, down))
 
     def body(c, y):
         with jax.named_scope("dispatch"):
             sizes, _at, live, token, w, rows = _chunk(m, flat_w, order, starts, c * size, size, k)
-        _g, _u, mid = _swiglu(rows, gate_b, up_b, sizes)
+        _g, _u, mid = _swiglu(rows, gate_b, up_b, sizes, interpret)
         with jax.named_scope("experts"):
-            out = grouped_matmul(mid, down_b, sizes)
+            out = grouped_matmul(mid, down_b, sizes, interpret=interpret)
         with jax.named_scope("combine"):
             return y.at[token].add(jnp.where(live[:, None], out, 0.0) * w[:, None])
 
@@ -119,30 +127,32 @@ def _held_experts_fwd(m, flat_w, gate, up, down, order, starts, size, k):
     return y, (m, flat_w, gate_b, up_b, down_b, order, starts)
 
 
-def _held_experts_bwd(size, k, res, dy):
+def _held_experts_bwd(size, k, interpret, res, dy):
     m, flat_w, gate_b, up_b, down_b, order, starts = res
-    # the transposed weights as arrays of their own: the compiler's grouped
-    # kernel takes (G, K, N) contracted over K, nothing else
-    gate_t, up_t, down_t = (jnp.swapaxes(x, 1, 2) for x in (gate_b, up_b, down_b))
+    # the products with the transposed weights contract over the weights' last
+    # axis in the kernel: no transposed copy of a leaf is made
+    product = partial(grouped_matmul, interpret=interpret)
+    by_transposed = partial(grouped_matmul, transposed=True, interpret=interpret)
+    outer = partial(grouped_outer, interpret=interpret)
 
     def body(c, carry):
         dm, dw, d_gate, d_up, d_down = carry
         with jax.named_scope("dispatch"):
             sizes, at, live, token, w, rows = _chunk(m, flat_w, order, starts, c * size, size, k)
-        g, u, mid = _swiglu(rows, gate_b, up_b, sizes)
+        g, u, mid = _swiglu(rows, gate_b, up_b, sizes, interpret)
         with jax.named_scope("combine"):
             dyt = jnp.where(live[:, None], dy[token], 0.0)
         with jax.named_scope("experts"):
-            out = grouped_matmul(mid, down_b, sizes)
+            out = product(mid, down_b, sizes)
             d_out = (dyt * w[:, None]).astype(jnp.bfloat16)
-            d_mid = grouped_matmul(d_out, down_t, sizes)
+            d_mid = by_transposed(d_out, down_b, sizes)
             sig = jax.nn.sigmoid(g)
             dg = (d_mid * u * (sig * (1.0 + g * (1.0 - sig)))).astype(jnp.bfloat16)
             du = (d_mid * (g * sig)).astype(jnp.bfloat16)
-            d_rows = grouped_matmul(dg, gate_t, sizes) + grouped_matmul(du, up_t, sizes)
-            d_gate = d_gate + grouped_outer(rows, dg, sizes)
-            d_up = d_up + grouped_outer(rows, du, sizes)
-            d_down = d_down + grouped_outer(mid, d_out, sizes)
+            d_rows = by_transposed(dg, gate_b, sizes) + by_transposed(du, up_b, sizes)
+            d_gate = d_gate + outer(rows, dg, sizes)
+            d_up = d_up + outer(rows, du, sizes)
+            d_down = d_down + outer(mid, d_out, sizes)
         with jax.named_scope("dispatch"):
             dm = dm.at[token].add(jnp.where(live[:, None], d_rows, 0.0))
             dw = dw.at[at].add(jnp.sum(dyt * out, axis=-1))
@@ -274,6 +284,16 @@ class MoETower:
         even = -(-picks * self.n_held // self.n_experts)
         return min(picks, -(-(even + even // 8) // 512) * 512)
 
+    def expert_paths(self, n_tokens: int) -> Dict[str, Any]:
+        """What a tower's ``*.paths`` event says of the expert layer for
+        ``n_tokens`` tokens: the kernels that run the grouped products and the
+        (row, K, N) tiles the chunk's shapes gave them, gate and up's first
+        and down's after it."""
+        chunk, d, f = self.pick_chunk(n_tokens), self.hidden, self.expert_width
+        tiles = [grouped_tiles(chunk, k, n) for k, n in ((d, f), (f, d))]
+        return {"experts": "pallas_grouped", "experts_tile": "/".join("x".join(map(str, t)) for t in tiles),
+                "held": self.n_held, "pick_chunk": chunk}
+
     def experts(self, p, m):
         """This chip's experts' part of the layer's result for tokens
         ``m`` (N, hidden), and the picks each held expert got, (n_held,).
@@ -294,7 +314,8 @@ class MoETower:
             starts = jnp.searchsorted(sorted_e, jnp.arange(held + 1, dtype=jnp.int32)).astype(jnp.int32)
         chunk = self.pick_chunk(n)
         order = jnp.pad(order, (0, -(n * k) % chunk))  # a last chunk may run past the picks
-        y = _held_experts(m, weight.reshape(-1), p["gate"], p["up"], p["down"], order, starts, chunk, k)
+        y = _held_experts(m, weight.reshape(-1), p["gate"], p["up"], p["down"], order, starts, chunk, k,
+                          self.interpret)
         return y, starts[1:] - starts[:-1]
 
     def head(self, params, h):

@@ -62,8 +62,7 @@ class SDARMoE(MoETower):
         # with what the attention kernels execute a head and sequence: visits by
         # the tile's kind, sub-tiles executed of the visited, pairs beside the live
         record_event("sdar_moe.paths", attention="pallas_block_mask",
-                     experts="ragged_dot", seq_len=seq_len, block_len=self.block_len,
-                     held=self.n_held, pick_chunk=self.pick_chunk(b * t),
+                     seq_len=seq_len, block_len=self.block_len, **self.expert_paths(b * t),
                      qk_prep="pallas_rows", qk_prep_tile=qk_prep_tile(t),
                      **block_diffusion_plan(seq_len, self.block_len))
 

@@ -160,7 +160,10 @@ def test_tower_against_the_reference(one_step, what):
         assert (tiles[:, 0] == tiles[:, 1]).all() and tiles[0, 0] // 3 < tiles[1, 0]
     else:  # what the entry prints to stderr
         said = s["paths"][-1]
-        assert said["attention"] == "pallas_interval" and said["experts"] == "ragged_dot"
+        assert said["attention"] == "pallas_interval" and said["experts"] == "pallas_grouped"
+        # the grouped products' (row, K, N) tiles, gate and up's then down's, for this length's chunk
+        chunk = int(said["pick_chunk"])
+        assert said["experts_tile"] == "{0}x128x64/{0}x64x128".format(min(512, -(-chunk // 16) * 16))
         assert (said["window"], said["tile"], said["head_chunk"], said["seq_len"]) == ("8", "16", "32", "64")
         assert (said["grid_sliding"], said["grid_full"]) == (str(1 + 2 * 3), str(10))
         # q/k norm, RoPE and the cast as one pass, a sequence's positions one block

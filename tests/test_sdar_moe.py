@@ -152,7 +152,9 @@ def test_the_paths_event_carries_the_attention_plan(one_step):
     """What the entry prints to stderr: the path names, and what the kernels
     execute a head at this length (one tile of 32 a half: three cut visits)."""
     said = one_step["paths"][-1]
-    assert said["attention"] == "pallas_block_mask" and said["experts"] == "ragged_dot"
+    assert said["attention"] == "pallas_block_mask" and said["experts"] == "pallas_grouped"
+    # the grouped products' (row, K, N) tiles, gate and up's then down's: 512 picks a chunk here
+    assert said["pick_chunk"] == "512" and said["experts_tile"] == "512x128x64/512x64x128"
     # q/k norm, RoPE and the cast as one pass, the 2L positions of a sequence one block
     assert (said["qk_prep"], said["qk_prep_tile"]) == ("pallas_rows", str(2 * LENGTH))
     plan = block_diffusion_plan(LENGTH, TINY["block_length"])
@@ -365,15 +367,15 @@ def test_grouped_products_against_a_loop(what):
         want = np.zeros((m, n))
         for i in range(4):
             want[starts[i]:starts[i + 1]] = f32(x)[starts[i]:starts[i + 1]] @ f32(w)[i]
-        got = grouped_matmul(x, w, gs)
+        got = grouped_matmul(x, w, gs, interpret=True)
     elif what == "transposed_weights":
         want = np.zeros((m, k))
         for i in range(4):
             want[starts[i]:starts[i + 1]] = f32(g)[starts[i]:starts[i + 1]] @ f32(w)[i].T
-        got = grouped_matmul(g, jnp.swapaxes(w, 1, 2), gs)
+        got = grouped_matmul(g, jnp.swapaxes(w, 1, 2), gs, interpret=True)
     else:
         want = np.stack([f32(x)[starts[i]:starts[i + 1]].T @ f32(g)[starts[i]:starts[i + 1]] for i in range(4)])
-        got = grouped_outer(x, g, gs)
+        got = grouped_outer(x, g, gs, interpret=True)
     assert got.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-4, rtol=1e-5)
 

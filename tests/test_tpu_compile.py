@@ -283,3 +283,40 @@ def test_an_sdar_layer_keeps_q_and_k_in_the_projections_layout(one_chip, no_comp
     assert sorted(w[1].split(".")[0] for w in large if "qk_norm_rope" in w[1]) == [
         "qk_norm_rope_bwd", "qk_norm_rope_fwd", "qk_norm_rope_fwd"]
     assert len(large) <= 20 and sum(w[0] for w in large) < 3.5e9, (len(large), large)
+
+
+# ---- the held experts' grouped products (ISSUE 39) ----
+
+# cell: (rows of a chunk, hidden, an expert's width)
+EXPERT_SHAPES = {"mellum2-ep4-pack16k": (36_864, 2304, 896), "sdar-ep8-bd4-seq4k": (18_432, 2048, 768)}
+
+
+@pytest.mark.parametrize("form", ["product", "transposed_weights", "outer"])
+@pytest.mark.parametrize("leaf", ["gate_or_up", "down"])
+@pytest.mark.parametrize("cell", sorted(EXPERT_SHAPES))
+def test_the_grouped_products_compile_at_the_cells_widths(
+        one_chip, no_compile_cache, highest_by_default, cell, leaf, form):
+    """16 held experts, a chunk's rows in tiles of 512 with the whole K and N
+    in a block, under the harness's ``highest``: one Mosaic kernel each, which
+    needs more VMEM than the scoped default (the call asks for it), no
+    transposed copy of the weights and nothing else of the rows' size."""
+    from persia_tpu.ops import grouped_matmul as gm
+
+    m, d, f = EXPERT_SHAPES[cell]
+    k, n = (d, f) if leaf == "gate_or_up" else (f, d)
+    assert gm.grouped_tiles(m, k, n) == (512, k, n)
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    sizes = shaped((16,), jnp.int32)
+    if form == "product":
+        fn, args, name = gm.grouped_matmul, (shaped((m, k)), shaped((16, k, n)), sizes), "grouped_matmul"
+    elif form == "transposed_weights":
+        fn = functools.partial(gm.grouped_matmul, transposed=True)
+        args, name = (shaped((m, k)), shaped((16, n, k)), sizes), "grouped_matmul_t"
+    else:
+        fn, args, name = gm.grouped_outer, (shaped((m, k)), shaped((m, n)), sizes), "grouped_outer"
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 1 and name in text
+    assert not re.search(r"= bf16\[[\d,]+\]\S* (transpose|copy)\(", text) and "ragged" not in text
+    # beside the result: the walk's four small arrays
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
