@@ -574,6 +574,58 @@ def phase_kernel(
     return {"cases": cases, "interpret": interpret, "max_abs_err": worst}
 
 
+def phase_sequence_kernels(length: int = 2048, heads: int = 4, chunk: int = 64, tile: int = 512,
+                           interpret: bool = False) -> Dict:
+    """The chunked delta rule (``ops.delta_rule.kda``, its four kernels) against the
+    plain recurrence, and interval attention at widths 192/128 (``k_shared``)
+    against dense softmax, over packed documents whose starts fall inside
+    chunks and tiles; forward and every gradient, as relative gaps."""
+    import jax
+    import jax.numpy as jnp
+
+    from persia_tpu.ops.delta_rule import kda, kda_recurrence
+    from persia_tpu.ops.flash_attention import interval_attention
+
+    rng = np.random.default_rng(0)
+    docs = [length // 2 + 3, length // 4 + 5, length // 8 - 7]
+    docs.append(length - sum(docs))
+    lo = jnp.asarray(np.repeat(np.cumsum([0] + docs[:-1]), docs)[None].astype(np.int32))
+    normal = lambda *shape: rng.standard_normal(shape).astype(np.float32)
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+    gap = lambda a, b: float(jnp.linalg.norm((a - b).astype(jnp.float32)) / jnp.linalg.norm(b))
+
+    def gaps(mine, theirs, args):
+        ct = jnp.asarray(normal(*theirs(*args).shape))
+        out = {"forward": gap(mine(*args), theirs(*args))}
+        grads = [jax.grad(lambda *a: jnp.sum(f(*a) * ct), argnums=tuple(range(len(args))))(*args)
+                 for f in (mine, theirs)]
+        out.update({f"d{i}": gap(a, b) for i, (a, b) in enumerate(zip(*grads))})
+        return out
+
+    shape = (1, length, heads, 128)
+    delta = gaps(lambda *a: kda(*a, lo, chunk=chunk, interpret=interpret), lambda *a: kda_recurrence(*a, lo), [
+        jnp.asarray(unit(normal(*shape)) / np.sqrt(128)), jnp.asarray(unit(normal(*shape))),
+        jnp.asarray(normal(*shape)), jnp.asarray(-np.exp(rng.uniform(np.log(1e-3), np.log(1.6), shape)), jnp.float32),
+        jnp.asarray(rng.uniform(0.1, 0.9, shape[:3]), jnp.float32)])
+    say(f"  delta rule L={length} H={heads} chunk={chunk}: " + " ".join(f"{k}={v:.1e}" for k, v in delta.items()))
+    assert all(np.isfinite(v) and v < 2e-2 for v in delta.values()), delta  # bfloat16 operands against float32
+
+    def dense(q, k, shared, v):
+        kk = jnp.concatenate([k, jnp.broadcast_to(shared[:, :, None, :], (*k.shape[:3], shared.shape[-1]))], -1)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk, precision="highest") / np.sqrt(q.shape[-1])
+        at = jnp.arange(length)
+        mask = (at[None, None, :] >= lo[:, :, None]) & (at[None, None, :] <= at[None, :, None])
+        p = jax.nn.softmax(jnp.where(mask[:, None], s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v, precision="highest")
+
+    latent = gaps(lambda q, k, s, v: interval_attention(q, k, v, lo, tile=tile, interpret=interpret, k_shared=s),
+                  dense, [jnp.asarray(normal(1, length, heads, 192)), jnp.asarray(normal(*shape)),
+                          jnp.asarray(normal(1, length, 64)), jnp.asarray(normal(*shape))])
+    say(f"  interval attention 192/128 L={length} H={heads}: " + " ".join(f"{k}={v:.1e}" for k, v in latent.items()))
+    assert all(np.isfinite(v) and v < 2e-2 for v in latent.values()), latent
+    return {"delta_rule": delta, "latent_attention": latent, "interpret": interpret}
+
+
 # -------------------------------------------------------------- multichip
 
 
@@ -685,6 +737,8 @@ def main() -> None:
     run("pinned (FusedTrainCtx)", phase_pinned, shape, steps=12)
     say("kernel (flash_attention vs reference_attention, compiled):")
     run("kernel", phase_kernel)
+    say("sequence kernels (delta rule vs recurrence, interval attention 192/128 vs dense, compiled):")
+    run("sequence kernels", phase_sequence_kernels)
 
     if device["count"] >= 4:
         run("multichip", phase_multichip, shape)

@@ -13,24 +13,23 @@ keys ``[lo_i, i]``, ``lo_i`` the document's start on a full layer and the later
 of that and ``i - sliding_window + 1`` on a sliding one
 (``ops/flash_attention.py::interval_attention``).
 
-Every position has a label, so head and loss never meet whole: ``train_loss``
-(which ``build_fused_train_step`` takes where a model states one) runs them
-in chunks of ``head_chunk`` positions, each chunk's logits recomputed in the
-backward, and no ``(T, vocab)`` array is ever live. ``apply`` gives the whole
-logits, for evaluation and for the tests that hold ``train_loss`` to them.
+Every position has a label, so head and loss never meet whole: the tower's
+``train_loss`` (``moe_tower.NextTokenTower``, which ``build_fused_train_step``
+takes where a model states one) runs them in chunks of ``head_chunk``
+positions. ``apply`` gives the whole logits, for evaluation and for the tests
+that hold ``train_loss`` to them.
 """
 
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from persia_tpu.models.moe_tower import MoETower
+from persia_tpu.models.moe_tower import NextTokenTower
 from persia_tpu.ops.flash_attention import (
     BLOCK_DIFFUSION_TILE, interval_attention, interval_tile_counts, interval_visits,
 )
@@ -69,7 +68,7 @@ YARN = {"factor": 16.0, "original_max_position_embeddings": 8192, "beta_fast": 3
 
 
 @dataclass(frozen=True)
-class MellumMoE(MoETower):
+class MellumMoE(NextTokenTower):
     vocab: int  # ids held here: the logits' width
     n_layers: int
     hidden: int = 2304
@@ -154,55 +153,3 @@ class MellumMoE(MoETower):
             stats = {"expert_picks": stats["expert_picks"] + picks,
                      "attention_tiles": stats["attention_tiles"] + per_kind}
         return h, stats
-
-    def apply(self, variables, dense, emb, train: bool = True, mutable: Optional[Sequence[str]] = None):
-        """Logits of every position, (B, T, vocab) float32. ``dense`` is
-        ``[starts (B, T) int32]``, ``emb`` one raw slot, ``(rows (B, T,
-        hidden), mask)``. With ``mutable=["batch_stats"]`` also the counters."""
-        del train
-        h, stats = self._hidden(variables, dense, emb)
-        with jax.named_scope("lm_head"):
-            logits = self.head(variables["params"], h)
-        return (logits, {"batch_stats": stats}) if mutable else logits
-
-    # ----------------------------------------------------- loss and outputs
-
-    def loss(self, logits, labels):
-        """Next-token cross-entropy: ``labels`` = [next token (B, T) int32,
-        weight (B, T) float32 (0 at a document's last position, else 1)];
-        the weighted mean."""
-        targets, weight = labels[0], labels[1]
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, targets[..., None].astype(jnp.int32), axis=-1)[..., 0]
-        return jnp.sum(weight * (logz - picked)) / jnp.sum(weight)
-
-    def outputs(self, logits):
-        """The most likely next id of each position, (B, T) int32."""
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    def train_loss(self, variables, dense, emb, labels):
-        """``(loss, outputs, counters)`` of a training step, the head and the
-        loss in chunks of ``head_chunk`` positions under one scan whose body is
-        recomputed in the backward: a chunk's logits and their gradient are
-        the largest arrays the head ever holds."""
-        h, stats = self._hidden(variables, dense, emb)
-        params = variables["params"]
-        b, t, d = h.shape
-        chunk = min(self.head_chunk, b * t)
-        if (b * t) % chunk:
-            raise ValueError(f"{b * t} positions are no whole chunks of {chunk}")
-        by_chunk = lambda x: x.reshape((b * t) // chunk, chunk, *x.shape[2:])
-        targets, weight = labels[0].astype(jnp.int32), labels[1].astype(jnp.float32)
-
-        @jax.checkpoint
-        def one(total, xs):
-            hc, tc, wc = xs
-            logits = self.head(params, hc)
-            picked = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
-            total = total + jnp.sum(wc * (jax.nn.logsumexp(logits, axis=-1) - picked))
-            return total, jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-        with jax.named_scope("lm_head"):
-            total, ids = jax.lax.scan(one, jnp.zeros((), jnp.float32),
-                                      (by_chunk(h), by_chunk(targets), by_chunk(weight)))
-        return total / jnp.sum(weight), ids.reshape(b, t), stats

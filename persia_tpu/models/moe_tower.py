@@ -1,19 +1,27 @@
-"""What the sequence towers share: a mixture-of-experts transformer tower over
-the sparse plane (RMSNorm, grouped-query attention with RoPE and per-head q/k
-norms, a routed expert layer with no shared expert, an untied head), as one
-``lax.scan`` over stacked layers. ``models/sdar_moe.py`` (block diffusion) and
-``models/mellum_moe.py`` (causal, window and full layers over packed
-documents) state what differs: the kinds of layer a period holds, the RoPE
-table a kind, which attention kernel runs, which positions have logits, the
-loss.
+"""What the sequence towers share: a mixture-of-experts tower over the sparse
+plane (RMSNorm, an attention a kind of layer, an expert layer that is told
+which experts it holds, an untied head), as one ``lax.scan`` over stacked
+layers. A tower states what its layers are: the kinds of layer a period
+holds and whether they hold different leaves, a leading layer before the
+scan, the MLP (routed experts; routed and a shared one; dense) and the
+router's law (softmax then the k largest renormalised; sigmoid with a
+selection bias and a scaling factor). ``models/sdar_moe.py`` (block
+diffusion) and ``models/mellum_moe.py`` (causal, window and full layers over
+packed documents) keep the tower's own attention (grouped-query softmax
+attention with RoPE and per-head q/k norms) and state a RoPE table a kind,
+which attention kernel runs, which positions have logits and the loss;
+``models/kimi_linear_moe.py`` brings two attentions of its own (the gated
+delta rule, latent attention), a leading dense layer, the shared expert and
+the sigmoid law.
 
 **The expert layer is told which experts it holds** (``first_held``,
 ``n_held``): it routes over all ``n_experts`` with the published
 ``experts_per_token``, and computes its own experts' part of the result for
 the tokens routed to them; what the absent experts would add is another
 chip's to add. On one chip it runs without that exchange. The parts all the
-shares give sum to the whole layer (``tests/test_sdar_moe.py``,
-``tests/test_mellum_moe.py``).
+shares give sum to the whole layer, a shared expert counted once
+(``tests/test_sdar_moe.py``, ``tests/test_mellum_moe.py``,
+``tests/test_kimi_linear_moe.py``).
 
 Arithmetic: every matrix product takes bfloat16 operands and accumulates in
 float32, forward and backward (``_mm``, ``ops.grouped_matmul``, the attention
@@ -31,7 +39,7 @@ is all the fused step asks of a model.
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -168,42 +176,97 @@ def _held_experts_bwd(size, k, interpret, res, dy):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
+def _swiglu_mlp(m, gate, up, down):
+    """down(silu(gate m) * up m) for every token: a dense MLP, a shared expert."""
+    return _mm(jax.nn.silu(_mm(m, gate)) * _mm(m, up), down)
+
+
 class MoETower:
     """The tower's parameters, layers and scan, for a frozen dataclass that
     holds its sizes (``vocab``, ``n_layers``, ``hidden``, ``n_heads``,
     ``n_kv_heads``, ``head_dim``, ``n_experts``, ``experts_per_token``,
     ``expert_width``, ``first_held``, ``n_held``, ``rms_eps``, ``interpret``
-    for the Pallas kernels) and states
-    ``layer_kinds``, the kinds of layer of one period in their order: the
-    layers are the period repeated. A kind has a RoPE table and an attention
-    of its own; everything else of a layer is the same layer."""
+    for the Pallas kernels) and states what its layers are:
+
+    - ``layer_kinds``, the kinds of layer of one period in their order: the
+      scanned layers are the period repeated. A kind has an attention of its
+      own (``attention``) and what that takes beside the layer's leaves (a
+      RoPE table, the documents' starts).
+    - ``kind_leaves``: False where every kind holds the same leaves (they are
+      stacked along one layer axis under ``layers``), True where the kinds'
+      attentions hold different ones (``attention_shapes``): each kind's
+      layers are then stacked under ``layers[kind]``. Either way a period is
+      one scan body.
+    - ``leading_kinds``: layers before the scan, each with leaves of its own
+      under ``lead``, whose MLP is dense (a SwiGLU of ``dense_width``, no
+      router).
+    - ``mlp``: what follows a scanned layer's attention: ``"experts"`` (the
+      held routed experts' part) or ``"shared_experts"`` (that and an expert
+      every token takes, computed here whole).
+    - ``router_law``: ``"softmax"`` (softmax over all experts, the k largest,
+      renormalised) or ``"sigmoid"`` (sigmoid scores, the k largest of score
+      plus a selection bias that no gradient reaches, the picked scores
+      renormalised and times ``routed_scaling``)."""
 
     layer_kinds: Tuple[str, ...] = ("attention",)
+    kind_leaves: bool = False
+    leading_kinds: Tuple[str, ...] = ()
+    mlp: str = "experts"
+    router_law: str = "softmax"
 
     # ------------------------------------------------------------ parameters
 
-    def param_shapes(self) -> Dict[str, Tuple[int, ...]]:
-        """Every dense leaf; those under ``layers`` carry a leading layer axis."""
-        d, hd, n = self.hidden, self.head_dim, self.n_layers
-        e, f = self.n_held, self.expert_width
-        return {
-            "layers": {
-                "norm1": (n, d), "wq": (n, d, self.n_heads * hd), "wk": (n, d, self.n_kv_heads * hd),
-                "wv": (n, d, self.n_kv_heads * hd), "q_norm": (n, hd), "k_norm": (n, hd),
-                "wo": (n, self.n_heads * hd, d), "norm2": (n, d), "router": (n, d, self.n_experts),
-                "gate": (n, e, d, f), "up": (n, e, d, f), "down": (n, e, f, d),
-            },
-            "norm_f": (d,), "head": (d, self.vocab),
-        }
+    def attention_shapes(self, kind: str) -> Dict[str, Tuple[int, ...]]:
+        """The leaves of one layer's attention of ``kind``."""
+        d, hd = self.hidden, self.head_dim
+        return {"wq": (d, self.n_heads * hd), "wk": (d, self.n_kv_heads * hd),
+                "wv": (d, self.n_kv_heads * hd), "q_norm": (hd,), "k_norm": (hd,),
+                "wo": (self.n_heads * hd, d)}
+
+    def mlp_shapes(self, mlp: str) -> Dict[str, Tuple[int, ...]]:
+        """The leaves of one layer's MLP: the router and the held experts, the
+        shared expert beside them, or a dense SwiGLU."""
+        d, e, f = self.hidden, self.n_held, self.expert_width
+        if mlp == "dense":
+            w = self.dense_width
+            return {"dense_gate": (d, w), "dense_up": (d, w), "dense_down": (w, d)}
+        out = {"router": (d, self.n_experts), "gate": (e, d, f), "up": (e, d, f), "down": (e, f, d)}
+        if mlp == "shared_experts":
+            out.update(shared_gate=(d, f), shared_up=(d, f), shared_down=(f, d))
+        return out
+
+    def layer_shapes(self, kind: str, mlp: str) -> Dict[str, Tuple[int, ...]]:
+        d = self.hidden
+        return {"norm1": (d,), **self.attention_shapes(kind), "norm2": (d,), **self.mlp_shapes(mlp)}
+
+    @property
+    def n_scanned(self) -> int:
+        return self.n_layers - len(self.leading_kinds)
+
+    def param_shapes(self) -> Dict[str, Any]:
+        """Every dense leaf; those under ``layers`` carry a leading layer axis
+        (of all scanned layers, or of a kind's where ``kind_leaves``)."""
+        kinds, n = self.layer_kinds, self.n_scanned
+        stacked = lambda shapes, count: {k: (count, *s) for k, s in shapes.items()}
+        if self.kind_leaves:
+            layers = {kind: stacked(self.layer_shapes(kind, self.mlp), kinds.count(kind) * n // len(kinds))
+                      for kind in set(kinds)}
+        else:
+            layers = stacked(self.layer_shapes(kinds[0], self.mlp), n)
+        out = {"layers": layers, "norm_f": (self.hidden,), "head": (self.hidden, self.vocab)}
+        if self.leading_kinds:
+            out["lead"] = tuple(self.layer_shapes(kind, "dense") for kind in self.leading_kinds)
+        return out
 
     def counters(self) -> Dict[str, Any]:
-        """What the step counts on the device: the picks by layer and held expert."""
-        return {"expert_picks": jnp.zeros((self.n_layers, self.n_held), jnp.int32)}
+        """What the step counts on the device: the picks by expert layer and held expert."""
+        return {"expert_picks": jnp.zeros((self.n_scanned, self.n_held), jnp.int32)}
 
     def init(self, rng, dense, emb, train: bool = False) -> Dict[str, Any]:
         """Normal(0, 0.02) products, unit norms, and the step's counters."""
         shapes = self.param_shapes()
-        leaves, tree = jax.tree.flatten_with_path(shapes, is_leaf=lambda s: isinstance(s, tuple))
+        leaves, tree = jax.tree.flatten_with_path(shapes, is_leaf=lambda s: isinstance(s, tuple) and (
+            not s or isinstance(s[0], int)))
         keys = jax.random.split(rng, len(leaves))
         params = jax.tree.unflatten(tree, [
             jnp.ones(shape, jnp.float32) if "norm" in path[-1].key
@@ -213,24 +276,40 @@ class MoETower:
 
     # ---------------------------------------------------------------- layers
 
-    def layers(self, params, rows, rope, attend):
+    def layers(self, params, rows, side, attend=None, buffers=None):
         """The residual stream after the last layer, (B, T, hidden) float32,
-        and the picks by layer and held expert. ``rope[kind]`` is the kind's
-        ``(cos, sin)``, ``attend(kind, q, k, v)`` its attention over bfloat16
-        q (B, T, Hq, D), k and v (B, T, Hkv, D).
+        and the picks by scanned layer and held expert. ``side[kind]`` is what
+        the kind's attention takes beside the leaves (the default's: its RoPE
+        ``(cos, sin)``), ``attend(kind, q, k, v)`` the default attention's
+        kernel over bfloat16 q (B, T, Hq, D), k and v (B, T, Hkv, D).
+        ``buffers``: leaves that are no parameters, ``{kind: {leaf: stacked}}``
+        as a ``kind_leaves`` tower's ``params["layers"]``, which a layer finds
+        beside its own.
 
-        One scan over the periods, a period's layers written out in its body.
-        Each layer is recomputed in the backward, but for its attention
-        kernel's output and row statistics, which are kept (150 MB a layer at
-        the benchmark's sequence cells against 12 ms of the forward kernel)."""
-        kinds = self.layer_kinds
-        if self.n_layers % len(kinds):
-            raise ValueError(f"{self.n_layers} layers are no whole periods of {kinds}")
+        The leading layers, then one scan over the periods, a period's layers
+        written out in its body. Each layer is recomputed in the backward, but
+        for its attention kernel's output and row statistics, which are kept
+        (150 MB a layer at the benchmark's sequence cells against 12 ms of the
+        forward kernel)."""
+        kinds, lead = self.layer_kinds, self.leading_kinds
+        if self.n_scanned % len(kinds):
+            raise ValueError(f"{self.n_scanned} layers are no whole periods of {kinds}")
         keep = jax.checkpoint_policies.save_only_these_names(ATTENTION_OUT, ATTENTION_LSE)
-        scope = {k: "attention" if len(set(kinds)) == 1 else f"attention/{k}" for k in kinds}
-        layer = {k: jax.checkpoint(partial(self._layer, rope=rope[k], scope=scope[k],
-                                           attend=partial(attend, k)), policy=keep)
-                 for k in set(kinds)}
+        every = set(kinds) | set(lead)
+        scope = {k: "attention" if len(every) == 1 else f"attention/{k}" for k in every}
+
+        def make(kind, mlp):
+            return jax.checkpoint(partial(self._layer, kind=kind, mlp=mlp, side=side.get(kind),
+                                          scope=scope[kind], attend=attend and partial(attend, kind)),
+                                  policy=keep)
+
+        h = rows.astype(jnp.float32)
+        for kind, p in zip(lead, params.get("lead", ())):
+            h, _ = make(kind, "dense")(p, h)
+        layer = {k: make(k, self.mlp) for k in set(kinds)}
+        stacked = params["layers"]
+        if buffers is not None:  # by kind, as the leaves of a tower whose kinds hold their own
+            stacked = {kind: dict(leaves, **buffers.get(kind, {})) for kind, leaves in stacked.items()}
 
         # a period of one layer is scanned as the stacked leaves lie: regrouping
         # them costs the SDAR cell 0.8% of its step in slices and updates of the
@@ -238,36 +317,58 @@ class MoETower:
         single = len(kinds) == 1
 
         def period(h, p):
-            picks = []
+            picks, seen = [], {}
             for i, kind in enumerate(kinds):
-                h, got = layer[kind](p if single else jax.tree.map(lambda x: x[i], p), h)
+                if self.kind_leaves:
+                    j = seen[kind] = seen.get(kind, -1) + 1  # its place among the period's layers of its kind
+                    mine = jax.tree.map(lambda x: x[j], p[kind])
+                else:
+                    mine = p if single else jax.tree.map(lambda x: x[i], p)
+                h, got = layer[kind](mine, h)
                 picks.append(got)
             return h, picks[0] if single else jnp.stack(picks)
 
-        by_period = params["layers"] if single else jax.tree.map(
-            lambda x: x.reshape(-1, len(kinds), *x.shape[1:]), params["layers"])
-        h, picks = jax.lax.scan(period, rows.astype(jnp.float32), by_period)
-        return h, picks.reshape(self.n_layers, self.n_held)
+        if self.kind_leaves:
+            by_period = {kind: jax.tree.map(lambda x: x.reshape(-1, kinds.count(kind), *x.shape[1:]), leaves)
+                         for kind, leaves in stacked.items()}
+        else:
+            by_period = stacked if single else jax.tree.map(
+                lambda x: x.reshape(-1, len(kinds), *x.shape[1:]), stacked)
+        h, picks = jax.lax.scan(period, h, by_period)
+        return h, picks.reshape(self.n_scanned, self.n_held)
 
-    def _layer(self, p, h, rope, scope, attend):
-        b, t, d = h.shape
+    def attention(self, kind, p, a, side, attend):
+        """What a layer's attention adds to the residual stream for its normed
+        input ``a`` (B, T, hidden). Here: grouped-query softmax attention with
+        per-head q/k norms and RoPE, the kernel the tower's ``attend``."""
+        b, t, _ = a.shape
         hd = self.head_dim
-        cos, sin = rope
+        cos, sin = side
+        # norm, RoPE and the cast as one pass over the projections as they
+        # come, a head a column block; the kernels read them so, and the
+        # reshapes at their boundary move nothing
+        q = qk_norm_rope(_mm(a, p["wq"]), p["q_norm"], cos, sin, self.n_heads, self.rms_eps,
+                         interpret=self.interpret)
+        k = qk_norm_rope(_mm(a, p["wk"]), p["k_norm"], cos, sin, self.n_kv_heads, self.rms_eps,
+                         interpret=self.interpret)
+        v = _mm(a, p["wv"]).astype(jnp.bfloat16)
+        o = attend(q.reshape(b, t, self.n_heads, hd), k.reshape(b, t, self.n_kv_heads, hd),
+                   v.reshape(b, t, self.n_kv_heads, hd))
+        # bfloat16 as the kernel leaves it: what the product takes, and the
+        # gradient comes back in what the kernel's backward takes
+        return _mm(o.reshape(b, t, -1), p["wo"])
+
+    def _layer(self, p, h, kind, mlp, side, scope, attend):
+        b, t, d = h.shape
         with jax.named_scope(scope):
             a = _rms(h, p["norm1"], self.rms_eps)
-            # norm, RoPE and the cast as one pass over the projections as they
-            # come, a head a column block; the kernels read them so, and the
-            # reshapes at their boundary move nothing
-            q = qk_norm_rope(_mm(a, p["wq"]), p["q_norm"], cos, sin, self.n_heads, self.rms_eps,
-                             interpret=self.interpret)
-            k = qk_norm_rope(_mm(a, p["wk"]), p["k_norm"], cos, sin, self.n_kv_heads, self.rms_eps,
-                             interpret=self.interpret)
-            v = _mm(a, p["wv"]).astype(jnp.bfloat16)
-            o = attend(q.reshape(b, t, self.n_heads, hd), k.reshape(b, t, self.n_kv_heads, hd),
-                       v.reshape(b, t, self.n_kv_heads, hd))
-            # bfloat16 as the kernel leaves it: what the product takes, and the
-            # gradient comes back in what the kernel's backward takes
-            h = h + _mm(o.reshape(b, t, -1), p["wo"])
+            h = h + self.attention(kind, p, a, side, attend)
+        if mlp == "dense":
+            with jax.named_scope("dense_mlp"):
+                m = _rms(h, p["norm2"], self.rms_eps)
+                # its 9,216-wide intermediates recomputed in its own backward: they do not stand
+                # beside what the layer's attention keeps
+                return h + jax.checkpoint(_swiglu_mlp)(m, p["dense_gate"], p["dense_up"], p["dense_down"]), None
         with jax.named_scope("moe"):
             m = _rms(h, p["norm2"], self.rms_eps)
             y, picks = self.experts(p, m.reshape(b * t, d))
@@ -294,17 +395,33 @@ class MoETower:
         return {"experts": "pallas_grouped", "experts_tile": "/".join("x".join(map(str, t)) for t in tiles),
                 "held": self.n_held, "pick_chunk": chunk}
 
+    def route(self, p, m):
+        """The router's law: each token's ``experts_per_token`` experts of all
+        ``n_experts`` and their weights, both (N, k); float32 scores."""
+        k = self.experts_per_token
+        if self.router_law == "softmax":
+            probs = jax.nn.softmax(_mm(m, p["router"]), axis=-1)
+            top_p, top_e = jax.lax.top_k(probs, k)
+            return top_p / jnp.sum(top_p, axis=-1, keepdims=True), top_e  # norm_topk_prob
+        if self.router_law != "sigmoid":
+            raise ValueError(f"router_law {self.router_law!r}; known: softmax, sigmoid")
+        score = jax.nn.sigmoid(_mm(m, p["router"]))
+        # the bias moves the selection alone: a buffer, no gradient reaches it
+        _, top_e = jax.lax.top_k(jax.lax.stop_gradient(score + p["router_bias"]), k)
+        top_s = jnp.take_along_axis(score, top_e, axis=-1)
+        return self.routed_scaling * top_s / jnp.sum(top_s, axis=-1, keepdims=True), top_e
+
     def experts(self, p, m):
         """This chip's experts' part of the layer's result for tokens
         ``m`` (N, hidden), and the picks each held expert got, (n_held,).
         ``p`` holds ``router`` (hidden, n_experts) and the held experts'
-        ``gate``, ``up`` (n_held, hidden, width) and ``down``."""
+        ``gate``, ``up`` (n_held, hidden, width) and ``down``; under
+        ``"shared_experts"`` also the shared expert's, whose result for every
+        token is added here, once."""
         n, d = m.shape
         k, held = self.experts_per_token, self.n_held
         with jax.named_scope("router"):
-            probs = jax.nn.softmax(_mm(m, p["router"]), axis=-1)
-            top_p, top_e = jax.lax.top_k(probs, k)
-            weight = top_p / jnp.sum(top_p, axis=-1, keepdims=True)  # norm_topk_prob
+            weight, top_e = self.route(p, m)
         with jax.named_scope("dispatch"):
             local = top_e - self.first_held
             local = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
@@ -316,8 +433,71 @@ class MoETower:
         order = jnp.pad(order, (0, -(n * k) % chunk))  # a last chunk may run past the picks
         y = _held_experts(m, weight.reshape(-1), p["gate"], p["up"], p["down"], order, starts, chunk, k,
                           self.interpret)
+        if self.mlp == "shared_experts":
+            with jax.named_scope("shared"):
+                y = y + _swiglu_mlp(m, p["shared_gate"], p["shared_up"], p["shared_down"])
         return y, starts[1:] - starts[:-1]
 
     def head(self, params, h):
         """Logits of positions ``h`` (..., hidden), float32 over the ids held here."""
         return _mm(_rms(h, params["norm_f"], self.rms_eps), params["head"])
+
+
+class NextTokenTower(MoETower):
+    """A tower trained on next tokens over packed documents: it states
+    ``_hidden(variables, dense, emb) -> (residual stream, counters)`` and
+    ``head_chunk``, and every position has a label, so head and loss never
+    meet whole: ``train_loss`` (which ``build_fused_train_step`` takes where a
+    model states one) runs them in chunks of ``head_chunk`` positions, each
+    chunk's logits recomputed in the backward, and no ``(T, vocab)`` array is
+    ever live. ``models/mellum_moe.py`` and ``models/kimi_linear_moe.py``."""
+
+    def apply(self, variables, dense, emb, train: bool = True, mutable: Optional[Sequence[str]] = None):
+        """Logits of every position, (B, T, vocab) float32. ``dense`` is
+        ``[starts (B, T) int32]``, ``emb`` one raw slot, ``(rows (B, T,
+        hidden), mask)``. With ``mutable=["batch_stats"]`` also the counters."""
+        del train
+        h, stats = self._hidden(variables, dense, emb)
+        with jax.named_scope("lm_head"):
+            logits = self.head(variables["params"], h)
+        return (logits, {"batch_stats": stats}) if mutable else logits
+
+    def loss(self, logits, labels):
+        """Next-token cross-entropy: ``labels`` = [next token (B, T) int32,
+        weight (B, T) float32 (0 at a document's last position, else 1)];
+        the weighted mean."""
+        targets, weight = labels[0], labels[1]
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, targets[..., None].astype(jnp.int32), axis=-1)[..., 0]
+        return jnp.sum(weight * (logz - picked)) / jnp.sum(weight)
+
+    def outputs(self, logits):
+        """The most likely next id of each position, (B, T) int32."""
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def train_loss(self, variables, dense, emb, labels):
+        """``(loss, outputs, counters)`` of a training step, the head and the
+        loss in chunks of ``head_chunk`` positions under one scan whose body is
+        recomputed in the backward: a chunk's logits and their gradient are
+        the largest arrays the head ever holds."""
+        h, stats = self._hidden(variables, dense, emb)
+        params = variables["params"]
+        b, t, d = h.shape
+        chunk = min(self.head_chunk, b * t)
+        if (b * t) % chunk:
+            raise ValueError(f"{b * t} positions are no whole chunks of {chunk}")
+        by_chunk = lambda x: x.reshape((b * t) // chunk, chunk, *x.shape[2:])
+        targets, weight = labels[0].astype(jnp.int32), labels[1].astype(jnp.float32)
+
+        @jax.checkpoint
+        def one(total, xs):
+            hc, tc, wc = xs
+            logits = self.head(params, hc)
+            picked = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
+            total = total + jnp.sum(wc * (jax.nn.logsumexp(logits, axis=-1) - picked))
+            return total, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        with jax.named_scope("lm_head"):
+            total, ids = jax.lax.scan(one, jnp.zeros((), jnp.float32),
+                                      (by_chunk(h), by_chunk(targets), by_chunk(weight)))
+        return total / jnp.sum(weight), ids.reshape(b, t), stats

@@ -16,7 +16,12 @@ kernels take as prefetched scalars: a dead pair is neither fetched nor
 computed. Forward, dq and dk/dv are all kernels and only the log-sum-exp a
 row is kept between them, so nothing is L^2 anywhere. ``models/mellum_moe.py``
 runs on it, at one packed sequence of 16,384 positions, 32 query heads over 4
-K/V heads, in the benchmark's cell ``mellum2-ep4-pack16k``.
+K/V heads, in the benchmark's cell ``mellum2-ep4-pack16k``. With
+``k_shared`` a score is wider than a value: R further key columns that every
+K/V head shares and R further columns a query head, their product added to
+the scores a tile in all three kernels (latent attention without positions:
+``models/kimi_linear_moe.py``, 32 heads at 192/128, in the cell
+``kimi-linear-ep32-pack16k``); without it the kernels are what they were.
 
 **The block-diffusion mask** (``block_diffusion_attention``) beside it: the
 same three kernel bodies, the mask given by ``(seq_len, block_len)``, so its
@@ -329,16 +334,22 @@ def block_diffusion_plan(seq_len: int, block_len: int, tile: int = BLOCK_DIFFUSI
                 pairs_executed=executed * sub * sub, pairs_live=seq_len * (seq_len + block_len))
 
 
-def _bd_scores(q, k, scale, test, block_len, q_at, k_at):
+def _raw_scores(q, k, more=None):
+    s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32, precision=_ONE_PASS)
+    return s if more is None else s + more
+
+
+def _bd_scores(q, k, scale, test, block_len, q_at, k_at, more=None):
     """Scaled scores of keys ``k`` against queries ``q`` in float32, keys down
     and queries across: in this orientation a query's statistics are a row,
     reduced over sublanes and stored lane-dense. With a ``test`` the keys and
     queries are a slab of a cut tile that starts at the tile's positions
     ``k_at`` and ``q_at``, and a key is masked unless its block passes the
     test against the query's: the block indices are a column and a row of
-    positions in the tile, the grid pays one compare and one select."""
-    s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32, precision=_ONE_PASS) * scale
+    positions in the tile, the grid pays one compare and one select. ``more``:
+    a second product's part of the same scores, unscaled, added before the scale."""
+    s = _raw_scores(q, k, more) * scale
     if test is None:
         return s
 
@@ -369,6 +380,9 @@ def _walk(kind, visit, tile, sub, by_keys, cuts=_CUT, by_lo=False):
                 visit(pl.ds(q0 * sub, nq * sub), pl.ds(k0 * sub, nk * sub), test)
     if by_lo:
         pl.when(kind == _LO)(lambda: visit(pl.ds(0, tile), pl.ds(0, tile), _LO))
+
+
+_NO_MORE = (lambda: None, lambda dst, queries, keys: None, lambda: None)
 
 
 # The three kernels' bodies, shared by the two masks. ``at`` is the grid
@@ -414,10 +428,13 @@ def _fwd_body(at, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, o_ref, lse
 
 
 def _dq_body(at, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-             dq_ref, acc_ref, scores, walk, scale):
+             dq_ref, acc_ref, scores, walk, scale, more=_NO_MORE):
+    # ``more`` (here and in ``_dkv_body``): what a second product of the scores
+    # adds to the kernel, as (init, visit(dst, queries, keys), finalize)
     @pl.when(first_ref[at] == 1)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
+        more[0]()
 
     def visit(queries, keys, test):
         k, do = k_ref[0, keys], do_ref[0, queries]
@@ -429,16 +446,18 @@ def _dq_body(at, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref, lse
         acc_ref[queries] += jax.lax.dot_general(dst, k, (((0,), (0,)), ((), ())),
                                                 preferred_element_type=jnp.float32,
                                                 precision=_ONE_PASS)
+        more[1](dst, queries, keys)
 
     walk(kind_ref[at], visit, by_keys=False)
 
     @pl.when(last_ref[at] == 1)
     def _finalize():
         dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
+        more[2]()
 
 
 def _dkv_body(at, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-              dk_ref, dv_ref, dk_acc, dv_acc, scores, walk, scale, group):
+              dk_ref, dv_ref, dk_acc, dv_acc, scores, walk, scale, group, more=_NO_MORE):
     # here a "row" of the tables is a k tile and its "columns" the q tiles that
     # read it; a pair's kind is the same pair's, and a cut tile is walked by
     # key rows, each over the query columns that read it
@@ -448,6 +467,7 @@ def _dkv_body(at, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref, ls
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+        more[0]()
 
     def visit(queries, keys, test):
         q, do = q_ref[0, queries], do_ref[0, queries]
@@ -460,6 +480,7 @@ def _dkv_body(at, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref, ls
         dst = (pt * (dpt - delta_ref[0, 0, :, queries])).astype(q.dtype)
         dk_acc[keys] += jax.lax.dot_general(dst, q, (((1,), (0,)), ((), ())),
                                             preferred_element_type=jnp.float32, precision=_ONE_PASS)
+        more[1](dst, queries, keys)
 
     walk(kind_ref[at], visit, by_keys=True)
 
@@ -467,6 +488,7 @@ def _dkv_body(at, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref, ls
     def _finalize():
         dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        more[2]()
 
 
 def _bd_mask(scale, tile, sub, block_len):
@@ -510,11 +532,13 @@ def _bd_plan(q, k, seq_len, block_len, tile):
     return tile, _sub_tile(tile, block_len), _live_tiles(seq_len, block_len, tile)
 
 
-def _forward_call(kernel, name, tables, per_batch, q, k, v, lo, tile, interpret):
+def _forward_call(kernel, name, tables, per_batch, q, k, v, lo, tile, interpret, more=None):
     """The forward kernel over the visits of ``tables``: ``per_batch`` 0 where
     one list of visits serves every sequence (the block-diffusion mask), else
     the visits a sequence, each with a list of its own; ``lo`` (B, 1, T) the
-    intervals' starts where the kernel takes them, else None."""
+    intervals' starts where the kernel takes them, else None. ``more``: the
+    scores' second product, ``(q_more (B, Hq, T, R), k_shared (B, T, R))``,
+    which the kernel takes after ``lo``."""
     b, t, hq, d = q.shape
     group = hq // k.shape[2]
     at = (lambda bi, p: p) if not per_batch else (lambda bi, p: bi * per_batch + p)
@@ -525,6 +549,10 @@ def _forward_call(kernel, name, tables, per_batch, q, k, v, lo, tile, interpret)
     kv_at = lambda bi, h, p, row, col, *_: (bi, col[at(bi, p)], h // group)
     lo_in = [] if lo is None else [
         pl.BlockSpec((1, 1, tile), lambda bi, h, p, row, *_: (bi, 0, row[at(bi, p)]))]
+    if more is not None:
+        r = more[1].shape[-1]
+        lo_in += [pl.BlockSpec((1, 1, tile, r), lambda bi, h, p, row, *_: (bi, h, row[at(bi, p)], 0)),
+                  pl.BlockSpec((1, tile, r), lambda bi, h, p, row, col, *_: (bi, col[at(bi, p)], 0))]
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -545,15 +573,17 @@ def _forward_call(kernel, name, tables, per_batch, q, k, v, lo, tile, interpret)
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=f"{name}_fwd",
-    )(*tables, *([] if lo is None else [lo]), q2, k2, v2)
+    )(*tables, *([] if lo is None else [lo]), *(more or ()), q2, k2, v2)
     return out.reshape(b, t, hq, d), lse
 
 
 def _backward_call(dq_kernel, dkv_kernel, name, tables, tables_t, per_batch, q, k, v, out, lse, do,
-                   lo, tile, interpret):
+                   lo, tile, interpret, more=None):
     """dq over ``tables`` (a q tile's k tiles) and dk, dv over ``tables_t`` (a k
     tile's q tiles, the group's query heads innermost so that a k tile's sums
-    stay in VMEM); the arguments as ``_forward_call``'s."""
+    stay in VMEM); the arguments as ``_forward_call``'s. With ``more`` also
+    the gradients of the second product's operands: ``q_more``'s, and
+    ``k_shared``'s by K/V head (B, Hkv, T, R) float32, for the caller to sum."""
     b, t, hq, d = q.shape
     hkv = k.shape[2]
     group = hq // hkv
@@ -564,14 +594,19 @@ def _backward_call(dq_kernel, dkv_kernel, name, tables, tables_t, per_batch, q, 
                        do.astype(jnp.float32))[:, :, None, :]
     do = do.astype(q.dtype)
     q2, k2, v2, do2 = (x.reshape(b, t, -1) for x in (q, k, v, do))
-    lo_arg = [] if lo is None else [lo]
+    lo_arg = ([] if lo is None else [lo]) + list(more or ())
+    r = more[1].shape[-1] if more else 0
 
     q_at = lambda bi, h, p, row, col, *_: (bi, row[at(bi, p)], h)
     kv_at = lambda bi, h, p, row, col, *_: (bi, col[at(bi, p)], h // group)
     stat_at = lambda bi, h, p, row, *_: (bi, h, 0, row[at(bi, p)])
     lo_in = [] if lo is None else [
         pl.BlockSpec((1, 1, tile), lambda bi, h, p, row, *_: (bi, 0, row[at(bi, p)]))]
-    dq = pl.pallas_call(
+    more_at = lambda bi, h, p, row, *_: (bi, h, row[at(bi, p)], 0)
+    if more:
+        lo_in += [pl.BlockSpec((1, 1, tile, r), more_at),
+                  pl.BlockSpec((1, tile, r), lambda bi, h, p, row, col, *_: (bi, col[at(bi, p)], 0))]
+    dq, *dq_more = pl.pallas_call(
         dq_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
@@ -580,10 +615,13 @@ def _backward_call(dq_kernel, dkv_kernel, name, tables, tables_t, per_batch, q, 
                               pl.BlockSpec((1, tile, d), kv_at), pl.BlockSpec((1, tile, d), q_at),
                               pl.BlockSpec((1, 1, 1, tile), stat_at),
                               pl.BlockSpec((1, 1, 1, tile), stat_at)],
-            out_specs=pl.BlockSpec((1, tile, d), q_at),
-            scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)],
+            out_specs=[pl.BlockSpec((1, tile, d), q_at)] + (
+                [pl.BlockSpec((1, 1, tile, r), more_at)] if more else []),
+            scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)] + (
+                [pltpu.VMEM((tile, r), jnp.float32)] if more else []),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, t, hq * d), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((b, t, hq * d), q.dtype)] + (
+            [jax.ShapeDtypeStruct(more[0].shape, more[0].dtype)] if more else []),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -595,7 +633,12 @@ def _backward_call(dq_kernel, dkv_kernel, name, tables, tables_t, per_batch, q, 
     stat_at = lambda bi, hk, p, g, row, col, *_: (bi, hk * group + g, 0, col[at(bi, p)])
     lo_in = [] if lo is None else [
         pl.BlockSpec((1, 1, tile), lambda bi, hk, p, g, row, col, *_: (bi, 0, col[at(bi, p)]))]
-    dk, dv = pl.pallas_call(
+    shared_at = lambda bi, hk, p, g, row, *_: (bi, hk, row[at(bi, p)], 0)
+    if more:
+        lo_in += [pl.BlockSpec((1, 1, tile, r),
+                               lambda bi, hk, p, g, row, col, *_: (bi, hk * group + g, col[at(bi, p)], 0)),
+                  pl.BlockSpec((1, tile, r), lambda bi, hk, p, g, row, *_: (bi, row[at(bi, p)], 0))]
+    dk, dv, *dk_more = pl.pallas_call(
         dkv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
@@ -604,18 +647,21 @@ def _backward_call(dq_kernel, dkv_kernel, name, tables, tables_t, per_batch, q, 
                               pl.BlockSpec((1, tile, d), kv_at), pl.BlockSpec((1, tile, d), q_at),
                               pl.BlockSpec((1, 1, 1, tile), stat_at),
                               pl.BlockSpec((1, 1, 1, tile), stat_at)],
-            out_specs=[pl.BlockSpec((1, tile, d), kv_at), pl.BlockSpec((1, tile, d), kv_at)],
+            out_specs=[pl.BlockSpec((1, tile, d), kv_at), pl.BlockSpec((1, tile, d), kv_at)] + (
+                [pl.BlockSpec((1, 1, tile, r), shared_at)] if more else []),
             scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32),
-                            pltpu.VMEM((tile, d), jnp.float32)],
+                            pltpu.VMEM((tile, d), jnp.float32)] + (
+                [pltpu.VMEM((tile, r), jnp.float32)] if more else []),
         ),
         out_shape=[jax.ShapeDtypeStruct((b, t, hkv * d), k.dtype),
-                   jax.ShapeDtypeStruct((b, t, hkv * d), v.dtype)],
+                   jax.ShapeDtypeStruct((b, t, hkv * d), v.dtype)] + (
+            [jax.ShapeDtypeStruct((b, hkv, t, r), jnp.float32)] if more else []),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         name=f"{name}_dkv",
     )(*tables_t, *lo_arg, q2, k2, v2, do2, lse, delta)
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)) + tuple(dq_more + dk_more)
 
 
 def _bd_forward(q, k, v, seq_len, block_len, scale, tile, interpret):
@@ -775,16 +821,22 @@ def interval_tile_counts(lo, window: Optional[int] = None, tile: int = BLOCK_DIF
     return visited, jnp.sum(own - first_tile + 1, dtype=jnp.int32)
 
 
-def _iv_mask(scale, tile, sub, lo_ref, q_tile, k_tile):
+def _iv_mask(scale, tile, sub, lo_ref, q_tile, k_tile, more=None):
     """The interval mask's ``scores`` and ``walk``: a tile cut by its diagonal
     alone goes the block-diffusion kernels' way (blocks of one position); any
     other cut tile takes ``lo_j <= key <= query`` from the keys' positions (a
-    column), the queries' (a row) and the queries' ``lo`` (a row)."""
+    column), the queries' (a row) and the queries' ``lo`` (a row). ``more``
+    ``(q_more_ref, k_shared_ref)``: columns every K/V head shares, whose
+    product with the query head's further columns is added to the scores."""
+    def second(queries, keys):
+        if more is None:
+            return None
+        return _raw_scores(more[0][0, 0, queries], more[1][0, keys])
+
     def scores(q, k, test, queries, keys):
         if test is None or callable(test):
-            return _bd_scores(q, k, scale, test, 1, queries.start, keys.start)
-        s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32, precision=_ONE_PASS) * scale
+            return _bd_scores(q, k, scale, test, 1, queries.start, keys.start, second(queries, keys))
+        s = _raw_scores(q, k, second(queries, keys)) * scale
         k_pos = jax.lax.broadcasted_iota(jnp.int32, (k.shape[0], 1), 0) + (k_tile * tile + keys.start)
         q_pos = jax.lax.broadcasted_iota(jnp.int32, (1, q.shape[0]), 1) + (q_tile * tile + queries.start)
         return jnp.where((k_pos >= lo_ref[0, :, queries]) & (k_pos <= q_pos), s, _NEG_BIG)
@@ -792,25 +844,75 @@ def _iv_mask(scale, tile, sub, lo_ref, q_tile, k_tile):
     return scores, functools.partial(_walk, tile=tile, sub=sub, cuts=_DIAGONAL, by_lo=True)
 
 
+def _split_more(refs, shared: bool, n_out: int, n_scratch: int):
+    """A two-width kernel's refs as the bodies take them, and the second
+    product's: its two inputs lead, its output follows the kernel's own
+    ``n_out`` outputs and its sums the ``n_scratch`` scratch buffers."""
+    if not shared:
+        return refs, None
+    q_more, k_shared, *rest = refs
+    n_in = len(rest) - n_out - n_scratch - 2
+    out_more, acc_more = rest[n_in + n_out], rest[-1]
+    own = rest[:n_in + n_out] + rest[n_in + n_out + 1:-1]
+    return own, (q_more, k_shared, out_more, acc_more)
+
+
 def _iv_fwd_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, lo_ref, *refs, scale, tile, sub,
-                   visits):
+                   visits, shared=False):
     at = pl.program_id(0) * visits + pl.program_id(2)
-    _fwd_body(at, kind_ref, first_ref, last_ref, *refs,
-              *_iv_mask(scale, tile, sub, lo_ref, row_ref[at], col_ref[at]))
+    more = refs[:2] if shared else None
+    _fwd_body(at, kind_ref, first_ref, last_ref, *refs[2 if shared else 0:],
+              *_iv_mask(scale, tile, sub, lo_ref, row_ref[at], col_ref[at], more))
+
+
+def _sums_of(acc, out, scale, of_rows):
+    """The second product's (init, visit, finalize) for a backward body:
+    ``acc`` sums ``dst`` against ``of_rows(queries, keys)`` and leaves for ``out``."""
+    def init():
+        acc[:] = jnp.zeros_like(acc)
+
+    def finalize():
+        out[0, 0] = (acc[:] * scale).astype(out.dtype)
+
+    return init, of_rows, finalize
 
 
 def _iv_dq_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, lo_ref, *refs, scale, tile, sub,
-                  visits):
+                  visits, shared=False):
     at = pl.program_id(0) * visits + pl.program_id(2)
+    refs, more = _split_more(refs, shared, 1, 1)
+    hooks = _NO_MORE
+    if more:
+        q_more, k_shared, dq_more, acc = more
+
+        def visit(dst, queries, keys):  # dst (keys, queries)
+            acc[queries] += jax.lax.dot_general(dst, k_shared[0, keys], (((0,), (0,)), ((), ())),
+                                                preferred_element_type=jnp.float32,
+                                                precision=_ONE_PASS)
+
+        hooks = _sums_of(acc, dq_more, scale, visit)
     _dq_body(at, kind_ref, first_ref, last_ref, *refs,
-             *_iv_mask(scale, tile, sub, lo_ref, row_ref[at], col_ref[at]), scale)
+             *_iv_mask(scale, tile, sub, lo_ref, row_ref[at], col_ref[at], more and more[:2]), scale,
+             hooks)
 
 
 def _iv_dkv_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, lo_ref, *refs, scale, tile, sub,
-                   visits, group):
+                   visits, group, shared=False):
     at = pl.program_id(0) * visits + pl.program_id(2)  # rows are k tiles here, columns q tiles
+    refs, more = _split_more(refs, shared, 2, 2)
+    hooks = _NO_MORE
+    if more:
+        q_more, k_shared, dk_more, acc = more
+
+        def visit(dst, queries, keys):
+            acc[keys] += jax.lax.dot_general(dst, q_more[0, 0, queries], (((1,), (0,)), ((), ())),
+                                             preferred_element_type=jnp.float32,
+                                             precision=_ONE_PASS)
+
+        hooks = _sums_of(acc, dk_more, scale, visit)
     _dkv_body(at, kind_ref, first_ref, last_ref, *refs,
-              *_iv_mask(scale, tile, sub, lo_ref, col_ref[at], row_ref[at]), scale, group)
+              *_iv_mask(scale, tile, sub, lo_ref, col_ref[at], row_ref[at], more and more[:2]), scale,
+              group, hooks)
 
 
 def _iv_plan(q, k, lo, tile):
@@ -825,12 +927,12 @@ def _iv_plan(q, k, lo, tile):
     return tile, _sub_tile(tile, 1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _iv_attention(q, k, v, lo, window, scale, tile, interpret):
-    return _iv_attention_fwd(q, k, v, lo, window, scale, tile, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _iv_attention(q, k, v, lo, more, window, scale, tile, interpret):
+    return _iv_attention_fwd(q, k, v, lo, more, window, scale, tile, interpret)[0]
 
 
-def _iv_attention_fwd(q, k, v, lo, window, scale, tile, interpret):
+def _iv_attention_fwd(q, k, v, lo, more, window, scale, tile, interpret):
     tile, sub = _iv_plan(q, k, lo, tile)
     visits = interval_visits(q.shape[1] // tile, tile, window)
     lo = _interval_lo(lo, window)
@@ -838,25 +940,28 @@ def _iv_attention_fwd(q, k, v, lo, window, scale, tile, interpret):
     tables = _interval_tables(kinds, visits)
     tables_t = _interval_tables(jnp.swapaxes(kinds, 1, 2), visits)
     lo = lo[:, None, :]  # lane-dense, as the row statistics are
-    kernel = functools.partial(_iv_fwd_kernel, scale=scale, tile=tile, sub=sub, visits=visits)
+    kernel = functools.partial(_iv_fwd_kernel, scale=scale, tile=tile, sub=sub, visits=visits,
+                               shared=more is not None)
     out, lse = _forward_call(kernel, "interval_attention", tables, visits, q, k, v, lo, tile,
-                             interpret)
+                             interpret, more)
     # named as the block-diffusion kernels' are: a caller that recomputes its
     # layer keeps these two and does not run the forward kernel a second time
     out, lse = checkpoint_name(out, ATTENTION_OUT), checkpoint_name(lse, ATTENTION_LSE)
-    return out, (q, k, v, out, lse, lo, tables, tables_t)
+    return out, (q, k, v, out, lse, lo, tables, tables_t, more)
 
 
 def _iv_attention_bwd(window, scale, tile, interpret, res, do):
-    q, k, v, out, lse, lo, tables, tables_t = res
+    q, k, v, out, lse, lo, tables, tables_t, more = res
     tile, sub = _iv_plan(q, k, lo[:, 0], tile)
     visits = interval_visits(q.shape[1] // tile, tile, window)
-    how = dict(scale=scale, tile=tile, sub=sub, visits=visits)
-    dq, dk, dv = _backward_call(
+    how = dict(scale=scale, tile=tile, sub=sub, visits=visits, shared=more is not None)
+    dq, dk, dv, *d_more = _backward_call(
         functools.partial(_iv_dq_kernel, **how),
         functools.partial(_iv_dkv_kernel, group=q.shape[2] // k.shape[2], **how),
-        "interval_attention", tables, tables_t, visits, q, k, v, out, lse, do, lo, tile, interpret)
-    return dq, dk, dv, None
+        "interval_attention", tables, tables_t, visits, q, k, v, out, lse, do, lo, tile, interpret, more)
+    if more:  # the shared columns' gradient: every K/V head's part, summed in float32
+        d_more = (d_more[0], jnp.sum(d_more[1], axis=1).astype(more[1].dtype))
+    return dq, dk, dv, None, tuple(d_more) if more else None
 
 
 _iv_attention.defvjp(_iv_attention_fwd, _iv_attention_bwd)
@@ -871,6 +976,7 @@ def interval_attention(
     scale: Optional[float] = None,
     tile: int = BLOCK_DIFFUSION_TILE,
     interpret: bool = False,
+    k_shared: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Attention in which query ``i`` reads the keys ``[lo_i, i]``: q [B, T,
     Hq, D], k and v [B, T, Hkv, D], ``lo`` [B, T] int32 -> [B, T, Hq, D];
@@ -895,9 +1001,21 @@ def interval_attention(
     probabilities from the forward's log-sum-exp a row. Products take the
     inputs' dtype as operands and accumulate in float32; the softmax is
     float32, over scores scaled by ``scale`` (``D ** -0.5``).
+
+    **Two widths** (latent attention): with ``k_shared`` [B, T, R], R further
+    key columns that every K/V head shares, q is [B, T, Hq, D + R] and a score
+    is 192 wide where a value is 128: ``q[..., :D] . k + q[..., D:] .
+    k_shared``, scaled by ``(D + R) ** -0.5``. The kernels add the second
+    product a tile; nothing is padded or repeated in HBM: q's further columns
+    go head-major ([B, Hq, T, R], a block's last axis the whole R), and the
+    shared columns' gradient leaves the dk/dv kernel a K/V head and is summed.
     """
     if q.ndim != 4:
         raise ValueError(f"expected [B, T, H, D], got shape {q.shape}")
     scale = q.shape[-1] ** -0.5 if scale is None else float(scale)
-    return _iv_attention(q, k, v, lo, None if window is None else int(window), scale, int(tile),
+    more = None
+    if k_shared is not None:
+        d = k.shape[-1]
+        q, more = q[..., :d], (jnp.swapaxes(q[..., d:], 1, 2), k_shared)
+    return _iv_attention(q, k, v, lo, more, None if window is None else int(window), scale, int(tile),
                          interpret)

@@ -94,6 +94,13 @@ def test_kernel_phase_interpreted():
     assert k["cases"] == 4 and k["max_abs_err"]["float32"] < 1e-5
 
 
+def test_sequence_kernels_phase_interpreted():
+    k = cs.phase_sequence_kernels(length=64, heads=2, chunk=16, tile=16, interpret=True)
+    assert set(k["delta_rule"]) == {"forward", "d0", "d1", "d2", "d3", "d4"}
+    assert set(k["latent_attention"]) == {"forward", "d0", "d1", "d2", "d3"}
+    assert max(k["latent_attention"].values()) < 1e-4  # float32 operands here: exact but for the sums' order
+
+
 @pytest.mark.slow  # a second and third cached stream: ~7 s of CPU compiles
 def test_multichip_phase_on_virtual_devices():
     r = cs.phase_multichip(TINY, steps=12, n_devices=4)

@@ -320,3 +320,60 @@ def test_the_grouped_products_compile_at_the_cells_widths(
     assert not re.search(r"= bf16\[[\d,]+\]\S* (transpose|copy)\(", text) and "ragged" not in text
     # beside the result: the walk's four small arrays
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
+
+
+# ---- the delta-rule and latent-attention cell's kernels (kimi-linear-ep32-pack16k, ISSUE 40) ----
+
+@pytest.mark.parametrize("what", ["forward", "backward"])
+def test_the_delta_rule_compiles_at_the_cells_widths(one_chip, no_compile_cache, highest_by_default, what):
+    """A pass of a KDA layer: 8 heads of 128 x 128, one sequence of 16,384
+    positions in chunks of 64, ``lo`` an argument: the four Mosaic kernels
+    (a chunk's operands and the scan over chunks, each way), the chunk states
+    the largest array between them."""
+    from persia_tpu.ops.delta_rule import kda
+
+    length, heads = 16384, 8
+    x = jax.ShapeDtypeStruct((1, length, heads, 128), jnp.float32, sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((1, length, heads), jnp.float32, sharding=one_chip)
+    lo = jax.ShapeDtypeStruct((1, length), jnp.int32, sharding=one_chip)
+
+    def backward(q, k, v, g, beta, lo):
+        return jax.grad(lambda *a: kda(*a, lo).sum(), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+    compiled = jax.jit(kda if what == "forward" else backward).lower(x, x, x, x, beta, lo).compile()
+    kernels = sorted(set(re.findall(r"kda_(?:chunk|prepare)_\w+", compiled.as_text())))
+    want = (["kda_chunk_fwd", "kda_prepare_fwd"] if what == "forward"
+            else ["kda_chunk_bwd", "kda_chunk_fwd", "kda_prepare_bwd", "kda_prepare_fwd"])
+    assert [k for k in want if any(k in name for name in kernels)] == want, kernels
+    # the chunk states of 8 heads are 128 MiB; nothing stands a position at 128 x 128
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
+@pytest.mark.parametrize("what", ["forward", "backward"])
+def test_interval_attention_compiles_at_two_widths(one_chip, no_compile_cache, highest_by_default, what):
+    """Latent attention at the cell's widths: 32 heads, scores 192 wide (a
+    head's 128 columns and 64 that all share), values 128, one sequence of
+    16,384 positions: the same three kernels, a second product a tile, and no
+    array 256 lanes a head in HBM."""
+    from persia_tpu.ops.flash_attention import interval_attention
+
+    length = 16384
+    q = jax.ShapeDtypeStruct((1, length, 32, 192), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, length, 32, 128), jnp.bfloat16, sharding=one_chip)
+    shared = jax.ShapeDtypeStruct((1, length, 64), jnp.bfloat16, sharding=one_chip)
+    lo = jax.ShapeDtypeStruct((1, length), jnp.int32, sharding=one_chip)
+
+    def forward(q, k, v, s, lo):
+        return interval_attention(q, k, v, lo, k_shared=s)
+
+    def backward(q, k, v, s, lo):
+        return jax.grad(lambda *a: forward(*a, lo).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3))(q, k, v, s)
+
+    compiled = jax.jit(forward if what == "forward" else backward).lower(q, kv, kv, shared, lo).compile()
+    text = compiled.as_text()
+    kernels = sorted(set(re.findall(r"interval_attention_\w+", text)))
+    want = ["interval_attention_fwd"] if what == "forward" else [
+        "interval_attention_dkv", "interval_attention_dq", "interval_attention_fwd"]
+    assert [k for k in want if any(k in name for name in kernels)] == want, kernels
+    assert "16384,8192]" not in text and "32,16384,256]" not in text  # no head padded to 256 lanes
+    assert compiled.memory_analysis().temp_size_in_bytes < 1200 * 2**20
