@@ -230,7 +230,7 @@ class KimiLinearMoE(NextTokenTower):
         b, t, _ = rows.shape
         tile = min(self.tile, t)
         record_event("kimi_linear.paths", kda="pallas_chunk_scan", kda_chunk=kda_chunk(t, self.kda_chunk),
-                     kda_prepare="pallas", kda_backward_keeps="chunk_states_float32",
+                     kda_prepare="pallas", kda_backward_keeps="chunk_states_float32+chunk_inverse_float32",
                      kda_heads_a_pass=min(self.kda_heads, self.n_heads),
                      convolution="xla", norms_and_gates="xla",
                      latent_attention="pallas_interval_two_products", tile=tile, seq_len=t,

@@ -26,9 +26,13 @@ at once and what is left to scan is linear in the state:
 ``U0``, ``Q+``, ``P``, ``Ke``, ``gamma``) from q, k, v, g, beta and ``lo``: the
 running sums, the pair products, the inverse; a grid step takes four chunks of
 one head, a head a block of 128 columns of the (B, T, H x 128) inputs, and no
-step depends on another. ``kda_prepare_bwd`` makes the chunk's parts again and
-takes the operands' cotangents back to q, k, v, g and beta by hand (nothing of
-the forward is kept but its inputs). ``kda_chunk_fwd`` and ``kda_chunk_bwd``
+step depends on another. It also writes the inverse ``T`` itself (float32, 16
+KB a chunk of 64: 33.5 MB for 8 heads over 16,384 positions), which is most of
+the chunk's work and which only the backward reads. ``kda_prepare_bwd`` takes
+``T`` from there, makes the chunk's other parts again (the running sums, the
+pair products, the masks and exponentials: one-pass products and VPU work)
+and takes the operands' cotangents back to q, k, v, g and beta by hand: of
+the forward it keeps its inputs and ``T``. ``kda_chunk_fwd`` and ``kda_chunk_bwd``
 run the scan, a chunk a grid step with the state (forward) or its gradient
 (backward, the chunks in reverse) carried in VMEM. The forward writes the
 state that enters each chunk (float32, ``B H (T / C) Dv Dk``: 537 MB at 16,384
@@ -47,11 +51,14 @@ that document began before the chunk. The running sum ``G`` runs through the
 starts (every ``g`` is finite), and only differences inside one document are
 ever used.
 
-**Arithmetic.** ``g``, its running sums (one triangular product at
-``highest``), the exponentials, ``T`` (a block-recursive inverse of the unit
-lower-triangular ``I + A``: six levels of two float32 products at
-``highest``, which reorders forward substitution and does not square ``A``),
-its gradient ``-T^T dT T^T`` and the state are float32. Every other matrix
+**Arithmetic.** ``g``, its running sums (one triangular product in float32:
+the triangle of 0 and 1 against ``g`` in three bfloat16 parts, which is what a
+product at ``highest`` sums once the terms that multiply zeros are left out),
+the exponentials, ``T`` (a block-recursive inverse of the unit lower-triangular
+``I + A``: the blocks of 2 are ``I - A`` there, then five levels of two float32
+products at ``highest`` at a chunk of 64, which reorders forward substitution
+and does not square ``A``), its gradient ``-T^T dT T^T`` and the state are
+float32, and ``T`` crosses HBM as float32. Every other matrix
 product takes bfloat16 operands and sums in float32: ``k e^{G - G_m}`` and
 ``k e^{G_m - G}`` into ``A`` and ``P`` (``G_m`` the running sum at the
 chunk's middle, so that a pair's two factors stay in range: the form is exact
@@ -107,30 +114,50 @@ _NN, _TN, _NT = ((1,), (0,)), ((0,), (0,)), ((1,), (1,))  # a b, a^T b, a b^T
 def unit_lower_inverse(a):
     """``(I + a)^-1`` in float32 for strictly lower-triangular ``a`` (C, C), C
     a power of two: the inverses of the diagonal blocks of size s give those
-    of size 2s, ``X - X L X`` with ``L`` the blocks of ``a`` under them."""
+    of size 2s, ``X - X L X`` with ``L`` the blocks of ``a`` under them. The
+    blocks of size 1 are the identity, so those of size 2 are ``I - L`` and
+    cost no product."""
     c = a.shape[-1]
     row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    x = (row == col).astype(a.dtype)
-    s = 1
+    under = lambda s: (row // (2 * s) == col // (2 * s)) & (row // s > col // s)
+    x = (row == col).astype(a.dtype) - jnp.where(under(1), a, 0.0)
+    s = 2
     while s < c:
-        under = (row // (2 * s) == col // (2 * s)) & (row // s > col // s)
-        x = x - _fdot(_fdot(x, jnp.where(under, a, 0.0), _NN), x, _NN)
+        x = x - _fdot(_fdot(x, jnp.where(under(s), a, 0.0), _NN), x, _NN)
         s *= 2
     return x
 
 
+def running_sums(x, reverse=False):
+    """The sums of ``x`` (C, D) float32 over the rows up to and with each row
+    (from each row on if ``reverse``), as a triangular product in float32: the
+    triangle is 0 and 1, exact in bfloat16, so of the six passes of a product
+    at ``highest`` the three that take its two lower parts multiply zeros.
+    ``x`` goes in as three bfloat16 parts (24 bits), a pass each."""
+    c = x.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    upto = (row >= col).astype(jnp.bfloat16)
+    dims = _TN if reverse else _NN
+    high = x.astype(jnp.bfloat16)
+    rest = x - high.astype(jnp.float32)
+    middle = rest.astype(jnp.bfloat16)
+    low = (rest - middle.astype(jnp.float32)).astype(jnp.bfloat16)
+    return _dot(upto, low, dims) + _dot(upto, middle, dims) + _dot(upto, high, dims)
+
+
 # ---- a chunk's operands (``kda_prepare_fwd``, ``kda_prepare_bwd``): no state, every chunk alone
 
-def _chunk_parts(q, k, g, beta, lo_col, lo_row, first):
+def _chunk_parts(q, k, g, beta, lo_col, lo_row, first, t_inv=None):
     """What both kernels make of a chunk on the way to its operands: q, k, g
     (C, Dk) float32, ``beta`` and ``lo_col`` (C, 1), ``lo_row`` (1, C),
-    ``first`` the chunk's first position."""
+    ``first`` the chunk's first position; ``t_inv`` the chunk's inverse where
+    the caller has it (the backward: the forward wrote it), else made here."""
     c = q.shape[0]
     row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    # the running sums as one triangular product (float32 at highest)
-    run = _fdot((row >= col).astype(jnp.float32), g, _NN)
+    run = running_sums(g)
     middle = max(c // 2 - 1, 0)
     mid, end = run[middle:middle + 1], run[c - 1:c]
     rise, fall = run - mid, mid - run
@@ -145,7 +172,8 @@ def _chunk_parts(q, k, g, beta, lo_col, lo_row, first):
     pairs = jnp.where(below, _rdot(k_up, k_down, _NT), 0.0)
     return dict(row=row, col=col, middle=middle, up=up, down=down, rising=rise < _EXP_CAP,
                 falling=fall < _EXP_CAP, k_up=k_up, k_down=k_down, q_up=q * up, pairs=pairs, below=below,
-                upto=same & (row >= col), t_inv=unit_lower_inverse(pairs * beta), decay=jnp.exp(run) * carried,
+                upto=same & (row >= col), t_inv=unit_lower_inverse(pairs * beta) if t_inv is None else t_inv,
+                decay=jnp.exp(run) * carried,
                 leave=jnp.exp(end - run) * tail, gamma=jnp.exp(end) * carried[c - 1:c])
 
 
@@ -165,10 +193,11 @@ def _chunk_inputs(refs, s, chunk, sub):
 
 
 def _prepare_fwd_kernel(*refs, chunk, sub):
-    w_ref, u0_ref, qin_ref, p_ref, ke_ref, gamma_ref = refs[7:]
+    w_ref, u0_ref, qin_ref, p_ref, ke_ref, gamma_ref, t_ref = refs[7:]
     for s in range(sub):
         q, k, v, g, beta, lo_col, lo_row, first = _chunk_inputs(refs[:7], s, chunk, sub)
         x = _chunk_parts(q, k, g, beta, lo_col, lo_row, first)
+        t_ref[0, 0, s] = x["t_inv"]
         w_ref[0, 0, s] = _rdot(x["t_inv"], beta * k * x["decay"], _NN).astype(w_ref.dtype)
         u0_ref[0, 0, s] = _rdot(x["t_inv"], beta * v, _NN)
         qin_ref[0, 0, s] = (q * x["decay"]).astype(qin_ref.dtype)
@@ -178,16 +207,17 @@ def _prepare_fwd_kernel(*refs, chunk, sub):
 
 
 def _prepare_bwd_kernel(*refs, chunk, sub):
-    """The chunk's parts made again, then the operands' cotangents back to q,
-    k, v, g and beta. The roundings pass gradients straight through; a product
-    the forward made on bfloat16 operands is transposed on bfloat16 operands;
-    the inverse's, ``dA = -T^T dT T^T`` under the diagonal, is float32."""
-    dw_ref, du0_ref, dqin_ref, dp_ref, dke_ref, dgamma_ref = refs[7:13]
-    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref = refs[13:]
+    """The chunk's parts made again but for the inverse, which the forward
+    kept, then the operands' cotangents back to q, k, v, g and beta. The
+    roundings pass gradients straight through; a product the forward made on
+    bfloat16 operands is transposed on bfloat16 operands; the inverse's, ``dA
+    = -T^T dT T^T`` under the diagonal, is float32."""
+    t_ref, dw_ref, du0_ref, dqin_ref, dp_ref, dke_ref, dgamma_ref = refs[7:14]
+    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref = refs[14:]
     f32 = lambda ref, s: ref[0, 0, s].astype(jnp.float32)
     for s in range(sub):
         q, k, v, g, beta, lo_col, lo_row, first = _chunk_inputs(refs[:7], s, chunk, sub)
-        x = _chunk_parts(q, k, g, beta, lo_col, lo_row, first)
+        x = _chunk_parts(q, k, g, beta, lo_col, lo_row, first, t_inv=t_ref[0, 0, s])
         row, col, t_inv, decay, up, down = x["row"], x["col"], x["t_inv"], x["decay"], x["up"], x["down"]
         dw, du0, dqin, dp, dke = (f32(r, s) for r in (dw_ref, du0_ref, dqin_ref, dp_ref, dke_ref))
         rows = slice(s * chunk, (s + 1) * chunk)
@@ -216,7 +246,7 @@ def _prepare_bwd_kernel(*refs, chunk, sub):
         at = row[:, :1]
         drun = (drun - jnp.where(at == x["middle"], jnp.sum(about, axis=0, keepdims=True), 0.0)
                 + jnp.where(at == chunk - 1, dend, 0.0))
-        dg_ref[0, rows, :] = _fdot((row >= col).astype(jnp.float32), drun, _TN)
+        dg_ref[0, rows, :] = running_sums(drun, reverse=True)
 
 
 def _sub_chunks(n):
@@ -250,31 +280,40 @@ def _prepare_call(kernel, name, q, k, v, g, beta, lo, chunk, by_chunk_ins, out_s
     )(*ins, beta.astype(jnp.float32), lo[..., None], lo[:, :, None, :], *by_chunk_ins)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _prepare(q, k, v, g, beta, lo, chunk, interpret):
+def _prepare_fwd(q, k, v, g, beta, lo, chunk, interpret):
     """The chunk's operands for the scan, head-major: ``W``, ``Q+``, ``Ke``
     (B, H, N, C, Dk) and ``P`` (B, H, N, C, C) bfloat16; ``U0`` (B, H, N, C,
-    Dv) and ``gamma`` (B, H, N, 1, Dk) float32."""
+    Dv) and ``gamma`` (B, H, N, 1, Dk) float32; and the chunk's inverse ``T``
+    (B, H, N, C, C) float32, which the scan does not take and the backward
+    does. One kernel writes all seven whoever calls: a call that is not
+    differentiated throws ``T`` away."""
     b, t, h, dk = q.shape
     n, dv = t // chunk, v.shape[-1]
     shape = lambda *last, dtype=jnp.bfloat16: jax.ShapeDtypeStruct((b, h, n) + last, dtype)
     outs = [shape(chunk, dk), shape(chunk, dv, dtype=jnp.float32), shape(chunk, dk), shape(chunk, chunk),
-            shape(chunk, dk), shape(1, dk, dtype=jnp.float32)]
-    return tuple(_prepare_call(_prepare_fwd_kernel, "kda_prepare_fwd", q, k, v, g, beta, lo, chunk, [], outs,
-                               interpret))
+            shape(chunk, dk), shape(1, dk, dtype=jnp.float32), shape(chunk, chunk, dtype=jnp.float32)]
+    *operands, t_inv = _prepare_call(_prepare_fwd_kernel, "kda_prepare_fwd", q, k, v, g, beta, lo, chunk, [], outs,
+                                     interpret)
+    return tuple(operands), t_inv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _prepare(q, k, v, g, beta, lo, chunk, interpret):
+    return _prepare_fwd(q, k, v, g, beta, lo, chunk, interpret)[0]
 
 
 def _prepare_vjp_fwd(q, k, v, g, beta, lo, chunk, interpret):
-    return _prepare(q, k, v, g, beta, lo, chunk, interpret), (q, k, v, g, beta, lo)
+    operands, t_inv = _prepare_fwd(q, k, v, g, beta, lo, chunk, interpret)
+    return operands, (q, k, v, g, beta, lo, t_inv)
 
 
 def _prepare_vjp_bwd(chunk, interpret, res, cts):
-    q, k, v, g, beta, lo = res
+    q, k, v, g, beta, lo, t_inv = res
     b, t, h, dk = q.shape
     flat = lambda x: jax.ShapeDtypeStruct((b, t, h * x.shape[-1]), jnp.float32)
     outs = [flat(q), flat(k), flat(v), flat(g), jax.ShapeDtypeStruct((b, h, t // chunk, 1, chunk), jnp.float32)]
     dq, dk_, dv, dg, dbeta = _prepare_call(_prepare_bwd_kernel, "kda_prepare_bwd", q, k, v, g, beta, lo, chunk,
-                                           list(cts), outs, interpret)
+                                           [t_inv, *cts], outs, interpret)
     dbeta = jnp.moveaxis(dbeta.reshape(b, h, t), 1, 2)
     return dq.reshape(q.shape), dk_.reshape(k.shape), dv.reshape(v.shape), dg.reshape(g.shape), dbeta, None
 

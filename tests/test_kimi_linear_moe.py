@@ -29,7 +29,9 @@ from persia_tpu.data import IDTypeFeature, Label, PersiaBatch, document_starts  
 from persia_tpu.embedding.optim import Adagrad  # noqa: E402
 from persia_tpu.models import KimiLinearMoE  # noqa: E402
 from persia_tpu.models.kimi_linear_moe import short_convolution  # noqa: E402
-from persia_tpu.ops.delta_rule import kda, kda_recurrence, log_decay_floor, unit_lower_inverse  # noqa: E402
+from persia_tpu.ops.delta_rule import (  # noqa: E402
+    _chunk_parts, _prepare_fwd, kda, kda_recurrence, log_decay_floor, running_sums, unit_lower_inverse,
+)
 from persia_tpu.ops.flash_attention import interval_attention, interval_tile_counts  # noqa: E402
 from persia_tpu.parallel.fused_ctx import FusedTrainCtx  # noqa: E402
 from persia_tpu.parallel.fused_step import (  # noqa: E402
@@ -165,7 +167,8 @@ def test_tower_against_the_reference(one_step, what):
         said = s["paths"][-1]
         assert said["kda"] == "pallas_chunk_scan" and said["kda_chunk"] == "16"
         assert said["latent_attention"] == "pallas_interval_two_products" and said["tile"] == "16"
-        assert said["experts"] == "pallas_grouped" and said["kda_backward_keeps"] == "chunk_states_float32"
+        assert said["experts"] == "pallas_grouped"
+        assert said["kda_backward_keeps"] == "chunk_states_float32+chunk_inverse_float32"
     else:  # the tower's leaves are the weights file's, shape for shape
         shapes = jax.tree.map(lambda x: x.shape, s["state"].params)
         want = jax.tree.map(lambda s: tuple(s), s["model"].param_shapes(),
@@ -297,16 +300,56 @@ def test_delta_rule_survives_the_strongest_decay(decay):
         assert np.isfinite(np.asarray(a)).all() and (name == "g" or _gap(a, b) < 3e-2), name
 
 
-def test_unit_lower_inverse():
+@pytest.mark.parametrize("what", ["value", "gradient"])
+@pytest.mark.parametrize("c", [2, 4, 16, 64])
+def test_unit_lower_inverse(c, what):
+    """Against ``jnp.linalg.inv``; at 2 the product-free first level is the
+    only level. Entries of deviation 1 up to 16 and 0.25 at 64, where the
+    inverse of a random triangle of deviation 1 has entries of 1e8 (a
+    chunk's own are ``beta k_i . k_j`` with a decay, under 1)."""
     rng = np.random.default_rng(1)
-    a = jnp.asarray(np.tril(rng.standard_normal((3, 16, 16)), -1), jnp.float32)
+    a = jnp.asarray(np.tril(rng.standard_normal((3, c, c)), -1) * min(1.0, 16 / c), jnp.float32)
     inverse = jax.vmap(unit_lower_inverse)  # the kernels' own, a chunk's (C, C) at a time
-    t = inverse(a)
-    np.testing.assert_allclose(np.asarray(t @ (jnp.eye(16) + a)), np.broadcast_to(np.eye(16), a.shape), atol=2e-4)
-    ct = jnp.asarray(rng.standard_normal(a.shape), jnp.float32)
-    mine = jax.grad(lambda a: jnp.sum(inverse(a) * ct))(a)
-    theirs = jax.grad(lambda a: jnp.sum(jnp.linalg.inv(jnp.eye(16) + jnp.tril(a, -1)) * ct))(a)
-    assert _gap(mine, theirs) < 1e-3
+    if what == "value":
+        t = inverse(a)
+        np.testing.assert_allclose(np.asarray(t @ (jnp.eye(c) + a)), np.broadcast_to(np.eye(c), a.shape), atol=2e-4)
+        if c == 2:
+            np.testing.assert_array_equal(np.asarray(t), np.asarray(jnp.eye(2) - a))
+    else:
+        ct = jnp.asarray(rng.standard_normal(a.shape), jnp.float32)
+        mine = jax.grad(lambda a: jnp.sum(inverse(a) * ct))(a)
+        theirs = jax.grad(lambda a: jnp.sum(jnp.linalg.inv(jnp.eye(c) + jnp.tril(a, -1)) * ct))(a)
+        assert _gap(mine, theirs) < 1e-3
+
+
+@pytest.mark.parametrize("reverse", [False, True])
+@pytest.mark.parametrize("c", [16, 64])
+def test_running_sums_in_three_passes(c, reverse):
+    """The triangle against three bfloat16 parts of ``g`` is a float32 sum:
+    against a float64 ``cumsum`` over the decays' whole range."""
+    g = -np.random.default_rng(c).uniform(1e-3, 2.5, (c, 128)).astype(np.float32)
+    want = np.cumsum(g[::-1].astype(np.float64), axis=0)[::-1] if reverse else np.cumsum(g.astype(np.float64), axis=0)
+    np.testing.assert_allclose(np.asarray(running_sums(jnp.asarray(g), reverse=reverse)), want, rtol=1e-6)
+
+
+def test_the_forward_kernel_writes_the_inverse_the_backward_reads():
+    """``T`` of ``kda_prepare_fwd`` is ``unit_lower_inverse(pairs * beta)``
+    made outside it, chunk by chunk, with two documents starting inside a
+    chunk; and it is float32."""
+    q, k, v, g, beta = _kda_inputs()
+    lo, chunk = jnp.asarray(KDA_CASES["two_in_one_chunk"]), 16
+    operands, t_inv = _prepare_fwd(q, k, v, g, beta, lo, chunk, True)
+    assert t_inv.shape == (1, 2, LENGTH // chunk, chunk, chunk) and t_inv.dtype == jnp.float32
+    assert len(operands) == 6 and operands[3].shape == t_inv.shape  # P beside it
+    for head in range(2):
+        for n in range(LENGTH // chunk):
+            rows = slice(n * chunk, (n + 1) * chunk)
+            b, l = beta[0, rows, head, None], lo[0, rows]
+            parts = _chunk_parts(q[0, rows, head], k[0, rows, head], g[0, rows, head], b, l[:, None], l[None, :],
+                                 n * chunk, t_inv=0.0)  # everything but the inverse
+            want = unit_lower_inverse(parts["pairs"] * b)
+            np.testing.assert_allclose(np.asarray(t_inv[0, head, n]), np.asarray(want), rtol=1e-6, atol=1e-7)
+    assert np.abs(np.asarray(t_inv) - np.eye(chunk)).max() > 1e-2  # and not the identity
 
 
 @pytest.mark.parametrize("case", ["inside", "at_the_start", "shorter_than_the_taps"])
