@@ -577,9 +577,11 @@ def phase_kernel(
 def phase_sequence_kernels(length: int = 2048, heads: int = 4, chunk: int = 64, tile: int = 512,
                            interpret: bool = False) -> Dict:
     """The chunked delta rule (``ops.delta_rule.kda``, its four kernels) against the
-    plain recurrence, and interval attention at widths 192/128 (``k_shared``)
-    against dense softmax, over packed documents whose starts fall inside
-    chunks and tiles; forward and every gradient, as relative gaps."""
+    plain recurrence, interval attention at widths 192/128 (``k_shared``)
+    against dense softmax, and a whole latent-attention layer with a low-rank
+    query and rotated columns (``models.moe_tower.latent_attention``) against
+    the same written out in float32, over packed documents whose starts fall
+    inside chunks and tiles; forward and every gradient, as relative gaps."""
     import jax
     import jax.numpy as jnp
 
@@ -623,7 +625,46 @@ def phase_sequence_kernels(length: int = 2048, heads: int = 4, chunk: int = 64, 
                           jnp.asarray(normal(1, length, 64)), jnp.asarray(normal(*shape))])
     say(f"  interval attention 192/128 L={length} H={heads}: " + " ".join(f"{k}={v:.1e}" for k, v in latent.items()))
     assert all(np.isfinite(v) and v < 2e-2 for v in latent.values()), latent
-    return {"delta_rule": delta, "latent_attention": latent, "interpret": interpret}
+
+    # the layer both latent towers call, as the joyai_llm_flash family states it: q through wq_a, a norm
+    # and wq_b, the last 64 columns of every head's query and the shared key columns rotated by the
+    # position inside the document (interleaved pairs, theta 32e6)
+    from persia_tpu.models.joyai_flash_moe import rope_tables
+    from persia_tpu.models.moe_tower import latent_attention
+
+    d, q_rank, rank = 256, 192, 128
+    names = ("wq_a", "q_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo")
+    shapes = ((d, q_rank), (q_rank,), (q_rank, heads * 192), (d, rank + 64), (rank,), (rank, heads * 256), (heads * 128, d))
+    leaves = [jnp.asarray(1.0 + 0.1 * normal(*s) if len(s) == 1 else normal(*s) / np.sqrt(s[0])) for s in shapes]
+    pos = (jnp.arange(length, dtype=jnp.int32)[None, :] - lo).astype(jnp.float32)
+    angle = pos[:, :, None] * jnp.asarray(np.repeat(32e6 ** (-np.arange(32) / 32.0), 2).astype(np.float32))
+
+    def layer(a, *leaves):
+        return latent_attention(dict(zip(names, leaves)), a, lo, n_heads=heads, head_dim=128, rope_head_dim=64,
+                                kv_lora_rank=rank, eps=1e-6, tile=tile, interpret=interpret,
+                                rope=rope_tables(lo, 64, 32e6))
+
+    def written_out(a, wq_a, q_norm, wq_b, wkv_a, kv_norm, wkv_b, wo):
+        hi = lambda x, w: jnp.dot(x, w, precision="highest")
+        rms = lambda x, w: x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + 1e-6) * w
+
+        def turn(x, angle):  # (y_2m, y_2m+1) = (x_2m c - x_2m+1 s, x_2m s + x_2m+1 c)
+            even, odd, c, s = x[..., 0::2], x[..., 1::2], jnp.cos(angle[..., 0::2]), jnp.sin(angle[..., 0::2])
+            return jnp.stack([even * c - odd * s, even * s + odd * c], axis=-1).reshape(x.shape)
+
+        q = hi(rms(hi(a, wq_a), q_norm), wq_b).reshape(1, length, heads, 192)
+        kv_a = hi(a, wkv_a)
+        kv = hi(rms(kv_a[..., :rank], kv_norm), wkv_b).reshape(1, length, heads, 256)
+        q = jnp.concatenate([q[..., :128], turn(q[..., 128:], angle[:, :, None, :])], axis=-1)
+        o = dense(q, kv[..., :128], turn(kv_a[..., rank:], angle), kv[..., 128:])
+        return hi(o.reshape(1, length, heads * 128), wo)
+
+    rotated = gaps(layer, written_out, [jnp.asarray(normal(1, length, d))] + leaves)
+    say(f"  rotated latent attention (low-rank q) L={length} H={heads}: "
+        + " ".join(f"{k}={v:.1e}" for k, v in rotated.items()))
+    assert all(np.isfinite(v) and v < 3e-2 for v in rotated.values()), rotated  # bfloat16 operands, four products deep
+    return {"delta_rule": delta, "latent_attention": latent, "rotated_latent_attention": rotated,
+            "interpret": interpret}
 
 
 # -------------------------------------------------------------- multichip
@@ -737,7 +778,8 @@ def main() -> None:
     run("pinned (FusedTrainCtx)", phase_pinned, shape, steps=12)
     say("kernel (flash_attention vs reference_attention, compiled):")
     run("kernel", phase_kernel)
-    say("sequence kernels (delta rule vs recurrence, interval attention 192/128 vs dense, compiled):")
+    say("sequence kernels (delta rule vs recurrence, interval attention 192/128 vs dense, "
+        "rotated latent attention vs written out, compiled):")
     run("sequence kernels", phase_sequence_kernels)
 
     if device["count"] >= 4:

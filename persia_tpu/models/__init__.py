@@ -10,7 +10,7 @@ array; raw (sequence) slots contribute a ``(gathered, mask)`` pair with
 ``nn.Sigmoid`` into ``forward``, e.g.
 `/root/reference/examples/src/adult-income/model.py:40`).
 
-Three towers are no click models: mixture-of-experts sequence models over one
+Four towers are no click models: mixture-of-experts sequence models over one
 raw slot of token rows that state their own loss and outputs, on one shared
 tower (``models/moe_tower.py``: norms, grouped-query attention up to its
 kernel, the expert layer that is told which experts it holds, the scan over
@@ -24,7 +24,14 @@ and loss in chunks of positions (``train_loss``). ``KimiLinearMoE``
 (``models/kimi_linear_moe.py``) trains the same objective on the same batches
 with gated delta-rule layers (a state a head, ``ops/delta_rule.py``) and
 latent-attention layers (scores 192 wide, values 128), a leading dense layer,
-a shared expert beside sigmoid-routed ones.
+a shared expert beside sigmoid-routed ones. ``JoyAIFlashMoE``
+(``models/joyai_flash_moe.py``) has that latent attention in every layer
+(``moe_tower.latent_attention``, shared with the ``kimi_linear`` family) with
+a low-rank query and a rotation of the query's last and the shared key's
+columns by the position inside the document, and trains a second objective:
+a multi-token-prediction module after the scan reads the tower's normed
+stream beside the next token's row (the gathered slot shifted by a position)
+and goes through the tower's head a second time (``NextTokenTower.objectives``).
 """
 
 from persia_tpu.models.dnn import DNN  # noqa: F401
@@ -35,3 +42,4 @@ from persia_tpu.models.din import DIN  # noqa: F401
 from persia_tpu.models.sdar_moe import SDARMoE  # noqa: F401
 from persia_tpu.models.mellum_moe import MellumMoE  # noqa: F401
 from persia_tpu.models.kimi_linear_moe import KimiLinearMoE  # noqa: F401
+from persia_tpu.models.joyai_flash_moe import JoyAIFlashMoE  # noqa: F401

@@ -4,6 +4,7 @@ Delta Attention (KDA: a short convolution on q, k and v, a decay a channel, a
 128 x 128 state a head and no keys; ``ops/delta_rule.py``), the fourth is
 multi-head latent attention without positions (MLA, NoPE: scores 192 wide, a
 head's own 128 key columns beside 64 that all heads share, values 128 wide;
+``moe_tower.latent_attention``, which the ``joyai_llm_flash`` family shares, over
 ``ops/flash_attention.py::interval_attention`` with ``k_shared``). A leading
 KDA layer has a dense SwiGLU; every later layer a shared expert beside
 sigmoid-routed ones. The tower itself (the scan, the expert layer that is told
@@ -33,10 +34,10 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from persia_tpu.models.moe_tower import NextTokenTower, _mm, _rms
+from persia_tpu.models.moe_tower import NextTokenTower, _mm, _rms, latent_attention
 from persia_tpu.ops.delta_rule import KDA_CHUNK, kda, kda_chunk, log_decay_floor
 from persia_tpu.ops.flash_attention import (
-    ATTENTION_OUT, BLOCK_DIFFUSION_TILE, interval_attention, interval_tile_counts, interval_visits,
+    ATTENTION_OUT, BLOCK_DIFFUSION_TILE, interval_tile_counts, interval_visits,
 )
 from persia_tpu.tracing import record_event
 
@@ -86,6 +87,11 @@ class KimiLinearMoE(NextTokenTower):
     leading_kinds: Tuple[str, ...] = (KDA,)
     mlp: str = "shared_experts"
     router_law: str = "sigmoid"
+    # a chunk of picks holds twice what an even router sends the held experts (8,192 picks for 16,384
+    # tokens), where the tower's own is an eighth over it: over 32 shares a layer's load on one share
+    # follows which token ids took it and reads 0.65-1.95 of even by the seed, and a second trip of
+    # the loop over chunks costs more than the picks in it (``PERF.md`` section 7 row 5)
+    pick_room: float = 2.0
     head_chunk: int = 2048
     tile: int = BLOCK_DIFFUSION_TILE  # the attention kernels' (the CPU tests cut a short sequence)
     kda_chunk: int = KDA_CHUNK  # the delta rule's scan
@@ -157,17 +163,6 @@ class KimiLinearMoE(NextTokenTower):
                     router_bias={kind: jnp.zeros((count, self.n_experts), jnp.float32)
                                  for kind, count in self._kind_counts().items()})
 
-    def pick_chunk(self, n_tokens: int) -> int:
-        """Twice what an even router sends the held experts, in whole tiles of
-        512 rows (8,192 picks for 16,384 tokens), where the tower's own is an
-        eighth over it: over 32 shares a layer's load on one share follows
-        which token ids took it and reads 0.65-1.95 of even by the seed, and
-        a second trip of the loop over chunks costs more than the picks in it
-        (``PERF.md`` section 7 row 5)."""
-        picks = n_tokens * self.experts_per_token
-        even = -(-picks * self.n_held // self.n_experts)
-        return min(picks, -(-2 * even // 512) * 512)
-
     # ------------------------------------------------------------- attention
 
     def attention(self, kind, p, a, side, attend):
@@ -210,16 +205,9 @@ class KimiLinearMoE(NextTokenTower):
         return _mm(checkpoint_name(y, ATTENTION_OUT), p["wo"])
 
     def _mla(self, p, a, starts):
-        b, t, _ = a.shape
-        h, hd, r = self.n_heads, self.head_dim, self.rope_head_dim
-        q = _mm(a, p["wq"]).astype(jnp.bfloat16).reshape(b, t, h, hd + r)
-        kv_a = _mm(a, p["wkv_a"])
-        latent = _rms(kv_a[..., :self.kv_lora_rank], p["kv_norm"], self.rms_eps)
-        shared = kv_a[..., self.kv_lora_rank:].astype(jnp.bfloat16)
-        kv = _mm(latent, p["wkv_b"]).astype(jnp.bfloat16).reshape(b, t, h, 2 * hd)
-        o = interval_attention(q, kv[..., :hd], kv[..., hd:], starts, tile=self.tile,
-                               interpret=self.interpret, k_shared=shared)
-        return _mm(o.reshape(b, t, h * hd), p["wo"])
+        return latent_attention(p, a, starts, n_heads=self.n_heads, head_dim=self.head_dim,
+                                rope_head_dim=self.rope_head_dim, kv_lora_rank=self.kv_lora_rank,
+                                eps=self.rms_eps, tile=self.tile, interpret=self.interpret)
 
     # --------------------------------------------------------------- forward
 

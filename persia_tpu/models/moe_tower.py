@@ -10,9 +10,13 @@ diffusion) and ``models/mellum_moe.py`` (causal, window and full layers over
 packed documents) keep the tower's own attention (grouped-query softmax
 attention with RoPE and per-head q/k norms) and state a RoPE table a kind,
 which attention kernel runs, which positions have logits and the loss;
-``models/kimi_linear_moe.py`` brings two attentions of its own (the gated
-delta rule, latent attention), a leading dense layer, the shared expert and
-the sigmoid law.
+``models/kimi_linear_moe.py`` brings the gated delta rule beside latent
+attention, a leading dense layer, the shared expert and the sigmoid law;
+``models/joyai_flash_moe.py`` has latent attention in every layer, with a
+low-rank query and rotated columns, and a prediction module after the scan
+(a block with leaves of its own: ``after_kinds``) whose objective is a second
+pass through the head. Latent attention is one function here for both
+(``latent_attention``).
 
 **The expert layer is told which experts it holds** (``first_held``,
 ``n_held``): it routes over all ``n_experts`` with the published
@@ -44,7 +48,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from persia_tpu.ops.flash_attention import ATTENTION_LSE, ATTENTION_OUT
+from persia_tpu.ops.flash_attention import ATTENTION_LSE, ATTENTION_OUT, interval_attention
 from persia_tpu.ops.grouped_matmul import grouped_matmul, grouped_outer, grouped_tiles
 from persia_tpu.ops.qk_prep import qk_norm_rope
 
@@ -181,6 +185,57 @@ def _swiglu_mlp(m, gate, up, down):
     return _mm(jax.nn.silu(_mm(m, gate)) * _mm(m, up), down)
 
 
+def rotate_pairs(x, cos, sin):
+    """RoPE on interleaved pairs, float32: columns ``2m`` and ``2m + 1`` of
+    ``x`` (..., R) turn by the angle whose ``cos`` and ``sin`` (..., R) hold at
+    both (``sin`` as it is: the sign is the pair's, here). A column's partner
+    comes by a shift of one lane either way; nothing is split or regrouped."""
+    even = (jnp.arange(x.shape[-1]) % 2 == 0)
+    partner = jnp.where(even, -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
+    return x * cos + partner * sin
+
+
+def latent_attention(p, a, starts, *, n_heads, head_dim, rope_head_dim, kv_lora_rank, eps, tile,
+                     interpret, rope=None):
+    """Multi-head latent attention over packed documents, for every tower that
+    holds it: what the layer adds to the residual stream for its normed input
+    ``a`` (B, T, hidden); query ``i`` reads the keys ``starts_i .. i``. A
+    head's score is ``head_dim + rope_head_dim`` wide: its own ``head_dim``
+    key columns (with its values, from the ``kv_lora_rank``-wide normed latent
+    through ``wkv_b``) beside ``rope_head_dim`` that all heads share (the last
+    columns of ``wkv_a``); values are ``head_dim`` wide. The query is full
+    rank (``wq``) or low rank (``wq_a``, the norm ``q_norm``, ``wq_b``) by the
+    leaves ``p`` holds. ``rope``: None (no positions: NoPE), or ``(cos, sin)``
+    (B, T, rope_head_dim) of each position's angle a column, by which the last
+    ``rope_head_dim`` columns of every head's query and the shared key columns
+    are rotated as interleaved pairs in float32, before the cast to bfloat16
+    the kernels take (``ops/flash_attention.py::interval_attention`` with
+    ``k_shared``)."""
+    b, t, _ = a.shape
+    h, hd, r = n_heads, head_dim, rope_head_dim
+    if "wq_a" in p:
+        q = _mm(_rms(_mm(a, p["wq_a"]), p["q_norm"], eps), p["wq_b"])
+    else:
+        q = _mm(a, p["wq"])
+    if rope is None:
+        q = q.astype(jnp.bfloat16)
+    q = q.reshape(b, t, h, hd + r)
+    kv_a = _mm(a, p["wkv_a"])
+    latent = _rms(kv_a[..., :kv_lora_rank], p["kv_norm"], eps)
+    shared = kv_a[..., kv_lora_rank:]
+    if rope is not None:
+        with jax.named_scope("rope"):
+            cos, sin = rope
+            turned = rotate_pairs(q[..., hd:], cos[:, :, None, :], sin[:, :, None, :])
+            q = jnp.concatenate([q[..., :hd], turned], axis=-1).astype(jnp.bfloat16)
+            shared = rotate_pairs(shared, cos, sin)
+    shared = shared.astype(jnp.bfloat16)
+    kv = _mm(latent, p["wkv_b"]).astype(jnp.bfloat16).reshape(b, t, h, 2 * hd)
+    o = interval_attention(q, kv[..., :hd], kv[..., hd:], starts, tile=tile, interpret=interpret,
+                           k_shared=shared)
+    return _mm(o.reshape(b, t, h * hd), p["wo"])
+
+
 class MoETower:
     """The tower's parameters, layers and scan, for a frozen dataclass that
     holds its sizes (``vocab``, ``n_layers``, ``hidden``, ``n_heads``,
@@ -200,6 +255,12 @@ class MoETower:
     - ``leading_kinds``: layers before the scan, each with leaves of its own
       under ``lead``, whose MLP is dense (a SwiGLU of ``dense_width``, no
       router).
+    - ``after_kinds``: blocks after the scan, each a whole layer of the
+      scanned layers' form with leaves of its own under ``after``, its own row
+      of ``expert_picks`` after the scanned layers' and its own selection
+      bias. The tower does not run them in ``layers``: a tower that states
+      one says what enters it (``layer_after``; a prediction module's merged
+      stream in ``models/joyai_flash_moe.py``).
     - ``mlp``: what follows a scanned layer's attention: ``"experts"`` (the
       held routed experts' part) or ``"shared_experts"`` (that and an expert
       every token takes, computed here whole).
@@ -211,8 +272,10 @@ class MoETower:
     layer_kinds: Tuple[str, ...] = ("attention",)
     kind_leaves: bool = False
     leading_kinds: Tuple[str, ...] = ()
+    after_kinds: Tuple[str, ...] = ()
     mlp: str = "experts"
     router_law: str = "softmax"
+    pick_room: float = 1.125
 
     # ------------------------------------------------------------ parameters
 
@@ -256,11 +319,14 @@ class MoETower:
         out = {"layers": layers, "norm_f": (self.hidden,), "head": (self.hidden, self.vocab)}
         if self.leading_kinds:
             out["lead"] = tuple(self.layer_shapes(kind, "dense") for kind in self.leading_kinds)
+        if self.after_kinds:
+            out["after"] = tuple(self.layer_shapes(kind, self.mlp) for kind in self.after_kinds)
         return out
 
     def counters(self) -> Dict[str, Any]:
-        """What the step counts on the device: the picks by expert layer and held expert."""
-        return {"expert_picks": jnp.zeros((self.n_scanned, self.n_held), jnp.int32)}
+        """What the step counts on the device: the picks by expert layer (the
+        scanned layers, then the blocks after the scan) and held expert."""
+        return {"expert_picks": jnp.zeros((self.n_scanned + len(self.after_kinds), self.n_held), jnp.int32)}
 
     def init(self, rng, dense, emb, train: bool = False) -> Dict[str, Any]:
         """Normal(0, 0.02) products, unit norms, and the step's counters."""
@@ -282,9 +348,9 @@ class MoETower:
         the kind's attention takes beside the leaves (the default's: its RoPE
         ``(cos, sin)``), ``attend(kind, q, k, v)`` the default attention's
         kernel over bfloat16 q (B, T, Hq, D), k and v (B, T, Hkv, D).
-        ``buffers``: leaves that are no parameters, ``{kind: {leaf: stacked}}``
-        as a ``kind_leaves`` tower's ``params["layers"]``, which a layer finds
-        beside its own.
+        ``buffers``: leaves that are no parameters, which a layer finds beside
+        its own: ``{kind: {leaf: stacked}}`` as a ``kind_leaves`` tower's
+        ``params["layers"]``, ``{leaf: stacked}`` where the kinds hold the same.
 
         The leading layers, then one scan over the periods, a period's layers
         written out in its body. Each layer is recomputed in the backward, but
@@ -294,22 +360,16 @@ class MoETower:
         kinds, lead = self.layer_kinds, self.leading_kinds
         if self.n_scanned % len(kinds):
             raise ValueError(f"{self.n_scanned} layers are no whole periods of {kinds}")
-        keep = jax.checkpoint_policies.save_only_these_names(ATTENTION_OUT, ATTENTION_LSE)
-        every = set(kinds) | set(lead)
-        scope = {k: "attention" if len(every) == 1 else f"attention/{k}" for k in every}
-
-        def make(kind, mlp):
-            return jax.checkpoint(partial(self._layer, kind=kind, mlp=mlp, side=side.get(kind),
-                                          scope=scope[kind], attend=attend and partial(attend, kind)),
-                                  policy=keep)
-
+        make = partial(self._recomputed_layer, side=side, attend=attend)
         h = rows.astype(jnp.float32)
         for kind, p in zip(lead, params.get("lead", ())):
             h, _ = make(kind, "dense")(p, h)
         layer = {k: make(k, self.mlp) for k in set(kinds)}
         stacked = params["layers"]
-        if buffers is not None:  # by kind, as the leaves of a tower whose kinds hold their own
+        if buffers is not None and self.kind_leaves:  # by kind, as the leaves of a tower whose kinds hold their own
             stacked = {kind: dict(leaves, **buffers.get(kind, {})) for kind, leaves in stacked.items()}
+        elif buffers is not None:
+            stacked = dict(stacked, **buffers)
 
         # a period of one layer is scanned as the stacked leaves lie: regrouping
         # them costs the SDAR cell 0.8% of its step in slices and updates of the
@@ -336,6 +396,22 @@ class MoETower:
                 lambda x: x.reshape(-1, len(kinds), *x.shape[1:]), stacked)
         h, picks = jax.lax.scan(period, h, by_period)
         return h, picks.reshape(self.n_scanned, self.n_held)
+
+    def _recomputed_layer(self, kind, mlp, side, attend=None):
+        """One layer ``(leaves, h) -> (h, picks)``, recomputed in the backward
+        but for its attention kernel's output and row statistics."""
+        every = set(self.layer_kinds) | set(self.leading_kinds) | set(self.after_kinds)
+        scope = "attention" if len(every) == 1 else f"attention/{kind}"
+        keep = jax.checkpoint_policies.save_only_these_names(ATTENTION_OUT, ATTENTION_LSE)
+        return jax.checkpoint(partial(self._layer, kind=kind, mlp=mlp, side=side.get(kind), scope=scope,
+                                      attend=attend and partial(attend, kind)), policy=keep)
+
+    def layer_after(self, i, params, h, side, attend=None, buffers=None):
+        """Block ``i`` of ``after_kinds`` on the stream ``h`` (B, T, hidden):
+        the stream after it and the picks its held experts got, (n_held,).
+        ``buffers``: its leaves that are no parameters."""
+        leaves = dict(params["after"][i], **(buffers or {}))
+        return self._recomputed_layer(self.after_kinds[i], self.mlp, side, attend)(leaves, h)
 
     def attention(self, kind, p, a, side, attend):
         """What a layer's attention adds to the residual stream for its normed
@@ -376,14 +452,15 @@ class MoETower:
         return h, picks
 
     def pick_chunk(self, n_tokens: int) -> int:
-        """Picks the expert layer handles at a time: an eighth over what an
-        even router sends the held experts of ``n_tokens`` tokens, in whole
-        tiles of 512 rows. The loop over chunks ends with the last live one,
-        so no token is dropped whatever the load and nothing larger than a
-        chunk's rows is held; near an even load one trip does."""
+        """Picks the expert layer handles at a time: ``pick_room`` times what
+        an even router sends the held experts of ``n_tokens`` tokens (an eighth
+        over it; a tower of many shares states more), in whole tiles of 512
+        rows. The loop over chunks ends with the last live one, so no token is
+        dropped whatever the load and nothing larger than a chunk's rows is
+        held; near an even load one trip does."""
         picks = n_tokens * self.experts_per_token
         even = -(-picks * self.n_held // self.n_experts)
-        return min(picks, -(-(even + even // 8) // 512) * 512)
+        return min(picks, -(-int(even * self.pick_room) // 512) * 512)
 
     def expert_paths(self, n_tokens: int) -> Dict[str, Any]:
         """What a tower's ``*.paths`` event says of the expert layer for
@@ -450,7 +527,9 @@ class NextTokenTower(MoETower):
     meet whole: ``train_loss`` (which ``build_fused_train_step`` takes where a
     model states one) runs them in chunks of ``head_chunk`` positions, each
     chunk's logits recomputed in the backward, and no ``(T, vocab)`` array is
-    ever live. ``models/mellum_moe.py`` and ``models/kimi_linear_moe.py``."""
+    ever live. A tower with further objectives over the same head states them
+    as further passes (``objectives``). ``models/mellum_moe.py``,
+    ``models/kimi_linear_moe.py`` and ``models/joyai_flash_moe.py``."""
 
     def apply(self, variables, dense, emb, train: bool = True, mutable: Optional[Sequence[str]] = None):
         """Logits of every position, (B, T, vocab) float32. ``dense`` is
@@ -475,29 +554,56 @@ class NextTokenTower(MoETower):
         """The most likely next id of each position, (B, T) int32."""
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    def train_loss(self, variables, dense, emb, labels):
-        """``(loss, outputs, counters)`` of a training step, the head and the
-        loss in chunks of ``head_chunk`` positions under one scan whose body is
-        recomputed in the backward: a chunk's logits and their gradient are
-        the largest arrays the head ever holds."""
+    def objectives(self, variables, dense, emb, labels):
+        """The passes through the head that make the training loss, and the
+        step's counters: each pass ``(scope, stream (B, T, hidden), norm
+        weight, targets (B, T), weights (B, T), coefficient)``; a stream that
+        is normed already comes with no norm weight. The loss is the sum over
+        the passes of coefficient x the weighted mean cross-entropy; the
+        outputs are the first pass's. Here: the next token, once."""
         h, stats = self._hidden(variables, dense, emb)
-        params = variables["params"]
-        b, t, d = h.shape
+        return [("lm_head", h, variables["params"]["norm_f"], labels[0], labels[1], 1.0)], stats
+
+    def train_loss(self, variables, dense, emb, labels):
+        """``(loss, outputs, counters)`` of a training step: every pass of
+        ``objectives`` through the one chunked head (``_head_pass``), the
+        head's gradient the passes' summed. A tower that counts ``objective``
+        gets the passes' weight sums, then their weighted cross-entropy sums,
+        added to it."""
+        passes, stats = self.objectives(variables, dense, emb, labels)
+        loss, outputs, lives, totals = None, None, [], []
+        for scope, h, norm, targets, weight, coefficient in passes:
+            weight = weight.astype(jnp.float32)
+            with jax.named_scope(scope):
+                total, ids = self._head_pass(variables["params"]["head"], h, norm, targets.astype(jnp.int32), weight)
+            live = jnp.sum(weight)
+            term = total / live if coefficient == 1.0 else coefficient * (total / live)  # no product by one in the one-pass towers' programs
+            loss = term if loss is None else loss + term
+            outputs = ids if outputs is None else outputs
+            lives.append(live)
+            totals.append(total)
+        if stats is not None and "objective" in stats:
+            stats = dict(stats, objective=stats["objective"] + jax.lax.stop_gradient(jnp.stack(lives + totals)))
+        return loss, outputs, stats
+
+    def _head_pass(self, head, h, norm, targets, weight):
+        """``(sum of weight x cross-entropy, most likely ids (B, T))`` of one
+        stream, in chunks of ``head_chunk`` positions under one scan whose body
+        is recomputed in the backward: a chunk's logits and their gradient are
+        the largest arrays the head ever holds."""
+        b, t, _ = h.shape
         chunk = min(self.head_chunk, b * t)
         if (b * t) % chunk:
             raise ValueError(f"{b * t} positions are no whole chunks of {chunk}")
         by_chunk = lambda x: x.reshape((b * t) // chunk, chunk, *x.shape[2:])
-        targets, weight = labels[0].astype(jnp.int32), labels[1].astype(jnp.float32)
 
         @jax.checkpoint
         def one(total, xs):
             hc, tc, wc = xs
-            logits = self.head(params, hc)
+            logits = _mm(hc if norm is None else _rms(hc, norm, self.rms_eps), head)
             picked = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
             total = total + jnp.sum(wc * (jax.nn.logsumexp(logits, axis=-1) - picked))
             return total, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-        with jax.named_scope("lm_head"):
-            total, ids = jax.lax.scan(one, jnp.zeros((), jnp.float32),
-                                      (by_chunk(h), by_chunk(targets), by_chunk(weight)))
-        return total / jnp.sum(weight), ids.reshape(b, t), stats
+        total, ids = jax.lax.scan(one, jnp.zeros((), jnp.float32), (by_chunk(h), by_chunk(targets), by_chunk(weight)))
+        return total, ids.reshape(b, t)

@@ -99,6 +99,9 @@ def test_sequence_kernels_phase_interpreted():
     assert set(k["delta_rule"]) == {"forward", "d0", "d1", "d2", "d3", "d4"}
     assert set(k["latent_attention"]) == {"forward", "d0", "d1", "d2", "d3"}
     assert max(k["latent_attention"].values()) < 1e-4  # float32 operands here: exact but for the sums' order
+    # the layer with a low-rank query and rotated columns: bfloat16 operands in its projections
+    assert set(k["rotated_latent_attention"]) == {"forward"} | {f"d{i}" for i in range(8)}
+    assert max(k["rotated_latent_attention"].values()) < 3e-2
 
 
 @pytest.mark.slow  # a second and third cached stream: ~7 s of CPU compiles

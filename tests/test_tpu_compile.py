@@ -377,3 +377,41 @@ def test_interval_attention_compiles_at_two_widths(one_chip, no_compile_cache, h
     assert [k for k in want if any(k in name for name in kernels)] == want, kernels
     assert "16384,8192]" not in text and "32,16384,256]" not in text  # no head padded to 256 lanes
     assert compiled.memory_analysis().temp_size_in_bytes < 1200 * 2**20
+
+
+# ---- the rotated latent-attention cell's layer (joyai-flash-ep32-pack16k-mtp1, ISSUE 42) ----
+
+@pytest.mark.parametrize("what", ["forward", "backward"])
+def test_rotated_latent_attention_compiles_at_the_cells_widths(one_chip, no_compile_cache, highest_by_default, what):
+    """One block's attention as the ``joyai_llm_flash`` family states it, at the
+    cell's shape: hidden 2,048, a query low rank of 1,536, 32 heads of 128 + 64,
+    one sequence of 16,384 positions, the rotation's tables made from ``lo`` in
+    the program: the three interval kernels at two widths, the rotation XLA's
+    with no array a pair wide (minor dimension 2) and nothing the size of the
+    scores."""
+    from persia_tpu.models.joyai_flash_moe import rope_tables
+    from persia_tpu.models.moe_tower import latent_attention
+
+    length, d, h = 16384, 2048, 32
+    shapes = {"wq_a": (d, 1536), "q_norm": (1536,), "wq_b": (1536, h * 192), "wkv_a": (d, 576), "kv_norm": (512,),
+              "wkv_b": (512, h * 256), "wo": (h * 128, d)}
+    p = {k: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip) for k, s in shapes.items()}
+    a = jax.ShapeDtypeStruct((1, length, d), jnp.float32, sharding=one_chip)
+    lo = jax.ShapeDtypeStruct((1, length), jnp.int32, sharding=one_chip)
+
+    def forward(p, a, lo):
+        return latent_attention(p, a, lo, n_heads=h, head_dim=128, rope_head_dim=64, kv_lora_rank=512, eps=1e-6,
+                                tile=512, interpret=False, rope=rope_tables(lo, 64, 32e6))
+
+    def backward(p, a, lo):
+        return jax.grad(lambda p, a: forward(p, a, lo).sum(), argnums=(0, 1))(p, a)
+
+    compiled = jax.jit(forward if what == "forward" else backward).lower(p, a, lo).compile()
+    text = compiled.as_text()
+    kernels = sorted(set(re.findall(r"interval_attention_\w+", text)))
+    want = ["interval_attention_fwd"] if what == "forward" else [
+        "interval_attention_dkv", "interval_attention_dq", "interval_attention_fwd"]
+    assert [k for k in want if any(k in name for name in kernels)] == want, kernels
+    assert not re.search(r"f32\[[\d,]*,2\]\{", text) and "16384,16384]" not in text
+    # q in float32 and bfloat16, its gradient, the latent's: 0.4 GB arrays, a handful of them
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
