@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from persia_tpu.models.mellum_moe import rope_frequencies
-from persia_tpu.models.moe_tower import NextTokenTower, _mm, _rms, latent_attention
+from persia_tpu.models.moe_tower import NextTokenTower, _mm, _rms, latent_attention, latent_attention_grid
 from persia_tpu.ops.flash_attention import BLOCK_DIFFUSION_TILE, interval_tile_counts, interval_visits
 from persia_tpu.tracing import record_event
 
@@ -206,6 +206,8 @@ class JoyAIFlashMoE(NextTokenTower):
                      rope_pairs="interleaved", mtp_depth=self.mtp_depth, mtp_embedding="shifted_slot",
                      head_passes=1 + self.mtp_depth, tile=tile, seq_len=t,
                      grid_mla=interval_visits(t // tile, tile, None), head_chunk=self.head_chunk,
+                     **latent_attention_grid(b, t, n_heads=self.n_heads, head_dim=self.head_dim,
+                                             rope_head_dim=self.rope_head_dim, tile=tile),
                      **self.expert_paths(b * t))
         stats = variables.get("batch_stats")
         side = self._side(starts)

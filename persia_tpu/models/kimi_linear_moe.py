@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from persia_tpu.models.moe_tower import NextTokenTower, _mm, _rms, latent_attention
+from persia_tpu.models.moe_tower import NextTokenTower, _mm, _rms, latent_attention, latent_attention_grid
 from persia_tpu.ops.delta_rule import KDA_CHUNK, kda, kda_chunk, log_decay_floor
 from persia_tpu.ops.flash_attention import (
     ATTENTION_OUT, BLOCK_DIFFUSION_TILE, interval_tile_counts, interval_visits,
@@ -223,6 +223,8 @@ class KimiLinearMoE(NextTokenTower):
                      convolution="xla", norms_and_gates="xla",
                      latent_attention="pallas_interval_two_products", tile=tile, seq_len=t,
                      grid_mla=interval_visits(t // tile, tile, None), head_chunk=self.head_chunk,
+                     **latent_attention_grid(b, t, n_heads=self.n_heads, head_dim=self.head_dim,
+                                             rope_head_dim=self.rope_head_dim, tile=tile),
                      **self.expert_paths(b * t))
         stats = variables.get("batch_stats")
         buffers = (stats or self.counters())["router_bias"]

@@ -31,7 +31,8 @@ import numpy as np
 
 from persia_tpu.models.moe_tower import NextTokenTower
 from persia_tpu.ops.flash_attention import (
-    BLOCK_DIFFUSION_TILE, interval_attention, interval_tile_counts, interval_visits,
+    BLOCK_DIFFUSION_TILE, attention_grid_steps, heads_a_step, interval_attention, interval_tile_counts,
+    interval_visits,
 )
 from persia_tpu.ops.qk_prep import qk_prep_tile
 from persia_tpu.tracing import record_event
@@ -135,10 +136,16 @@ class MellumMoE(NextTokenTower):
                                for f in (jnp.cos, jnp.sin))
         window = {SLIDING: self.sliding_window, FULL: None}
         tile = min(self.tile, t)
+        grid = (self.n_heads, self.n_kv_heads, self.head_dim, tile)
         record_event("mellum_moe.paths", attention="pallas_interval", **self.expert_paths(b * t),
                      seq_len=t, window=self.sliding_window, tile=tile, head_chunk=self.head_chunk,
                      qk_prep="pallas_rows", qk_prep_tile=qk_prep_tile(t),
-                     **{f"grid_{k}": interval_visits(t // tile, tile, w) for k, w in window.items()})
+                     **{f"grid_{k}": interval_visits(t // tile, tile, w) for k, w in window.items()},
+                     attention_heads_a_step=heads_a_step(*grid),
+                     # a call's grid steps on the full layer / on a sliding one
+                     attention_grid_steps="/".join(
+                         str(attention_grid_steps(b, *grid, interval_visits(t // tile, tile, window[k])))
+                         for k in (FULL, SLIDING)))
 
         def attend(kind, q, k, v):
             return interval_attention(q, k, v, starts, window=window[kind], tile=self.tile,

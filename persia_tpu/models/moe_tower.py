@@ -49,7 +49,9 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from persia_tpu.ops.flash_attention import ATTENTION_LSE, ATTENTION_OUT, interval_attention
+from persia_tpu.ops.flash_attention import (
+    ATTENTION_LSE, ATTENTION_OUT, attention_grid_steps, heads_a_step, interval_attention, interval_visits,
+)
 from persia_tpu.ops.grouped_matmul import grouped_matmul, grouped_outer, grouped_tiles
 from persia_tpu.ops.qk_prep import mla_rope, qk_norm_rope
 
@@ -233,6 +235,17 @@ def latent_attention(p, a, starts, *, n_heads, head_dim, rope_head_dim, kv_lora_
     o = interval_attention(q, kv[..., :hd], kv[..., hd:], starts, tile=tile, interpret=interpret,
                            k_shared=shared, q_shared=q_shared)
     return _mm(o.reshape(b, t, h * hd), p["wo"])
+
+
+def latent_attention_grid(batch, length, *, n_heads, head_dim, rope_head_dim, tile) -> Dict[str, int]:
+    """What a tower's ``*.paths`` event says of ``latent_attention``'s kernels
+    over ``batch`` sequences of ``length``: the query heads a grid step and the
+    grid steps of one kernel's call (a K/V head a query head, the
+    ``rope_head_dim`` key columns all share beside, the triangle's grid)."""
+    grid = dict(hq=n_heads, hkv=n_heads, d=head_dim, tile=tile, shared=rope_head_dim)
+    visits = interval_visits(length // tile, tile, None)
+    return {"attention_heads_a_step": heads_a_step(**grid),
+            "attention_grid_steps": attention_grid_steps(batch, visits=visits, **grid)}
 
 
 class MoETower:

@@ -22,7 +22,9 @@ import jax
 import jax.numpy as jnp
 
 from persia_tpu.models.moe_tower import MoETower
-from persia_tpu.ops.flash_attention import block_diffusion_attention, block_diffusion_plan
+from persia_tpu.ops.flash_attention import (
+    attention_grid_steps, block_diffusion_attention, block_diffusion_plan, heads_a_step,
+)
 from persia_tpu.ops.qk_prep import qk_prep_tile
 from persia_tpu.tracing import record_event
 
@@ -60,11 +62,15 @@ class SDARMoE(MoETower):
         cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)
         sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)
         # with what the attention kernels execute a head and sequence: visits by
-        # the tile's kind, sub-tiles executed of the visited, pairs beside the live
+        # the tile's kind, sub-tiles executed of the visited, pairs beside the live;
+        # and the query heads a grid step, and the grid steps of a kernel's call
+        plan = block_diffusion_plan(seq_len, self.block_len)
+        grid = (self.n_heads, self.n_kv_heads, self.head_dim, plan["tile"])
         record_event("sdar_moe.paths", attention="pallas_block_mask",
                      seq_len=seq_len, block_len=self.block_len, **self.expert_paths(b * t),
-                     qk_prep="pallas_rows", qk_prep_tile=qk_prep_tile(t),
-                     **block_diffusion_plan(seq_len, self.block_len))
+                     qk_prep="pallas_rows", qk_prep_tile=qk_prep_tile(t), **plan,
+                     attention_heads_a_step=heads_a_step(*grid, lo=False),
+                     attention_grid_steps=attention_grid_steps(b, *grid, plan["visits"], lo=False))
 
         def attend(kind, q, k, v):
             return block_diffusion_attention(q, k, v, seq_len, self.block_len,

@@ -14,7 +14,11 @@ pairs are dead, whole or cut is reduced on the device a call from each q
 tile's least and greatest ``lo`` and compacted into visit lists that the
 kernels take as prefetched scalars: a dead pair is neither fetched nor
 computed. Forward, dq and dk/dv are all kernels and only the log-sum-exp a
-row is kept between them, so nothing is L^2 anywhere. ``models/mellum_moe.py``
+row is kept between them, so nothing is L^2 anywhere. A grid step carries
+several query heads side by side in its blocks (``heads_a_step``, from the
+shapes: 8 at every benchmark cell's) and the K/V heads they read, and walks
+them in order in a loop of its own: each head's sums are those of a step of
+one head, in the same order, and the grid has an eighth of the steps. ``models/mellum_moe.py``
 runs on it, at one packed sequence of 16,384 positions, 32 query heads over 4
 K/V heads, in the benchmark's cell ``mellum2-ep4-pack16k``. With
 ``k_shared`` a score is wider than a value: R further key columns that every
@@ -382,16 +386,52 @@ def _walk(kind, visit, tile, sub, by_keys, cuts=_CUT, by_lo=False):
         pl.when(kind == _LO)(lambda: visit(pl.ds(0, tile), pl.ds(0, tile), _LO))
 
 
-_NO_MORE = (lambda: None, lambda dst, queries, keys: None, lambda: None)
+_NO_MORE = (lambda: None, lambda dst, queries, keys, j: None, lambda: None)
 
 
-# The three kernels' bodies, shared by the two masks. ``at`` is the grid
-# step's place in the prefetched tables, ``scores(q, k, test, queries, keys)``
-# the mask's scaled and masked scores of a slab (keys down, queries across),
-# ``walk(kind, visit, by_keys=...)`` its walk over a tile pair of that kind.
+def _lanes(j, width: int):
+    """The ``width`` lanes of head ``j`` in a block that holds heads side by
+    side: a static slice for a static ``j``, else one 128-aligned offset."""
+    if isinstance(j, int):
+        return pl.ds(j * width, width)
+    return pl.ds(pl.multiple_of(j * width, width), width)
+
+
+def _each_head(n: int, fn):
+    """``fn(j)`` for the grid step's heads ``j`` = 0 .. n - 1, in order: a loop
+    of the kernel's own, so that its code is one head's whatever ``n`` is (an
+    unrolled step of 8 heads would be 8 copies of every visit's code). In the
+    interpreter XLA's CPU backend fuses a multiply-add into one rounding or
+    not by how the loop's code is laid out, so there a step of several heads
+    is equal to steps of one to an ulp, and only unrolled bit for bit; Mosaic
+    keeps every product and sum as it is written."""
+    if n == 1:
+        fn(0)
+    else:
+        jax.lax.fori_loop(0, n, lambda j, carry: (fn(j), carry)[1], 0)
+
+
+def _kv_of(j, heads: int, group: int):
+    """The step's K/V head that its query head ``j`` reads: the step holds
+    ``max(1, heads // group)`` K/V heads, and a group's query heads lie side by side."""
+    if group == 1:
+        return j
+    return 0 if heads <= group else j // group
+
+
+# The three kernels' bodies, shared by the two masks. A grid step carries
+# ``heads`` query heads (``heads_a_step``) side by side in its blocks, and the
+# K/V heads they read; ``group`` query heads read one K/V head. ``at`` is the
+# grid step's place in the prefetched tables, ``scores(q, k, test, queries,
+# keys, j)`` the mask's scaled and masked scores of a slab of head ``j`` (keys
+# down, queries across), ``walk(kind, visit, by_keys=...)`` its walk over a
+# tile pair of that kind. Every slab is visited for the step's heads in order,
+# so each head's sums run over the operands of a step of one head, in its order.
 
 def _fwd_body(at, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-              m_ref, l_ref, acc_ref, scores, walk):
+              m_ref, l_ref, acc_ref, scores, walk, heads, group):
+    d = acc_ref.shape[1]
+
     @pl.when(first_ref[at] == 1)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, _NEG_BIG)
@@ -399,69 +439,88 @@ def _fwd_body(at, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, o_ref, lse
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     def visit(queries, keys, test):
-        st = scores(q_ref[0, queries], k_ref[0, keys], test, queries, keys)
-        m_prev = m_ref[:1, queries]
-        m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
-        # once a query has met a key it may read, m_new is a real score and a
-        # masked score's exp is 0. Under the block-diffusion mask its first keys
-        # hold one (its own block, or block 0 of the clean half); under
-        # intervals they may not (a document that starts inside the tile):
-        # what it sums until then is finite and leaves with corr = 0 at the
-        # first real score, which its own key on the diagonal is at the latest
-        pt = jnp.exp(st - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l = l_ref[:1, queries] * corr + jnp.sum(pt, axis=0, keepdims=True)
-        l_ref[:, queries] = jnp.broadcast_to(l, (_STAT_ROWS, queries.size))
-        # the output is accumulated transposed, (D, queries): v^T p^T
-        v = v_ref[0, keys]
-        acc_ref[:, queries] = acc_ref[:, queries] * corr + jax.lax.dot_general(
-            v, pt.astype(v.dtype), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=_ONE_PASS)
-        m_ref[:, queries] = jnp.broadcast_to(m_new, (_STAT_ROWS, queries.size))
+        def head(j):
+            cols, kv_cols = _lanes(j, d), _lanes(_kv_of(j, heads, group), d)
+            st = scores(q_ref[0, queries, cols], k_ref[0, keys, kv_cols], test, queries, keys, j)
+            m_prev = m_ref[j, :1, queries]
+            m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+            # once a query has met a key it may read, m_new is a real score and a
+            # masked score's exp is 0. Under the block-diffusion mask its first keys
+            # hold one (its own block, or block 0 of the clean half); under
+            # intervals they may not (a document that starts inside the tile):
+            # what it sums until then is finite and leaves with corr = 0 at the
+            # first real score, which its own key on the diagonal is at the latest
+            pt = jnp.exp(st - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l = l_ref[j, :1, queries] * corr + jnp.sum(pt, axis=0, keepdims=True)
+            l_ref[j, :, queries] = jnp.broadcast_to(l, (_STAT_ROWS, queries.size))
+            # the output is accumulated transposed, (D, queries): v^T p^T
+            v = v_ref[0, keys, kv_cols]
+            acc_ref[j, :, queries] = acc_ref[j, :, queries] * corr + jax.lax.dot_general(
+                v, pt.astype(v.dtype), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=_ONE_PASS)
+            m_ref[j, :, queries] = jnp.broadcast_to(m_new, (_STAT_ROWS, queries.size))
+
+        _each_head(heads, head)
 
     walk(kind_ref[at], visit, by_keys=False)
 
     @pl.when(last_ref[at] == 1)
     def _finalize():
-        o_ref[0] = jnp.transpose(acc_ref[:] / l_ref[:1]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[:1] + jnp.log(l_ref[:1])
+        def head(j):
+            o_ref[0, :, _lanes(j, d)] = jnp.transpose(acc_ref[j] / l_ref[j, :1]).astype(o_ref.dtype)
+            lse_ref[0, j] = m_ref[j, :1] + jnp.log(l_ref[j, :1])
+
+        _each_head(heads, head)
 
 
 def _dq_body(at, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-             dq_ref, acc_ref, scores, walk, scale, more=_NO_MORE):
+             dq_ref, acc_ref, scores, walk, scale, heads, group, more=_NO_MORE):
     # ``more`` (here and in ``_dkv_body``): what a second product of the scores
-    # adds to the kernel, as (init, visit(dst, queries, keys), finalize)
+    # adds to the kernel, as (init, visit(dst, queries, keys, j), finalize)
+    d = acc_ref.shape[2]
+
     @pl.when(first_ref[at] == 1)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         more[0]()
 
     def visit(queries, keys, test):
-        k, do = k_ref[0, keys], do_ref[0, queries]
-        st = scores(q_ref[0, queries], k, test, queries, keys)
-        pt = jnp.exp(st - lse_ref[0, 0, :, queries])
-        dpt = jax.lax.dot_general(v_ref[0, keys], do, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32, precision=_ONE_PASS)
-        dst = (pt * (dpt - delta_ref[0, 0, :, queries])).astype(k.dtype)
-        acc_ref[queries] += jax.lax.dot_general(dst, k, (((0,), (0,)), ((), ())),
-                                                preferred_element_type=jnp.float32,
-                                                precision=_ONE_PASS)
-        more[1](dst, queries, keys)
+        def head(j):
+            cols, kv_cols = _lanes(j, d), _lanes(_kv_of(j, heads, group), d)
+            k, do = k_ref[0, keys, kv_cols], do_ref[0, queries, cols]
+            st = scores(q_ref[0, queries, cols], k, test, queries, keys, j)
+            pt = jnp.exp(st - lse_ref[0, j, :, queries])
+            dpt = jax.lax.dot_general(v_ref[0, keys, kv_cols], do, (((1,), (1,)), ((), ())),
+                                      preferred_element_type=jnp.float32, precision=_ONE_PASS)
+            dst = (pt * (dpt - delta_ref[0, j, :, queries])).astype(k.dtype)
+            acc_ref[j, queries] += jax.lax.dot_general(dst, k, (((0,), (0,)), ((), ())),
+                                                       preferred_element_type=jnp.float32,
+                                                       precision=_ONE_PASS)
+            more[1](dst, queries, keys, j)
+
+        _each_head(heads, head)
 
     walk(kind_ref[at], visit, by_keys=False)
 
     @pl.when(last_ref[at] == 1)
     def _finalize():
-        dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
+        def head(j):
+            dq_ref[0, :, _lanes(j, d)] = (acc_ref[j] * scale).astype(dq_ref.dtype)
+
+        _each_head(heads, head)
         more[2]()
 
 
 def _dkv_body(at, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-              dk_ref, dv_ref, dk_acc, dv_acc, scores, walk, scale, group, more=_NO_MORE):
+              dk_ref, dv_ref, dk_acc, dv_acc, scores, walk, scale, heads, group, more=_NO_MORE):
     # here a "row" of the tables is a k tile and its "columns" the q tiles that
     # read it; a pair's kind is the same pair's, and a cut tile is walked by
-    # key rows, each over the query columns that read it
-    g = pl.program_id(3)
+    # key rows, each over the query columns that read it. A K/V head's sums run
+    # over its group's query heads in order: within the step, and over the
+    # grid's last axis where a group spans several steps
+    g, steps = pl.program_id(3), pl.num_programs(3)
+    d = dk_acc.shape[2]
 
     @pl.when((first_ref[at] == 1) & (g == 0))
     def _init():
@@ -470,51 +529,61 @@ def _dkv_body(at, kind_ref, first_ref, last_ref, q_ref, k_ref, v_ref, do_ref, ls
         more[0]()
 
     def visit(queries, keys, test):
-        q, do = q_ref[0, queries], do_ref[0, queries]
-        st = scores(q, k_ref[0, keys], test, queries, keys)
-        pt = jnp.exp(st - lse_ref[0, 0, :, queries])
-        dv_acc[keys] += jax.lax.dot_general(pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-                                            preferred_element_type=jnp.float32, precision=_ONE_PASS)
-        dpt = jax.lax.dot_general(v_ref[0, keys], do, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32, precision=_ONE_PASS)
-        dst = (pt * (dpt - delta_ref[0, 0, :, queries])).astype(q.dtype)
-        dk_acc[keys] += jax.lax.dot_general(dst, q, (((1,), (0,)), ((), ())),
-                                            preferred_element_type=jnp.float32, precision=_ONE_PASS)
-        more[1](dst, queries, keys)
+        def head(j):
+            kv = _kv_of(j, heads, group)
+            cols, kv_cols = _lanes(j, d), _lanes(kv, d)
+            q, do = q_ref[0, queries, cols], do_ref[0, queries, cols]
+            st = scores(q, k_ref[0, keys, kv_cols], test, queries, keys, j)
+            pt = jnp.exp(st - lse_ref[0, j, :, queries])
+            dv_acc[kv, keys] += jax.lax.dot_general(pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                                                    preferred_element_type=jnp.float32,
+                                                    precision=_ONE_PASS)
+            dpt = jax.lax.dot_general(v_ref[0, keys, kv_cols], do, (((1,), (1,)), ((), ())),
+                                      preferred_element_type=jnp.float32, precision=_ONE_PASS)
+            dst = (pt * (dpt - delta_ref[0, j, :, queries])).astype(q.dtype)
+            dk_acc[kv, keys] += jax.lax.dot_general(dst, q, (((1,), (0,)), ((), ())),
+                                                    preferred_element_type=jnp.float32,
+                                                    precision=_ONE_PASS)
+            more[1](dst, queries, keys, j)
+
+        _each_head(heads, head)
 
     walk(kind_ref[at], visit, by_keys=True)
 
-    @pl.when((last_ref[at] == 1) & (g == group - 1))
+    @pl.when((last_ref[at] == 1) & (g == steps - 1))
     def _finalize():
-        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        def head(kv):
+            dk_ref[0, :, _lanes(kv, d)] = (dk_acc[kv] * scale).astype(dk_ref.dtype)
+            dv_ref[0, :, _lanes(kv, d)] = dv_acc[kv].astype(dv_ref.dtype)
+
+        _each_head(dk_acc.shape[0], head)
         more[2]()
 
 
 def _bd_mask(scale, tile, sub, block_len):
     """The block-diffusion mask's ``scores`` and ``walk`` for the bodies above."""
-    def scores(q, k, test, queries, keys):
+    def scores(q, k, test, queries, keys, j):
         return _bd_scores(q, k, scale, test, block_len, queries.start, keys.start)
 
     return scores, functools.partial(_walk, tile=tile, sub=sub)
 
 
 def _bd_fwd_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, *refs, scale, tile, sub,
-                   block_len):
+                   block_len, heads, group):
     _fwd_body(pl.program_id(2), kind_ref, first_ref, last_ref, *refs,
-              *_bd_mask(scale, tile, sub, block_len))
+              *_bd_mask(scale, tile, sub, block_len), heads, group)
 
 
 def _bd_dq_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, *refs, scale, tile, sub,
-                  block_len):
+                  block_len, heads, group):
     _dq_body(pl.program_id(2), kind_ref, first_ref, last_ref, *refs,
-             *_bd_mask(scale, tile, sub, block_len), scale)
+             *_bd_mask(scale, tile, sub, block_len), scale, heads, group)
 
 
-def _bd_dkv_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, *refs, scale, tile, sub, group,
-                   block_len):
+def _bd_dkv_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, *refs, scale, tile, sub,
+                   block_len, heads, group):
     _dkv_body(pl.program_id(2), kind_ref, first_ref, last_ref, *refs,
-              *_bd_mask(scale, tile, sub, block_len), scale, group)
+              *_bd_mask(scale, tile, sub, block_len), scale, heads, group)
 
 
 _STAT_ROWS = 8  # the forward's running max and sum a query: one row, kept in a whole (8, tile) tile
@@ -532,45 +601,130 @@ def _bd_plan(q, k, seq_len, block_len, tile):
     return tile, _sub_tile(tile, block_len), _live_tiles(seq_len, block_len, tile)
 
 
+# How many query heads a grid step carries. A grid step costs some 0.25-0.35
+# us of its own whether it computes or not (PERF.md, sections 5 and 6), and the
+# interval kernels' grids run over the worst case, the whole triangle, where a
+# sequence of packed documents holds some 40% of it live: with a head a step,
+# the rotated-latent cell ran 354,816 grid steps a step, 214,368 of them dead.
+# ``heads_a_step`` is the largest admissible count whose blocks, scratch and
+# temporaries fit ``_VMEM_BUDGET`` in each of the three kernels, at most
+# ``_HEADS_A_STEP_MAX``; each call asks Mosaic for what it reckons.
+_HEADS_A_STEP_MAX = 8
+_VMEM_BUDGET = 48 * 2**20  # of the v5e's 128 MiB of VMEM; Mosaic's default scoped limit is 16
+_VMEM_SLACK = 4 * 2**20  # above the reckoning, for what Mosaic keeps beside it
+_TILE_TEMPORARIES = 4  # (tile, tile) float32 values a visit holds at once: scores, p, dp, ds
+
+
+def _vmem_block(shape, itemsize: int) -> int:
+    """Bytes of one VMEM buffer of ``shape``: its last two axes padded to whole
+    (8 x 4 / itemsize, 128) tiles."""
+    *lead, rows, cols = shape
+    return math.prod(lead) * _round_up(rows, 32 // itemsize) * _round_up(cols, 128) * itemsize
+
+
+def _vmem_reckoning(kernel: str, heads: int, group: int, d: int, shared: int, tile: int,
+                    itemsize: int, lo: bool) -> int:
+    """What one of the three kernels (``fwd``, ``dq``, ``dkv``) holds in VMEM
+    with ``heads`` query heads a step: its input and output blocks twice (the
+    pipeline fetches the next step's while this one computes), its scratch
+    once, and the body's (tile, tile) float32 temporaries."""
+    kvs = max(1, heads // group)
+    by_q, by_kv = (tile, heads * d), (tile, kvs * d)
+    stat = ((heads, 1, tile), 4)
+    ins = [((1, tile), 4)] if lo else []
+    if shared:
+        ins += [((heads, tile, shared), itemsize), ((tile, shared), itemsize)]
+    ins += [(by_q, itemsize), (by_kv, itemsize), (by_kv, itemsize)]
+    if kernel == "fwd":
+        outs = [(by_q, itemsize), stat]
+        scratch = [((heads, _STAT_ROWS, tile), 4)] * 2 + [((heads, d, tile), 4)]
+    elif kernel == "dq":
+        ins += [(by_q, itemsize), stat, stat]
+        outs = [(by_q, itemsize)] + ([((heads, tile, shared), itemsize)] if shared else [])
+        scratch = [((heads, tile, d), 4)] + ([((heads, tile, shared), 4)] if shared else [])
+    else:
+        ins += [(by_q, itemsize), stat, stat]
+        outs = [(by_kv, itemsize)] * 2 + ([((kvs, tile, shared), 4)] if shared else [])
+        scratch = [((kvs, tile, d), 4)] * 2 + ([((kvs, tile, shared), 4)] if shared else [])
+    size = lambda blocks: sum(_vmem_block(shape, n) for shape, n in blocks)
+    return 2 * size(ins + outs) + size(scratch) + _TILE_TEMPORARIES * tile * tile * 4
+
+
+def heads_a_step(hq: int, hkv: int, d: int, tile: int, dtype=jnp.bfloat16, shared: int = 0,
+                 lo: bool = True) -> int:
+    """The query heads one grid step of the attention kernels carries, from
+    the shapes alone: ``hq`` query heads over ``hkv`` K/V heads of ``d``, the
+    ``shared`` further columns of the two-width form, square tiles of ``tile``
+    positions, operands of ``dtype``, ``lo`` whether the mask is the
+    intervals'. A count is admissible where it divides ``hq`` and divides a
+    group of query heads or is made of whole groups, so that a step's K/V block
+    is whole heads; the largest that ``_vmem_reckoning`` fits in the budget."""
+    group = hq // hkv
+    itemsize = jnp.dtype(dtype).itemsize
+    fits = [n for n in range(1, min(hq, _HEADS_A_STEP_MAX) + 1)
+            if hq % n == 0 and (group % n == 0 or n % group == 0)
+            and max(_vmem_reckoning(kernel, n, group, d, shared, tile, itemsize, lo)
+                    for kernel in ("fwd", "dq", "dkv")) <= _VMEM_BUDGET]
+    return max(fits, default=1)
+
+
+def attention_grid_steps(batch: int, hq: int, hkv: int, d: int, tile: int, visits: int,
+                         dtype=jnp.bfloat16, shared: int = 0, lo: bool = True) -> int:
+    """The grid steps of one call of each of the three kernels (forward, dq and
+    dk/dv alike) over ``visits`` tile pairs a sequence."""
+    return batch * hq // heads_a_step(hq, hkv, d, tile, dtype, shared, lo) * visits
+
+
+def _compiler_params(kernel, heads, group, d, shared, tile, dtype, lo, semantics):
+    need = _vmem_reckoning(kernel, heads, group, d, shared, tile, jnp.dtype(dtype).itemsize, lo)
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=max(16 * 2**20, need + _VMEM_SLACK))
+
+
 def _forward_call(kernel, name, tables, per_batch, q, k, v, lo, tile, interpret, more=None):
     """The forward kernel over the visits of ``tables``: ``per_batch`` 0 where
     one list of visits serves every sequence (the block-diffusion mask), else
     the visits a sequence, each with a list of its own; ``lo`` (B, 1, T) the
     intervals' starts where the kernel takes them, else None. ``more``: the
     scores' second product, ``(q_more (B, Hq, T, R), k_shared (B, T, R))``,
-    which the kernel takes after ``lo``."""
+    which the kernel takes after ``lo``. ``kernel`` is handed the heads a step
+    and the group as ``heads`` and ``group``."""
     b, t, hq, d = q.shape
     group = hq // k.shape[2]
+    r = 0 if more is None else more[1].shape[-1]
+    hs = heads_a_step(hq, k.shape[2], d, tile, q.dtype, r, lo is not None)
+    kvs = max(1, hs // group)
     at = (lambda bi, p: p) if not per_batch else (lambda bi, p: bi * per_batch + p)
-    # heads stay where the projections left them: a head is a 128-lane column
-    # block of the (B, T, H * D) array, so nothing is transposed in HBM
+    # heads stay where the projections left them: a step's heads are a column
+    # block of the (B, T, H * D) array, 128 lanes a head, so nothing is
+    # transposed in HBM
     q2, k2, v2 = (x.reshape(b, t, -1) for x in (q, k, v))
     q_at = lambda bi, h, p, row, col, *_: (bi, row[at(bi, p)], h)
-    kv_at = lambda bi, h, p, row, col, *_: (bi, col[at(bi, p)], h // group)
+    kv_at = lambda bi, h, p, row, col, *_: (bi, col[at(bi, p)], h * hs // (group * kvs))
     lo_in = [] if lo is None else [
         pl.BlockSpec((1, 1, tile), lambda bi, h, p, row, *_: (bi, 0, row[at(bi, p)]))]
     if more is not None:
-        r = more[1].shape[-1]
-        lo_in += [pl.BlockSpec((1, 1, tile, r), lambda bi, h, p, row, *_: (bi, h, row[at(bi, p)], 0)),
+        lo_in += [pl.BlockSpec((1, hs, tile, r), lambda bi, h, p, row, *_: (bi, h, row[at(bi, p)], 0)),
                   pl.BlockSpec((1, tile, r), lambda bi, h, p, row, col, *_: (bi, col[at(bi, p)], 0))]
     out, lse = pl.pallas_call(
-        kernel,
+        functools.partial(kernel, heads=hs, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
-            grid=(b, hq, per_batch or tables[0].shape[0]),
-            in_specs=lo_in + [pl.BlockSpec((1, tile, d), q_at), pl.BlockSpec((1, tile, d), kv_at),
-                              pl.BlockSpec((1, tile, d), kv_at)],
-            out_specs=[pl.BlockSpec((1, tile, d), q_at),
-                       pl.BlockSpec((1, 1, 1, tile),
+            grid=(b, hq // hs, per_batch or tables[0].shape[0]),
+            in_specs=lo_in + [pl.BlockSpec((1, tile, hs * d), q_at),
+                              pl.BlockSpec((1, tile, kvs * d), kv_at),
+                              pl.BlockSpec((1, tile, kvs * d), kv_at)],
+            out_specs=[pl.BlockSpec((1, tile, hs * d), q_at),
+                       pl.BlockSpec((1, hs, 1, tile),
                                     lambda bi, h, p, row, *_: (bi, h, 0, row[at(bi, p)]))],
-            scratch_shapes=[pltpu.VMEM((_STAT_ROWS, tile), jnp.float32),
-                            pltpu.VMEM((_STAT_ROWS, tile), jnp.float32),
-                            pltpu.VMEM((d, tile), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((hs, _STAT_ROWS, tile), jnp.float32),
+                            pltpu.VMEM((hs, _STAT_ROWS, tile), jnp.float32),
+                            pltpu.VMEM((hs, d, tile), jnp.float32)],
         ),
         out_shape=[jax.ShapeDtypeStruct((b, t, hq * d), q.dtype),
                    jax.ShapeDtypeStruct((b, hq, 1, t), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params("fwd", hs, group, d, r, tile, q.dtype, lo is not None,
+                                         ("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=f"{name}_fwd",
     )(*tables, *([] if lo is None else [lo]), *(more or ()), q2, k2, v2)
@@ -583,7 +737,11 @@ def _backward_call(dq_kernel, dkv_kernel, name, tables, tables_t, per_batch, q, 
     tile's q tiles, the group's query heads innermost so that a k tile's sums
     stay in VMEM); the arguments as ``_forward_call``'s. With ``more`` also
     the gradients of the second product's operands: ``q_more``'s, and
-    ``k_shared``'s by K/V head (B, Hkv, T, R) float32, for the caller to sum."""
+    ``k_shared``'s by K/V head (B, Hkv, T, R) float32, for the caller to sum.
+
+    dk/dv's grid is (B, Hq / (hs x steps), visits, steps): a step carries
+    ``hs`` query heads and ``max(1, hs / group)`` K/V heads; where a group is
+    larger than a step, its ``steps = group / hs`` steps are the last axis."""
     b, t, hq, d = q.shape
     hkv = k.shape[2]
     group = hq // hkv
@@ -596,68 +754,77 @@ def _backward_call(dq_kernel, dkv_kernel, name, tables, tables_t, per_batch, q, 
     q2, k2, v2, do2 = (x.reshape(b, t, -1) for x in (q, k, v, do))
     lo_arg = ([] if lo is None else [lo]) + list(more or ())
     r = more[1].shape[-1] if more else 0
+    hs = heads_a_step(hq, hkv, d, tile, q.dtype, r, lo is not None)
+    kvs, steps = max(1, hs // group), max(1, group // hs)
+    how = (group, d, r, tile, q.dtype, lo is not None)
 
     q_at = lambda bi, h, p, row, col, *_: (bi, row[at(bi, p)], h)
-    kv_at = lambda bi, h, p, row, col, *_: (bi, col[at(bi, p)], h // group)
+    kv_at = lambda bi, h, p, row, col, *_: (bi, col[at(bi, p)], h * hs // (group * kvs))
     stat_at = lambda bi, h, p, row, *_: (bi, h, 0, row[at(bi, p)])
     lo_in = [] if lo is None else [
         pl.BlockSpec((1, 1, tile), lambda bi, h, p, row, *_: (bi, 0, row[at(bi, p)]))]
     more_at = lambda bi, h, p, row, *_: (bi, h, row[at(bi, p)], 0)
     if more:
-        lo_in += [pl.BlockSpec((1, 1, tile, r), more_at),
+        lo_in += [pl.BlockSpec((1, hs, tile, r), more_at),
                   pl.BlockSpec((1, tile, r), lambda bi, h, p, row, col, *_: (bi, col[at(bi, p)], 0))]
     dq, *dq_more = pl.pallas_call(
-        dq_kernel,
+        functools.partial(dq_kernel, heads=hs, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
-            grid=(b, hq, visits),
-            in_specs=lo_in + [pl.BlockSpec((1, tile, d), q_at), pl.BlockSpec((1, tile, d), kv_at),
-                              pl.BlockSpec((1, tile, d), kv_at), pl.BlockSpec((1, tile, d), q_at),
-                              pl.BlockSpec((1, 1, 1, tile), stat_at),
-                              pl.BlockSpec((1, 1, 1, tile), stat_at)],
-            out_specs=[pl.BlockSpec((1, tile, d), q_at)] + (
-                [pl.BlockSpec((1, 1, tile, r), more_at)] if more else []),
-            scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)] + (
-                [pltpu.VMEM((tile, r), jnp.float32)] if more else []),
+            grid=(b, hq // hs, visits),
+            in_specs=lo_in + [pl.BlockSpec((1, tile, hs * d), q_at),
+                              pl.BlockSpec((1, tile, kvs * d), kv_at),
+                              pl.BlockSpec((1, tile, kvs * d), kv_at),
+                              pl.BlockSpec((1, tile, hs * d), q_at),
+                              pl.BlockSpec((1, hs, 1, tile), stat_at),
+                              pl.BlockSpec((1, hs, 1, tile), stat_at)],
+            out_specs=[pl.BlockSpec((1, tile, hs * d), q_at)] + (
+                [pl.BlockSpec((1, hs, tile, r), more_at)] if more else []),
+            scratch_shapes=[pltpu.VMEM((hs, tile, d), jnp.float32)] + (
+                [pltpu.VMEM((hs, tile, r), jnp.float32)] if more else []),
         ),
         out_shape=[jax.ShapeDtypeStruct((b, t, hq * d), q.dtype)] + (
             [jax.ShapeDtypeStruct(more[0].shape, more[0].dtype)] if more else []),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params("dq", hs, *how, ("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=f"{name}_dq",
     )(*tables, *lo_arg, q2, k2, v2, do2, lse, delta)
 
+    # (bi, the step's K/V heads, visit, the step within a group); its query heads
+    # are the column block ``hk * steps + g`` of hs heads
     kv_at = lambda bi, hk, p, g, row, col, *_: (bi, row[at(bi, p)], hk)
-    q_at = lambda bi, hk, p, g, row, col, *_: (bi, col[at(bi, p)], hk * group + g)
-    stat_at = lambda bi, hk, p, g, row, col, *_: (bi, hk * group + g, 0, col[at(bi, p)])
+    q_at = lambda bi, hk, p, g, row, col, *_: (bi, col[at(bi, p)], hk * steps + g)
+    stat_at = lambda bi, hk, p, g, row, col, *_: (bi, hk * steps + g, 0, col[at(bi, p)])
     lo_in = [] if lo is None else [
         pl.BlockSpec((1, 1, tile), lambda bi, hk, p, g, row, col, *_: (bi, 0, col[at(bi, p)]))]
     shared_at = lambda bi, hk, p, g, row, *_: (bi, hk, row[at(bi, p)], 0)
     if more:
-        lo_in += [pl.BlockSpec((1, 1, tile, r),
-                               lambda bi, hk, p, g, row, col, *_: (bi, hk * group + g, col[at(bi, p)], 0)),
+        lo_in += [pl.BlockSpec((1, hs, tile, r),
+                               lambda bi, hk, p, g, row, col, *_: (bi, hk * steps + g, col[at(bi, p)], 0)),
                   pl.BlockSpec((1, tile, r), lambda bi, hk, p, g, row, *_: (bi, row[at(bi, p)], 0))]
     dk, dv, *dk_more = pl.pallas_call(
-        dkv_kernel,
+        functools.partial(dkv_kernel, heads=hs, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
-            grid=(b, hkv, visits, group),
-            in_specs=lo_in + [pl.BlockSpec((1, tile, d), q_at), pl.BlockSpec((1, tile, d), kv_at),
-                              pl.BlockSpec((1, tile, d), kv_at), pl.BlockSpec((1, tile, d), q_at),
-                              pl.BlockSpec((1, 1, 1, tile), stat_at),
-                              pl.BlockSpec((1, 1, 1, tile), stat_at)],
-            out_specs=[pl.BlockSpec((1, tile, d), kv_at), pl.BlockSpec((1, tile, d), kv_at)] + (
-                [pl.BlockSpec((1, 1, tile, r), shared_at)] if more else []),
-            scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32),
-                            pltpu.VMEM((tile, d), jnp.float32)] + (
-                [pltpu.VMEM((tile, r), jnp.float32)] if more else []),
+            grid=(b, hq // (hs * steps), visits, steps),
+            in_specs=lo_in + [pl.BlockSpec((1, tile, hs * d), q_at),
+                              pl.BlockSpec((1, tile, kvs * d), kv_at),
+                              pl.BlockSpec((1, tile, kvs * d), kv_at),
+                              pl.BlockSpec((1, tile, hs * d), q_at),
+                              pl.BlockSpec((1, hs, 1, tile), stat_at),
+                              pl.BlockSpec((1, hs, 1, tile), stat_at)],
+            out_specs=[pl.BlockSpec((1, tile, kvs * d), kv_at),
+                       pl.BlockSpec((1, tile, kvs * d), kv_at)] + (
+                [pl.BlockSpec((1, kvs, tile, r), shared_at)] if more else []),
+            scratch_shapes=[pltpu.VMEM((kvs, tile, d), jnp.float32),
+                            pltpu.VMEM((kvs, tile, d), jnp.float32)] + (
+                [pltpu.VMEM((kvs, tile, r), jnp.float32)] if more else []),
         ),
         out_shape=[jax.ShapeDtypeStruct((b, t, hkv * d), k.dtype),
                    jax.ShapeDtypeStruct((b, t, hkv * d), v.dtype)] + (
             [jax.ShapeDtypeStruct((b, hkv, t, r), jnp.float32)] if more else []),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary")),
+        compiler_params=_compiler_params("dkv", hs, *how,
+                                         ("parallel", "parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         name=f"{name}_dkv",
     )(*tables_t, *lo_arg, q2, k2, v2, do2, lse, delta)
@@ -676,7 +843,7 @@ def _bd_backward(q, k, v, out, lse, do, seq_len, block_len, scale, tile, interpr
     how = dict(scale=scale, tile=tile, sub=sub, block_len=block_len)
     return _backward_call(
         functools.partial(_bd_dq_kernel, **how),
-        functools.partial(_bd_dkv_kernel, group=q.shape[2] // k.shape[2], **how),
+        functools.partial(_bd_dkv_kernel, **how),
         "block_diffusion_attention", _visit_tables(kinds), _visit_tables(kinds.T), 0,
         q, k, v, out, lse, do, None, tile, interpret)
 
@@ -827,16 +994,16 @@ def _iv_mask(scale, tile, sub, lo_ref, q_tile, k_tile, more=None):
     other cut tile takes ``lo_j <= key <= query`` from the keys' positions (a
     column), the queries' (a row) and the queries' ``lo`` (a row). ``more``
     ``(q_more_ref, k_shared_ref)``: columns every K/V head shares, whose
-    product with the query head's further columns is added to the scores."""
-    def second(queries, keys):
+    product with query head ``j``'s further columns is added to the scores."""
+    def second(queries, keys, j):
         if more is None:
             return None
-        return _raw_scores(more[0][0, 0, queries], more[1][0, keys])
+        return _raw_scores(more[0][0, j, queries], more[1][0, keys])
 
-    def scores(q, k, test, queries, keys):
+    def scores(q, k, test, queries, keys, j):
         if test is None or callable(test):
-            return _bd_scores(q, k, scale, test, 1, queries.start, keys.start, second(queries, keys))
-        s = _raw_scores(q, k, second(queries, keys)) * scale
+            return _bd_scores(q, k, scale, test, 1, queries.start, keys.start, second(queries, keys, j))
+        s = _raw_scores(q, k, second(queries, keys, j)) * scale
         k_pos = jax.lax.broadcasted_iota(jnp.int32, (k.shape[0], 1), 0) + (k_tile * tile + keys.start)
         q_pos = jax.lax.broadcasted_iota(jnp.int32, (1, q.shape[0]), 1) + (q_tile * tile + queries.start)
         return jnp.where((k_pos >= lo_ref[0, :, queries]) & (k_pos <= q_pos), s, _NEG_BIG)
@@ -858,61 +1025,62 @@ def _split_more(refs, shared: bool, n_out: int, n_scratch: int):
 
 
 def _iv_fwd_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, lo_ref, *refs, scale, tile, sub,
-                   visits, shared=False):
+                   visits, heads, group, shared=False):
     at = pl.program_id(0) * visits + pl.program_id(2)
     more = refs[:2] if shared else None
     _fwd_body(at, kind_ref, first_ref, last_ref, *refs[2 if shared else 0:],
-              *_iv_mask(scale, tile, sub, lo_ref, row_ref[at], col_ref[at], more))
+              *_iv_mask(scale, tile, sub, lo_ref, row_ref[at], col_ref[at], more), heads, group)
 
 
 def _sums_of(acc, out, scale, of_rows):
     """The second product's (init, visit, finalize) for a backward body:
-    ``acc`` sums ``dst`` against ``of_rows(queries, keys)`` and leaves for ``out``."""
+    ``acc`` sums ``dst`` against ``of_rows(queries, keys, j)`` by head and leaves
+    for ``out``, whose block holds the step's heads as ``acc`` does."""
     def init():
         acc[:] = jnp.zeros_like(acc)
 
     def finalize():
-        out[0, 0] = (acc[:] * scale).astype(out.dtype)
+        out[0] = (acc[:] * scale).astype(out.dtype)
 
     return init, of_rows, finalize
 
 
 def _iv_dq_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, lo_ref, *refs, scale, tile, sub,
-                  visits, shared=False):
+                  visits, heads, group, shared=False):
     at = pl.program_id(0) * visits + pl.program_id(2)
     refs, more = _split_more(refs, shared, 1, 1)
     hooks = _NO_MORE
     if more:
         q_more, k_shared, dq_more, acc = more
 
-        def visit(dst, queries, keys):  # dst (keys, queries)
-            acc[queries] += jax.lax.dot_general(dst, k_shared[0, keys], (((0,), (0,)), ((), ())),
-                                                preferred_element_type=jnp.float32,
-                                                precision=_ONE_PASS)
+        def visit(dst, queries, keys, j):  # dst (keys, queries)
+            acc[j, queries] += jax.lax.dot_general(dst, k_shared[0, keys], (((0,), (0,)), ((), ())),
+                                                   preferred_element_type=jnp.float32,
+                                                   precision=_ONE_PASS)
 
         hooks = _sums_of(acc, dq_more, scale, visit)
     _dq_body(at, kind_ref, first_ref, last_ref, *refs,
              *_iv_mask(scale, tile, sub, lo_ref, row_ref[at], col_ref[at], more and more[:2]), scale,
-             hooks)
+             heads, group, hooks)
 
 
 def _iv_dkv_kernel(row_ref, col_ref, kind_ref, first_ref, last_ref, lo_ref, *refs, scale, tile, sub,
-                   visits, group, shared=False):
+                   visits, heads, group, shared=False):
     at = pl.program_id(0) * visits + pl.program_id(2)  # rows are k tiles here, columns q tiles
     refs, more = _split_more(refs, shared, 2, 2)
     hooks = _NO_MORE
     if more:
         q_more, k_shared, dk_more, acc = more
 
-        def visit(dst, queries, keys):
-            acc[keys] += jax.lax.dot_general(dst, q_more[0, 0, queries], (((1,), (0,)), ((), ())),
-                                             preferred_element_type=jnp.float32,
-                                             precision=_ONE_PASS)
+        def visit(dst, queries, keys, j):  # the shared columns' sums a K/V head, as dk's
+            acc[_kv_of(j, heads, group), keys] += jax.lax.dot_general(
+                dst, q_more[0, j, queries], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=_ONE_PASS)
 
         hooks = _sums_of(acc, dk_more, scale, visit)
     _dkv_body(at, kind_ref, first_ref, last_ref, *refs,
               *_iv_mask(scale, tile, sub, lo_ref, col_ref[at], row_ref[at], more and more[:2]), scale,
-              group, hooks)
+              heads, group, hooks)
 
 
 def _iv_plan(q, k, lo, tile):
@@ -957,7 +1125,7 @@ def _iv_attention_bwd(window, scale, tile, interpret, res, do):
     how = dict(scale=scale, tile=tile, sub=sub, visits=visits, shared=more is not None)
     dq, dk, dv, *d_more = _backward_call(
         functools.partial(_iv_dq_kernel, **how),
-        functools.partial(_iv_dkv_kernel, group=q.shape[2] // k.shape[2], **how),
+        functools.partial(_iv_dkv_kernel, **how),
         "interval_attention", tables, tables_t, visits, q, k, v, out, lse, do, lo, tile, interpret, more)
     if more:  # the shared columns' gradient: every K/V head's part, summed in float32
         d_more = (d_more[0], jnp.sum(d_more[1], axis=1).astype(more[1].dtype))
@@ -997,7 +1165,10 @@ def interval_attention(
     window's trailing edge, a document's first token) takes its mask from
     ``lo`` and the positions on vectors. The grids run over the worst case
     (``interval_visits``: the triangle, or the window's reach); the steps past
-    a sequence's live pairs stay on the last pair's blocks and do nothing.
+    a sequence's live pairs stay on the last pair's blocks and do nothing. A
+    grid step carries ``heads_a_step`` query heads and the K/V heads they read
+    (whole groups, or a part of one), so a grid has ``Hq / heads_a_step`` rows
+    of heads, and a dead step is paid once for them all.
     Nothing of size T x T is ever built: the backward recomputes the
     probabilities from the forward's log-sum-exp a row. Products take the
     inputs' dtype as operands and accumulate in float32; the softmax is

@@ -1,6 +1,7 @@
 """Pallas flash attention (interpret mode on CPU) vs the dense oracle."""
 
 import functools
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +14,7 @@ from persia_tpu.parallel.sequence import reference_attention
 # the kernel compiles by default (that needs a TPU); on CPU the suite asks
 # for the Pallas interpreter itself
 flash_attention = functools.partial(ops.flash_attention, interpret=True)
+KERNELS = importlib.import_module("persia_tpu.ops.flash_attention")  # the module, beside the function
 
 
 def _qkv(b=2, l=64, h=4, d=16, seed=0, dtype=jnp.float32):
@@ -99,3 +101,48 @@ def test_default_is_compiled_not_interpreted():
     q, k, v = _qkv(l=8)
     with pytest.raises(ValueError, match="interpret mode"):
         ops.flash_attention(q, k, v)
+
+
+# ---- how many query heads a grid step of the attention kernels carries ----
+
+# a call at each sequence cell's shape: query heads, K/V heads (the latent kernels read one
+# K/V head a query head), the shared key columns, whether the mask is the intervals', the
+# sequences, the tile pairs a sequence the grid runs over; and the kernels' VMEM by the
+# reckoning, forward, dq, dk/dv, at 8 heads a step
+CELL_CALLS = {
+    "joyai-flash-ep32-pack16k-mtp1": ((32, 32, 64, True, 1, 528), (17_596_416, 23_887_872, 30_179_328)),
+    "kimi-linear-ep32-pack16k": ((32, 32, 64, True, 1, 528), (17_596_416, 23_887_872, 30_179_328)),
+    "mellum2-ep4-pack16k": ((32, 4, 0, True, 1, 528), (11_567_104, 13_664_256, 10_518_528)),
+    "mellum2-ep4-pack16k-sliding": ((32, 4, 0, True, 1, 93), (11_567_104, 13_664_256, 10_518_528)),
+    "sdar-ep8-bd4-seq4k": ((32, 4, 0, False, 2, 80), (11_534_336, 13_631_488, 10_485_760)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_CALLS))
+def test_heads_a_step_and_their_vmem_at_the_cells_shapes(cell):
+    """8 query heads a grid step at every cell's shape, from the shapes
+    alone: an eighth of the grid steps of a head a step. Each kernel's
+    reckoning fits the budget and the limit the call asks Mosaic for lies
+    above it (``tests/test_tpu_compile.py`` compiles the kernels under the
+    reckoning alone); at the latent shape 16 heads would not fit."""
+    fa = KERNELS
+    (hq, hkv, shared, lo, batch, visits), want = CELL_CALLS[cell]
+    assert fa.heads_a_step(hq, hkv, 128, 512, jnp.bfloat16, shared, lo) == 8
+    assert fa.attention_grid_steps(batch, hq, hkv, 128, 512, visits, jnp.bfloat16, shared, lo) == \
+        batch * 4 * visits
+    group = hq // hkv
+    got = tuple(fa._vmem_reckoning(k, 8, group, 128, shared, 512, 2, lo) for k in ("fwd", "dq", "dkv"))
+    assert got == want and max(got) <= fa._VMEM_BUDGET
+    for kernel, need in zip(("fwd", "dq", "dkv"), got):
+        params = fa._compiler_params(kernel, 8, group, 128, shared, 512, jnp.bfloat16, lo, ("parallel",))
+        assert params.vmem_limit_bytes == max(16 * 2**20, need + fa._VMEM_SLACK)
+    if shared:
+        assert fa._vmem_reckoning("dkv", 16, group, 128, shared, 512, 2, lo) > fa._VMEM_BUDGET
+
+
+@pytest.mark.parametrize("hq,hkv,want", [(2, 2, 2), (6, 2, 6), (3, 1, 3), (11, 1, 1), (64, 2, 8), (12, 4, 6)])
+def test_heads_a_step_is_whole_groups_or_part_of_one(hq, hkv, want):
+    """A step's K/V block is whole heads: its query heads are whole groups or
+    lie inside one (``flash_attention(causal=True)``'s two heads take 2; a
+    group of 11 has no part of 2 to 8 heads and takes 1)."""
+    assert KERNELS.heads_a_step(hq, hkv, 128, 256, jnp.float32) == want

@@ -173,6 +173,8 @@ def test_tower_against_the_reference(one_step, what):
         assert said["rope"] == "pallas_pass" and said["rope_pairs"] == "interleaved" and said["tile"] == "16"
         assert said["mtp_depth"] == "1" and said["mtp_embedding"] == "shifted_slot" and said["head_passes"] == "2"
         assert said["experts"] == "pallas_grouped" and said["head_chunk"] == "32"
+        # both latent heads a grid step, a call's grid the triangle of 4 tiles a sequence
+        assert (said["attention_heads_a_step"], said["attention_grid_steps"]) == ("2", str(2 * 10))
     else:  # the tower's leaves are the weights file's, shape for shape
         shapes = jax.tree.map(lambda x: x.shape, s["state"].params)
         want = jax.tree.map(lambda s: tuple(s), s["model"].param_shapes(),

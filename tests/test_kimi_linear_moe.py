@@ -9,8 +9,10 @@ chunk and a document shorter than the convolution; the convolution alone;
 interval attention at widths 192/128 against dense softmax; sigmoid routing by
 hand values. The Pallas kernels run in the interpreter, by this file's choice."""
 
+import importlib
 import os
 import sys
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -32,11 +34,13 @@ from persia_tpu.models.kimi_linear_moe import short_convolution  # noqa: E402
 from persia_tpu.ops.delta_rule import (  # noqa: E402
     _chunk_parts, _prepare_fwd, kda, kda_recurrence, log_decay_floor, running_sums, unit_lower_inverse,
 )
-from persia_tpu.ops.flash_attention import interval_attention, interval_tile_counts  # noqa: E402
+from persia_tpu.ops.flash_attention import heads_a_step, interval_attention, interval_tile_counts  # noqa: E402
 from persia_tpu.parallel.fused_ctx import FusedTrainCtx  # noqa: E402
 from persia_tpu.parallel.fused_step import (  # noqa: E402
     FusedSlotSpec, FusedTrainState, group_stacked_specs,
 )
+
+KERNELS = importlib.import_module("persia_tpu.ops.flash_attention")
 
 # the leading layer and one period (kda/dense, kda, kda, mla, kda), 4 of 16 experts held, 2 a token
 TINY = {
@@ -169,6 +173,8 @@ def test_tower_against_the_reference(one_step, what):
         assert said["latent_attention"] == "pallas_interval_two_products" and said["tile"] == "16"
         assert said["experts"] == "pallas_grouped"
         assert said["kda_backward_keeps"] == "chunk_states_float32+chunk_inverse_float32"
+        # both latent heads a grid step, a call's grid the triangle of 4 tiles a sequence
+        assert (said["attention_heads_a_step"], said["attention_grid_steps"]) == ("2", str(2 * 10))
     else:  # the tower's leaves are the weights file's, shape for shape
         shapes = jax.tree.map(lambda x: x.shape, s["state"].params)
         want = jax.tree.map(lambda s: tuple(s), s["model"].param_shapes(),
@@ -382,19 +388,36 @@ def _dense_latent_attention(q, k, k_shared, v, lo):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v, precision="highest")
 
 
-@pytest.fixture(scope="module", params=["causal", "documents"])
-def latent_case(request):
+# the mask's case, and how q's further columns come: sliced from a 192-wide q, or head-major
+# beside it (``q_shared``, as the rotated-latent tower hands them over); 4 heads a grid step
+LATENT_CASES = ["causal", "documents", "documents-q_shared"]
+
+
+def _latent_case(name):
+    """((q, k, k_shared, v), the kernels' attention, dense attention, the
+    gradients of a fixed projection of either)."""
+    case, _, form = name.partition("-")
     lo = jnp.asarray({"causal": np.zeros((2, LENGTH), np.int32),
-                      "documents": _starts([18, 5, 41], [9, 3, 2, 50])}[request.param])
+                      "documents": _starts([18, 5, 41], [9, 3, 2, 50])}[case])
     rng = np.random.default_rng(7)
     q = jnp.asarray(rng.standard_normal((2, LENGTH, 4, 192)), jnp.float32)
     k, v = (jnp.asarray(rng.standard_normal((2, LENGTH, 4, 128)), jnp.float32) for _ in range(2))
     shared = jnp.asarray(rng.standard_normal((2, LENGTH, 64)), jnp.float32)
     ct = jnp.asarray(rng.standard_normal(v.shape), jnp.float32)
-    mine = lambda q, k, s, v: interval_attention(q, k, v, lo, tile=16, interpret=True, k_shared=s)
+    if form == "q_shared":
+        mine = lambda q, k, s, v: interval_attention(q[..., :128], k, v, lo, tile=16, interpret=True, k_shared=s,
+                                                     q_shared=jnp.swapaxes(q[..., 128:], 1, 2))
+    else:
+        mine = lambda q, k, s, v: interval_attention(q, k, v, lo, tile=16, interpret=True, k_shared=s)
     theirs = lambda q, k, s, v: _dense_latent_attention(q, k, s, v, lo)
     grads = lambda f: jax.grad(lambda *a: jnp.sum(f(*a) * ct), argnums=(0, 1, 2, 3))(q, k, shared, v)
-    return {"forward": (mine(q, k, shared, v), theirs(q, k, shared, v)),
+    return (q, k, shared, v), mine, theirs, grads
+
+
+@pytest.fixture(scope="module", params=LATENT_CASES)
+def latent_case(request):
+    args, mine, theirs, grads = _latent_case(request.param)
+    return {"forward": (mine(*args), theirs(*args)),
             **{n: pair for n, pair in zip(("dq", "dk", "dk_shared", "dv"), zip(grads(mine), grads(theirs)))}}
 
 
@@ -402,6 +425,29 @@ def latent_case(request):
 def test_interval_attention_at_two_widths_against_dense(latent_case, what):
     mine, theirs = latent_case[what]
     assert mine.shape == theirs.shape and _gap(mine, theirs) < 2e-5
+
+
+@pytest.fixture(scope="module", params=LATENT_CASES[2:])
+def latent_step_case(request):
+    """The kernels' output and gradients with 4 heads a grid step and with
+    one, the heads unrolled (``_each_head``: in the interpreter only so are
+    they bit for bit)."""
+    assert heads_a_step(4, 4, 128, 16, jnp.float32, shared=64) == 4
+    args, mine, _, grads = _latent_case(request.param)
+    run = lambda: dict(zip(("forward", "dq", "dk", "dk_shared", "dv"), (mine(*args), *grads(mine))))
+    with mock.patch.object(KERNELS, "_each_head", lambda n, fn: [fn(j) for j in range(n)]):
+        several = run()
+        with mock.patch.object(KERNELS, "_HEADS_A_STEP_MAX", 1):
+            return several, run()
+
+
+@pytest.mark.parametrize("what", ["forward", "dq", "dk", "dk_shared", "dv"])
+def test_two_widths_step_several_heads_as_one_bit_for_bit(latent_step_case, what):
+    """4 query heads a grid step give what a grid of one head a step gives:
+    the second product's operands a head and the shared columns' sums a K/V
+    head included."""
+    several, one = latent_step_case
+    np.testing.assert_array_equal(np.asarray(several[what]), np.asarray(one[what]))
 
 
 def test_from_config_reads_the_published_pattern():

@@ -8,9 +8,11 @@ expert shares against the uncut layer, the kernels against dense
 against whole logits. The Pallas kernels run in the interpreter, by this
 file's choice."""
 
+import importlib
 import math
 import os
 import sys
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -32,8 +34,8 @@ from persia_tpu.embedding.optim import Adagrad  # noqa: E402
 from persia_tpu.models import MellumMoE, SDARMoE  # noqa: E402
 from persia_tpu.models.mellum_moe import YARN, rope_frequencies  # noqa: E402
 from persia_tpu.ops.flash_attention import (  # noqa: E402
-    _DEAD, _LE, _LO, _WHOLE, _interval_kinds, _interval_lo, _interval_tables, interval_attention,
-    interval_tile_counts, interval_visits,
+    _DEAD, _LE, _LO, _WHOLE, _interval_kinds, _interval_lo, _interval_tables, heads_a_step,
+    interval_attention, interval_tile_counts, interval_visits,
 )
 from persia_tpu.parallel.fused_ctx import FusedTrainCtx, batch_to_fused  # noqa: E402
 from persia_tpu.parallel.fused_step import (  # noqa: E402
@@ -166,6 +168,8 @@ def test_tower_against_the_reference(one_step, what):
         assert said["experts_tile"] == "{0}x128x64/{0}x64x128".format(min(512, -(-chunk // 16) * 16))
         assert (said["window"], said["tile"], said["head_chunk"], said["seq_len"]) == ("8", "16", "32", "64")
         assert (said["grid_sliding"], said["grid_full"]) == (str(1 + 2 * 3), str(10))
+        # the 4 query heads a grid step (two groups), and a call's grid steps: full / sliding
+        assert (said["attention_heads_a_step"], said["attention_grid_steps"]) == ("4", f"{2 * 10}/{2 * 7}")
         # q/k norm, RoPE and the cast as one pass, a sequence's positions one block
         assert (said["qk_prep"], said["qk_prep_tile"]) == ("pallas_rows", str(LENGTH))
 
@@ -223,17 +227,34 @@ ATTENTION_CASES = {
 }
 
 
-@pytest.fixture(scope="module", params=sorted(ATTENTION_CASES))
-def attention_case(request):
-    lo, window = ATTENTION_CASES[request.param]
+# the mask's case, query heads, K/V heads: 32 over 4 as the cell has them (8 query heads a grid
+# step, ``heads_a_step``: one group), and shapes whose heads a step are fewer than a group (32 over
+# 2: 8 of 16), whole groups (8 over 4: 4 groups of 2) and an odd count (6 over 2: 2 groups of 3)
+KERNEL_CASES = {**{case: (case, 32, 4) for case in ATTENTION_CASES},
+                "documents_and_window-32over2": ("documents_and_window", 32, 2),
+                "documents_and_window-8over4": ("documents_and_window", 8, 4),
+                "documents_and_window-6over2": ("documents_and_window", 6, 2)}
+KERNELS = importlib.import_module("persia_tpu.ops.flash_attention")
+
+
+def _interval_case(case, hq, hkv):
+    """(q, k, v, the kernels' attention, dense attention, the gradients of a
+    fixed projection of either)."""
+    lo, window = ATTENTION_CASES[case]
     rng = np.random.default_rng(7)
-    q = jnp.asarray(rng.standard_normal((2, LENGTH, 32, 128)), jnp.float32)  # 32 query heads
-    k, v = (jnp.asarray(rng.standard_normal((2, LENGTH, 4, 128)), jnp.float32) for _ in range(2))  # over 4
+    q = jnp.asarray(rng.standard_normal((2, LENGTH, hq, 128)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((2, LENGTH, hkv, 128)), jnp.float32) for _ in range(2))
     ct = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
     lo = jnp.asarray(lo)
     mine = lambda q, k, v: interval_attention(q, k, v, lo, window=window, tile=16, interpret=True)
     theirs = lambda q, k, v: _dense_attention(q, k, v, lo, window)
     grads = lambda f: jax.grad(lambda *a: jnp.sum(f(*a) * ct), argnums=(0, 1, 2))(q, k, v)
+    return (q, k, v), mine, theirs, grads
+
+
+@pytest.fixture(scope="module", params=sorted(KERNEL_CASES))
+def attention_case(request):
+    (q, k, v), mine, theirs, grads = _interval_case(*KERNEL_CASES[request.param])
     return {"forward": (mine(q, k, v), theirs(q, k, v)),
             **{n: pair for n, pair in zip(("dq", "dk", "dv"), zip(grads(mine), grads(theirs)))}}
 
@@ -242,6 +263,38 @@ def attention_case(request):
 def test_interval_attention_against_dense(attention_case, what):
     mine, theirs = attention_case[what]
     assert float(jnp.max(jnp.abs(mine - theirs))) < 2e-5 * max(1.0, float(jnp.max(jnp.abs(theirs))))
+
+
+# query heads, K/V heads, the most heads a step, the heads a grid step that gives: one group
+# (4 over 1), fewer than a group (2 of 4: the cap set low, as on 16 heads over 1 it takes 8
+# of 16), whole groups (4 over 2: 2 groups of 2), an odd count (3 over 1)
+STEP_CASES = {"one_group": (4, 1, 8, 4), "part_of_a_group": (4, 1, 2, 2), "groups": (4, 2, 8, 4),
+              "odd": (3, 1, 8, 3)}
+
+
+@pytest.fixture(scope="module", params=sorted(STEP_CASES))
+def step_case(request):
+    """The kernels' output and gradients with the rule's heads a step and
+    with one, under documents and a window, the heads unrolled (``_each_head``:
+    in the interpreter only so are they bit for bit)."""
+    hq, hkv, most, hs = STEP_CASES[request.param]
+    (q, k, v), mine, _, grads = _interval_case("documents_and_window", hq, hkv)
+    run = lambda: dict(zip(("forward", "dq", "dk", "dv"), (mine(q, k, v), *grads(mine))))
+    with mock.patch.object(KERNELS, "_each_head", lambda n, fn: [fn(j) for j in range(n)]):
+        with mock.patch.object(KERNELS, "_HEADS_A_STEP_MAX", most):
+            assert heads_a_step(hq, hkv, 128, 16, jnp.float32) == hs
+            several = run()
+        with mock.patch.object(KERNELS, "_HEADS_A_STEP_MAX", 1):
+            return several, run()
+
+
+@pytest.mark.parametrize("what", ["forward", "dq", "dk", "dv"])
+def test_a_step_of_several_heads_is_steps_of_one_bit_for_bit(step_case, what):
+    """Each head's sums run over the operands of a grid of one head a step,
+    in their order: dk and dv over a group's query heads too, whether the
+    group is a step, lies inside one or spans several."""
+    several, one = step_case
+    np.testing.assert_array_equal(np.asarray(several[what]), np.asarray(one[what]))
 
 
 @pytest.mark.parametrize("case", sorted(ATTENTION_CASES))

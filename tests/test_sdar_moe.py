@@ -5,8 +5,10 @@ attention kernels (``ops/flash_attention.py``) and grouped products
 shares against the uncut layer, the kernels against dense ``jax.numpy``.
 The Pallas kernels run in the interpreter, by this file's choice."""
 
+import importlib
 import os
 import sys
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -26,13 +28,15 @@ from persia_tpu.embedding.optim import Adagrad  # noqa: E402
 from persia_tpu.models import SDARMoE  # noqa: E402
 from persia_tpu.ops.flash_attention import (  # noqa: E402
     _CUT, _DEAD, _EQ, _LE, _LT, _WHOLE, _cut_slabs, _live_tiles, _sub_tile, _visit_tables,
-    block_diffusion_attention, block_diffusion_mask, block_diffusion_plan,
+    block_diffusion_attention, block_diffusion_mask, block_diffusion_plan, heads_a_step,
 )
 from persia_tpu.ops.grouped_matmul import grouped_matmul, grouped_outer  # noqa: E402
 from persia_tpu.parallel.fused_ctx import FusedTrainCtx, batch_to_fused  # noqa: E402
 from persia_tpu.parallel.fused_step import (  # noqa: E402
     FusedSlotSpec, FusedTrainState, group_stacked_specs,
 )
+
+KERNELS = importlib.import_module("persia_tpu.ops.flash_attention")
 
 # 2 layers, 8 of 16 experts held, 4 a token, b 4, L 32
 TINY = {
@@ -161,6 +165,8 @@ def test_the_paths_event_carries_the_attention_plan(one_step):
     assert {k: said[k] for k in plan} == {k: str(v) for k, v in plan.items()}
     assert (plan["visits"], plan["visits_whole"], plan["sub_tile"]) == (3, 0, 8)
     assert (plan["sub_tiles_executed"], plan["sub_tiles_visited"]) == (4 + 10 + 10, 48)
+    # the 4 query heads a grid step (two groups), over the batch's 2 sequences
+    assert (said["attention_heads_a_step"], said["attention_grid_steps"]) == ("4", str(2 * 3))
 
 
 def test_the_shares_add_up():
@@ -343,6 +349,41 @@ def test_attention_kernels_against_dense(attention_case, what):
     got, want = attention_case["kernel"][what], attention_case["dense"][what]
     assert got.shape == want.shape
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+# (L, block_len, tile, query heads, K/V heads): 4 query heads a grid step of groups of 2,
+# under the mask's cut tiles of 4 x 4 sub-tiles of 4
+STEP_CASES = [(32, 4, 16, 4, 2)]
+
+
+@pytest.fixture(scope="module", params=STEP_CASES, ids=lambda c: "{3}over{4}".format(*c))
+def step_case(request):
+    """The kernels' output and gradients with the rule's heads a step and
+    with one, the heads unrolled (``_each_head``: in the interpreter only so
+    are they bit for bit)."""
+    length, b, tile, hq, hkv = request.param
+    assert heads_a_step(hq, hkv, 128, tile, jnp.float32, lo=False) == hq
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.standard_normal((1, 2 * length, hq, 128)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((1, 2 * length, hkv, 128)), jnp.float32) for _ in range(2))
+    w = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+
+    def run():
+        o, vjp = jax.vjp(lambda *a: block_diffusion_attention(*a, length, b, tile=tile, interpret=True), q, k, v)
+        return dict(zip(("forward", "dq", "dk", "dv"), (o,) + vjp(w)))
+
+    with mock.patch.object(KERNELS, "_each_head", lambda n, fn: [fn(j) for j in range(n)]):
+        several = run()
+        with mock.patch.object(KERNELS, "_HEADS_A_STEP_MAX", 1):
+            return several, run()
+
+
+@pytest.mark.parametrize("what", ["forward", "dq", "dk", "dv"])
+def test_the_mask_steps_several_heads_as_one_bit_for_bit(step_case, what):
+    """Several query heads a grid step give what a grid of one head a step
+    gives, the cut tiles' walks by sub-tiles included."""
+    several, one = step_case
+    np.testing.assert_array_equal(np.asarray(several[what]), np.asarray(one[what]))
 
 
 def test_attention_refuses_shapes_it_cannot_tile():

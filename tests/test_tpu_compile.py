@@ -420,3 +420,48 @@ def test_rotated_latent_attention_compiles_at_the_cells_widths(one_chip, no_comp
     assert not re.search(r"f32\[1,16384,32,(192|63|1)\]", text)
     # the query's two products, their gradients, the latent's: 1.75 GiB in the backward (2.72 with XLA's rotation)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
+# ---- several query heads a grid step of the attention kernels ----
+
+# a call at each sequence cell's shape: (q, k and v, ``q_shared``, ``k_shared``, ``lo``, window)
+# or, for the block-diffusion mask, (q, k and v, seq_len)
+HEADS_A_STEP_CALLS = {
+    "latent-q_shared": ((1, 16384, 32, 128), (1, 16384, 32, 128), (1, 32, 16384, 64), (1, 16384, 64), None),
+    "interval-full": ((1, 16384, 32, 128), (1, 16384, 4, 128), None, None, None),
+    "interval-sliding": ((1, 16384, 32, 128), (1, 16384, 4, 128), None, None, 1024),
+    "block_diffusion": ((2, 8192, 32, 128), (2, 8192, 4, 128), None, None, None),
+}
+
+
+@pytest.mark.parametrize("call", sorted(HEADS_A_STEP_CALLS))
+def test_the_kernels_compile_within_their_reckoning_at_the_cells_shapes(
+        one_chip, no_compile_cache, highest_by_default, monkeypatch, call):
+    """Forward, dq and dk/dv at 8 query heads a grid step, each asking Mosaic
+    for no more VMEM than ``_vmem_reckoning`` says (no slack): the latent
+    cells' 32 heads with ``q_shared`` head-major beside q, the packed cell's
+    full and sliding layers, the SDAR cell's mask."""
+    import importlib
+
+    fa = importlib.import_module("persia_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_VMEM_SLACK", 0)
+    q_shape, kv_shape, q_more, k_more, window = HEADS_A_STEP_CALLS[call]
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = [shaped(q_shape), shaped(kv_shape), shaped(kv_shape)]
+    if call == "block_diffusion":
+        attend = lambda q, k, v: fa.block_diffusion_attention(q, k, v, q_shape[1] // 2, 4)
+    elif q_more:
+        args += [shaped(q_more), shaped(k_more), shaped(q_shape[:2], jnp.int32)]
+        attend = lambda q, k, v, qs, ks, lo: fa.interval_attention(q, k, v, lo, q_shared=qs, k_shared=ks)
+    else:
+        args += [shaped(q_shape[:2], jnp.int32)]
+        attend = lambda q, k, v, lo: fa.interval_attention(q, k, v, lo, window=window)
+    assert fa.heads_a_step(32, kv_shape[2], 128, 512, jnp.bfloat16, 64 if q_more else 0,
+                           call != "block_diffusion") == 8
+
+    def backward(q, k, v, *rest):
+        return jax.grad(lambda *a: attend(*a, *rest).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(backward).lower(*args).compile().as_text()
+    name = "block_diffusion_attention" if call == "block_diffusion" else "interval_attention"
+    assert {f"{name}_{k}" for k in ("fwd", "dq", "dkv")} <= set(re.findall(rf"{name}_[a-z]+", text))
