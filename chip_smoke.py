@@ -3,7 +3,7 @@
 Drives the repo's main path once on one TPU, in ONE process (the only one
 that touches JAX): ``CachedTrainCtx.train_stream`` feeding the jitted
 cached-tier step from the native feeder over a native PS, at the full DLRM
-bench width (the constants of bench.py, imported), then a few steps through
+width below (``Shape``'s defaults), then a few steps through
 the other two trainer contexts (pinned tables, per-step PS path), then the
 Pallas flash-attention kernel compiled against its dense reference, then —
 when four TPU devices are present — the cached phase on a 4-device data
@@ -36,12 +36,10 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-import bench
-
 # n=1 vs n=N runs of one seeded stream differ by reduction order only.
 # The loss bound is the one __graft_entry__.dryrun_multichip states. Its
 # dense-parameter gate (7.8e-3) was calibrated on its toy tower; at the
-# bench width Adam (|update| <= lr per step, so 30 steps cap the drift at
+# smoke's width Adam (|update| <= lr per step, so 30 steps cap the drift at
 # 3e-2) carries reduction-order noise further: measured 1.20e-2 on four
 # v5e chips and 6.9e-3 on four virtual CPU devices (PR 21). Gate at 1.5x
 # the chips' measurement, as that file does: a doubling fails.
@@ -49,19 +47,24 @@ DP_LOSS_ATOL = 1.2e-3
 DP_PARAM_ATOL = 1.5 * 1.20e-2
 
 
+# dense features of a Criteo-shaped batch
+N_DENSE = 13
+
+
 @dataclass(frozen=True)
 class Shape:
-    """Model and traffic sizes of one smoke run. The defaults are the bench
-    width; the CPU test passes a tiny one."""
+    """Model and traffic sizes of one smoke run. The defaults are a
+    Criteo-shaped DLRM (26 slots of a million rows, dim 16); the CPU test
+    passes a tiny one."""
 
-    batch: int = bench.BATCH_SIZE
-    n_slots: int = bench.N_SLOTS
-    emb_dim: int = bench.EMB_DIM
-    vocab: int = bench.VOCAB
-    bottom_mlp: Tuple[int, ...] = bench.BOTTOM_MLP
-    top_mlp: Tuple[int, ...] = bench.TOP_MLP
-    # bench_cached_saturated's cache: small enough that the stream reaches
-    # the eviction steady state within the smoke's step count
+    batch: int = 4096
+    n_slots: int = 26
+    emb_dim: int = 16
+    vocab: int = 1_000_000
+    bottom_mlp: Tuple[int, ...] = (256, 64, 16)
+    top_mlp: Tuple[int, ...] = (512, 256)
+    # small enough that the stream reaches the eviction steady state within
+    # the smoke's step count
     cache_rows: int = 1 << 18
     store_capacity: int = 1 << 25
     dispatch_k: int = 8
@@ -186,13 +189,45 @@ def _dispatch_latency_us(n: int = 200) -> Dict:
     }
 
 
+def _link() -> Dict:
+    """The host<->device link of the first device: one ~4 MiB transfer each
+    way, then the round trip of a small fetch."""
+    import jax
+
+    dev = jax.devices()[0]
+    add = jax.jit(lambda x, i: x + i)
+    a = np.random.default_rng(0).standard_normal(1 << 20, dtype=np.float32)  # 4 MiB
+    bufs = [a + np.float32(i) for i in range(4)]
+    t0 = time.perf_counter()
+    ys = [jax.device_put(b, dev) for b in bufs]
+    jax.block_until_ready(ys)
+    h2d = 4 * len(bufs) / (time.perf_counter() - t0)
+    zs = [add(ys[0], float(i)) for i in range(4)]
+    jax.block_until_ready(zs)
+    t0 = time.perf_counter()
+    for z in zs:
+        np.asarray(z)
+    d2h = 4 * len(zs) / (time.perf_counter() - t0)
+    small = add(ys[0][:256], 1.0)
+    small.block_until_ready()
+    t0 = time.perf_counter()
+    for i in range(5):
+        np.asarray(add(ys[0][:256], float(i)))
+    rt_ms = (time.perf_counter() - t0) / 5 * 1e3
+    return {
+        "h2d_MBps": round(h2d, 1),
+        "d2h_MBps": round(d2h, 1),
+        "small_d2h_roundtrip_ms": round(rt_ms, 3),
+    }
+
+
 def phase_link() -> Dict:
     """The host↔device link and whether a device→host fetch changes what a
     later dispatch costs. Must run before anything else fetches from the
     device: ``before`` is only meaningful while the process has done no
     d2h at all."""
     before = _dispatch_latency_us()
-    link = bench.bench_link()  # performs the process's first d2h
+    link = _link()  # performs the process's first d2h
     after = _dispatch_latency_us()
     return {
         "link": link,
@@ -216,8 +251,8 @@ def _embedding_config(shape: Shape):
 
 
 def _native_worker(shape: Shape, cfg):
-    """One in-process native PS behind an EmbeddingWorker, as bench.py
-    builds it — ``"native"``, never ``"auto"``: a missing C++ core must
+    """One in-process native PS behind an EmbeddingWorker —
+    ``"native"``, never ``"auto"``: a missing C++ core must
     fail here, not drop to the numpy store."""
     from persia_tpu.embedding.native_store import create_store
     from persia_tpu.embedding.optim import Adagrad
@@ -250,29 +285,40 @@ def _metric_total(name: str, series: str = "") -> float:
 
 
 def _batches(shape: Shape, seed: int, steps: int, learnable: bool = False):
-    """``steps`` batches of bench.py's zipf stream. Its labels are coin
-    flips; ``learnable`` replaces them with a function of the dense
-    features (as __graft_entry__._make_batch does), so that gradients carry
-    a signal and two runs that differ only in reduction order stay close
-    under Adam instead of random-walking apart."""
-    from persia_tpu.data import Label, PersiaBatch
+    """``steps`` batches of the benchmark's zipf stream
+    (``perf/generators/zipf.py``: ranks of a power law of exponent 1.2 over
+    each slot's ``vocab`` rows, a fixed rotation a slot, 13 normal dense
+    features), one id a slot and sample. Its labels are coin flips;
+    ``learnable`` replaces them with a function of the dense features (as
+    __graft_entry__._make_batch does), so that gradients carry a signal and
+    two runs that differ only in reduction order stay close under Adam
+    instead of random-walking apart."""
+    from perf.generators.zipf import ZipfBatches
+    from persia_tpu.data import (
+        IDTypeFeatureWithSingleID,
+        Label,
+        NonIDTypeFeature,
+        PersiaBatch,
+    )
 
-    make = bench._zipf_batch_maker(
-        seed, batch_size=shape.batch, n_slots=shape.n_slots, vocab=shape.vocab,
+    gen = ZipfBatches(
+        {"table_rows": [shape.vocab] * shape.n_slots, "num_dense": N_DENSE},
+        {"batch": shape.batch, "zipf_a": 1.2}, seed,
     )
     for _ in range(steps):
-        b = make()
+        b = gen.next_batch()
+        labels = b["labels"]
         if learnable:
-            dense = b.non_id_type_features[0].data
-            b = PersiaBatch(
-                b.id_type_features,
-                non_id_type_features=b.non_id_type_features,
-                labels=[Label(
-                    (dense.sum(axis=1, keepdims=True) > 0).astype(np.float32)
-                )],
-                requires_grad=True,
-            )
-        yield b
+            labels = (b["dense"].sum(axis=1, keepdims=True) > 0).astype(np.float32)
+        yield PersiaBatch(
+            [
+                IDTypeFeatureWithSingleID(f"cat_{i}", ids.astype(np.uint64))
+                for i, ids in enumerate(b["ids"])
+            ],
+            non_id_type_features=[NonIDTypeFeature(b["dense"])],
+            labels=[Label(labels)],
+            requires_grad=True,
+        )
 
 
 def phase_cached(
@@ -280,7 +326,7 @@ def phase_cached(
     learnable: bool = False,
 ) -> Dict:
     """The main path: ``CachedTrainCtx.train_stream`` over a native PS with
-    the bench's wires (bf16 write-back and checkout, touch-gated admission,
+    narrow wires (bf16 write-back and checkout, touch-gated admission,
     K-step packing). ``n_ps_slots`` moves that many slots to the PS path
     (int8 gradient-return wire), so the d2h gradient wire and the PS update
     run too — such steps are never packed (stream._packable), which is why
@@ -376,7 +422,7 @@ def phase_cached(
 
 
 def phase_hybrid(shape: Shape, steps: int, seed: int = 0) -> Dict:
-    """The per-step PS path as bench_hybrid builds it: ``TrainCtx`` (bf16
+    """The per-step PS path: ``TrainCtx`` (bf16
     embedding wire) with a few synchronous steps, then the pipelined
     ``DataLoader`` with gradients returning through its backward engine."""
     import jax
@@ -466,7 +512,7 @@ def pinned_vocab_that_fits(shape: Shape) -> Tuple[int, Dict]:
 
 
 def phase_pinned(shape: Shape, steps: int, seed: int = 0) -> Dict:
-    """The pinned placement as bench_fused builds it — every table whole in
+    """The pinned placement — every table whole in
     HBM, one stacked table per dim, one jitted program per step — through
     its trainer context. Runs at the largest vocabulary that fits the
     device and says so."""

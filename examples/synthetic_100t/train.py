@@ -14,15 +14,12 @@ the real hybrid pipeline (DLRM dense half, async gradient return) against
 
 Run:  python examples/synthetic_100t/train.py [--steps N] [--ps-replicas 128]
 
-Measurements land in a committed artifact (``--out``, default
-``BENCH_100T.json`` at the repo root) — the repo's claim to the
-reference's 100T capability must be a file, not a stdout line that
-scrolled away (VERDICT r05 weak #6).
+``--out <file>`` also writes the measurements as one JSON artifact (by
+default no file is written).
 """
 
 import argparse
 import json
-import os
 import sys
 import time
 
@@ -80,12 +77,8 @@ def main(argv=None) -> int:
         help="reproducible mode: ordered batches, staleness=1 (ref: REPRODUCIBLE=1)",
     )
     ap.add_argument(
-        "--out",
-        default=os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-            "BENCH_100T.json",
-        ),
-        help="JSON artifact path ('' disables the file)",
+        "--out", default="",
+        help="JSON artifact path ('' writes no file)",
     )
     args = ap.parse_args(argv)
 

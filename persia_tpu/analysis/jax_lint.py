@@ -28,14 +28,14 @@ rules, each mechanizing one of those:
   the read observes garbage — or silently stale data on backends that
   copy. The loop idiom ``state, loss = step(state, batch)`` rebinds and
   is clean.
-- JAX004 **un-synced benchmark timing** (``bench.py`` + ``benchmarks/``):
-  a ``t0 = time.perf_counter()`` … ``x - t0`` window that calls a
-  device-producing function (jitted, or a package function touching
-  jax/jnp — resolved through imports, whole-program like CONC005) with no
-  ``block_until_ready`` inside the window. The window then measures
-  dispatch, not execution. Host-orchestrated loops (ctx methods that sync
-  internally) stay silent — only resolvable device-producing callees
-  count.
+- JAX004 **un-synced benchmark timing** (the benchmark's timed path,
+  ``perf/harness.py`` + ``perf/entries/``): a ``t0 = time.perf_counter()``
+  … ``x - t0`` window that calls a device-producing function (jitted, or a
+  package function touching jax/jnp — resolved through imports,
+  whole-program like CONC005) with no ``block_until_ready`` inside the
+  window. The window then measures dispatch, not execution.
+  Host-orchestrated loops (ctx methods that sync internally) stay silent —
+  only resolvable device-producing callees count.
 
 Suppress with ``# persia-lint: disable=JAX00n`` on the reported line.
 Pure stdlib; jax itself is never imported.
@@ -55,9 +55,10 @@ _SYNC_SCOPE_DIRS = (
     os.path.join("persia_tpu", "parallel"),
     os.path.join("persia_tpu", "embedding", "hbm_cache"),
 )
-# JAX004 bench scope
-_BENCH_SCOPE_FILES = ("bench.py",)
-_BENCH_SCOPE_DIRS = ("benchmarks",)
+# JAX004 bench scope: the benchmark's timed path (the study scripts beside
+# it, e.g. perf/limits_one.py, time themselves on purpose)
+_BENCH_SCOPE_FILES = (os.path.join("perf", "harness.py"),)
+_BENCH_SCOPE_DIRS = (os.path.join("perf", "entries"),)
 
 _GUARD_TOKENS = ("sentinel", "isfinite", "isnan", "nonfinite", "block_until_ready")
 _CLOCK_FUNCS = ("perf_counter", "monotonic", "time")

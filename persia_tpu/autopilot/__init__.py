@@ -18,9 +18,6 @@ behind one hysteresis/dwell-guarded policy engine:
   lease/probe verdicts drive autonomous standby promotion for dead PS
   shards, gray-replica drains, and fleet grow/shrink, under the same
   two-phase journal (a SIGKILLed healer resumes its heal exactly-once).
-
-Soak evidence: ``benchmarks/autopilot_bench.py`` → ``BENCH_AUTOPILOT.json``
-and ``benchmarks/selfheal_bench.py`` → ``BENCH_SELFHEAL.json``.
 """
 
 from persia_tpu.autopilot.controller import (  # noqa: F401

@@ -28,8 +28,7 @@ Three mechanisms ride the lease:
 - **Serialization**: ``run(intent)`` blocks until the lease is free and no
   higher-priority intent is queued, executes, releases. At most one
   topology mutation is ever in flight — ``max_concurrent`` stays 1 by
-  construction, and the soak (benchmarks/soak_bench.py) measures it
-  independently rather than assuming it.
+  construction.
 - **Journaled preemption**: a waiting intent of strictly higher priority
   sets the holder's preemption flag when the holder declared itself
   ``preemptable``. The holder's ``execute(abort_check)`` threads that flag

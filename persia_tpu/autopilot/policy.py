@@ -39,8 +39,7 @@ KIND_HEAL = "heal"  # decided by autopilot.heal.HealPolicy, not PolicyEngine
 
 @dataclass
 class PolicyConfig:
-    """Knobs for all three actuators. Defaults are soak-tested by
-    benchmarks/autopilot_bench.py; production tuning goes through the
+    """Knobs for all three actuators. Production tuning goes through the
     launcher env (see ``--autopilot``)."""
 
     # --- PS resharding (ring re-split at a drained fence) ---

@@ -20,11 +20,10 @@ surfaces the training plane has:
   training loop through ``on_step``/``wrap_batches``) can SIGKILL a PS
   shard, restart it (optionally replaying the last snapshot through
   ``dump_shard``/``load_shard_bytes``), or open/heal a partition at a
-  chosen step — the same schedule file shape ``bench.py --chaos`` takes
-  for soak runs.
+  chosen step.
 
-Everything is usable both from tests (tests/test_chaos.py) and from
-``bench.py --chaos``.
+Everything is usable from tests (tests/test_chaos.py) and from a
+training loop.
 """
 
 from __future__ import annotations
@@ -70,7 +69,7 @@ class ChaosConfig:
 
 
 def parse_chaos_spec(spec: str) -> ChaosConfig:
-    """Parse a ``bench.py --chaos`` spec string like
+    """Parse a chaos spec string like
     ``"seed=7,reset=0.02,slow=0.01,slow_ms=40,corrupt=0.005"``.
     Keys: seed, refuse, reset, slow, slow_ms, corrupt, truncate."""
     cfg = ChaosConfig()
@@ -531,7 +530,7 @@ class DataPlaneChaosConfig:
 
 
 def parse_data_chaos_spec(spec: str) -> DataPlaneChaosConfig:
-    """Parse a ``bench.py --chaos`` data-plane spec string like
+    """Parse a data-plane chaos spec string like
     ``"seed=7,nan=0.01,label_flip=0.02,sign=0.01,spike=0.01"``.
     Keys: seed, nan, label_flip, sign, spike, spike_scale."""
     cfg = DataPlaneChaosConfig()
@@ -737,8 +736,7 @@ class LoadShapeConfig:
       distribution's shape is unchanged, its support moves) — yesterday's
       heavy hitters go cold, invalidating any placement pinned to them.
 
-    Used by both ``benchmarks/autopilot_bench.py`` and ``bench.py
-    --chaos`` (``BENCH_CHAOS_LOAD`` spec, :func:`parse_load_spec`)."""
+    A spec string builds one through :func:`parse_load_spec`."""
 
     seed: int = 7
     vocab: int = 1 << 17
@@ -758,7 +756,7 @@ class LoadShapeConfig:
 
 
 def parse_load_spec(spec: str) -> LoadShapeConfig:
-    """Parse a ``BENCH_CHAOS_LOAD`` spec like
+    """Parse a load-shape spec string like
     ``"a0=1.1,a1=1.7,ramp=10:50,spike=4x20:30,rotate=16,seed=7"``.
     Keys: seed, vocab, a0, a1, ramp=START:END, qps, spike=Xx|spike=XxS:E,
     rotate (= rotate_every), stride."""

@@ -663,9 +663,9 @@ class CachedTrainCtx:
         """Dispatch/feeder accounting of the most recent ``train_stream``:
         ``dispatch_k``, ``packs``, ``packed_steps``, ``single_steps``,
         ``feeder_busy_s``, ``wall_s``, plus the dense-plane sync record
-        (``sync_mode``, ``dense_wire_bytes_per_step``) — the artifact
-        fields bench.py commits so hot-loop regressions are visible from
-        the JSON alone. ``stages`` and ``waits`` hold the stream's one time
+        (``sync_mode``, ``dense_wire_bytes_per_step``) that prices the
+        dense collective, so a regression in either half is visible from
+        these fields alone. ``stages`` and ``waits`` hold the stream's one time
         accounting (tracing.StageAccumulator): per work span ``{n, busy_s,
         max_s}``, per wait span ``{n, wait_s, max_s}``; the closing
         ``stream.drain`` waits, ``drain()``'s included, arrive after

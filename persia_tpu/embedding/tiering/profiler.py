@@ -6,11 +6,8 @@ batch; the profiler taps that stream in place: one ``sketch_observe``
 per group per step on the single-id fast path (the flattened (S, B)
 matrix attributes positions to slots by stride), one per slot on the
 general path. The walk is DRAM-latency-bound like the admit walk it
-rides (~75 ns/sign measured on the 1-core build host — the feeder
-ceiling stays an order of magnitude above chip dispatch rates; see
-PROFILE_FEEDER.md). Everything downstream — the skew/working-set stats
-the placement planner scores, the snapshot/resume persistence — reads
-the same sketch.
+rides. Everything downstream — the skew/working-set stats the placement
+planner scores, the snapshot/resume persistence — reads the same sketch.
 
 Round 14: the profiler can run **sharded** (``shards=S``): one
 sub-sketch per feed-directory shard, partitioned by the same
@@ -151,8 +148,8 @@ class AccessProfiler:
             # sketch (same count-min / linear-counting error) and the
             # family's total footprint matches the unsharded sketch. This
             # is also the fused walk's cache-footprint contract: a family
-            # of full-width sketches measured 0.8x (slower than unfused);
-            # the scaled family measures 1.14x (PROFILE_FEEDER round 14).
+            # of full-width sketches would not fit the cache the walk
+            # shares with the admit walk.
             lg = (self.shards - 1).bit_length()
             sub = dict(self._cfg)
             sub["width_log2"] = max(4, width_log2 - lg)

@@ -458,8 +458,8 @@ def resume_job(
     generators: Optional[Dict[str, np.random.Generator]] = None,
 ) -> Tuple[Optional[Manifest], Dict]:
     """Load the newest good manifest and rebuild the fence state. Returns
-    ``(manifest_or_None, recovery_info)`` — the info dict is what
-    ``bench.py --chaos`` records as recovery metrics.
+    ``(manifest_or_None, recovery_info)`` — the info dict holds the
+    recovery metrics.
 
     ``rewind_ps=True`` restores the PS shards to the fence (clear + replay
     + journal clear): the replayed window then re-applies its gradients

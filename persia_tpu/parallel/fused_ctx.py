@@ -3,9 +3,8 @@
 The fused tier (``parallel/fused_step.py``) is the idiomatic TPU answer to
 the reference's async CPU-PS pipeline when the tables fit in HBM: gather →
 model fwd/bwd → dense update → duplicate-safe sparse update, all ONE jitted
-XLA program, host↔device traffic per step = the raw batch. Until now only
-bench/test code drove it, wiring ``init_fused_state``/``build_fused_*`` by
-hand; this module packages the same machinery behind the ``TrainCtx`` API
+XLA program, host↔device traffic per step = the raw batch. This module
+packages ``init_fused_state``/``build_fused_*`` behind the ``TrainCtx`` API
 (train_step / eval_batch / dump_checkpoint / load_checkpoint, ref:
 `persia/ctx.py` TrainCtx surface) so the example CLIs and user code can
 switch tiers with one flag.
@@ -15,7 +14,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Dict, Optional, Sequence
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -104,12 +103,12 @@ def batch_to_fused(
 
 
 class FusedTrainCtx:
-    """All-in-HBM training context (the bench's "fused" tier as an API).
+    """All-in-HBM training context (the "fused" tier as an API).
 
     State initializes lazily from the first batch (the model needs a sample
     to trace). ``train_step`` fetches the loss (one d2h per step — fine for
-    examples; throughput loops should use the raw ``build_fused_train_step``
-    the way bench.py does, or ``fetch_metrics=False``).
+    examples; throughput loops pass ``fetch_metrics=False`` and read
+    :meth:`last_metrics` when they need it).
     """
 
     def __init__(
@@ -134,9 +133,6 @@ class FusedTrainCtx:
         self.seed = seed
         self.fold_ids = fold_ids
         kw = {} if loss_fn is None else {"loss_fn": loss_fn}
-        self._loss_kw = kw
-        self._pipelines: Dict = {}
-        self._pipe_stats: Optional[Dict] = None
         self._step = build_fused_train_step(
             model, dense_optimizer, self.sparse_cfg, self.specs,
             self.slot_order, stack=stack, **kw
@@ -181,75 +177,6 @@ class FusedTrainCtx:
         with wait_span("fused.fetch", seq=seq):  # the d2h waits for the step
             return {"loss": _note_nonfinite_loss(float(loss)),
                     "preds": np.asarray(preds)}
-
-    def train_pipelined(
-        self,
-        batches,
-        pipeline_depth: int = 2,
-        dispatch_k: int = 1,
-        fetch_metrics: bool = True,
-    ) -> Dict:
-        """Stage-pipelined drive of a ``PersiaBatch`` iterable: host
-        conversion + h2d staging (FEED) overlap the jitted step (DENSE)
-        via :class:`~persia_tpu.parallel.fused_step.FusedPipeline`, with
-        ``pipeline_depth`` bounding the staged buffers in flight and
-        ``dispatch_k`` packing the dense stage into K-step windows. With
-        ``dispatch_k=1`` the math is the sequential ``train_step`` loop's
-        bit for bit (all rows are HBM-resident — no feed hazards);
-        ``dispatch_k>1`` inherits ``build_fused_multi_step``'s numerical
-        (~1 ulp) parity. The pipeline drains before this
-        returns, so ``dump_checkpoint`` right after has fence semantics;
-        pipeline overlap stats land in :meth:`pipeline_stats`. Programs
-        are cached per ``(pipeline_depth, dispatch_k)``."""
-        from persia_tpu.parallel.fused_step import build_fused_pipeline
-
-        it = iter(batches)
-        try:
-            first = next(it)
-        except StopIteration:
-            return {}
-        fb0 = batch_to_fused(first, self.specs, self.fold_ids)
-        self._ensure_state(fb0)
-        key = (int(pipeline_depth), int(dispatch_k))
-        pipe = self._pipelines.get(key)
-        if pipe is None:
-            pipe = build_fused_pipeline(
-                self.model, self.dense_optimizer, self.sparse_cfg,
-                self.specs, self.slot_order, stack=self.stack,
-                depth=pipeline_depth, k=dispatch_k, **self._loss_kw,
-            )
-            self._pipelines[key] = pipe
-
-        def fused_stream():
-            # consumed by the pipeline's feed thread: conversion rides
-            # the feed lane
-            yield fb0
-            for b in it:
-                yield batch_to_fused(b, self.specs, self.fold_ids)
-
-        self.state, losses = pipe.run(self.state, fused_stream())
-        self._pipe_stats = pipe.stats()
-        self._last = None
-        if not fetch_metrics or not losses:
-            return {}
-        return {"loss": _note_nonfinite_loss(float(losses[-1])),
-                "losses": np.asarray([float(l) for l in losses])}
-
-    def pipeline_stats(self) -> Optional[Dict]:
-        """Stage/overlap stats of the last :meth:`train_pipelined` run."""
-        return self._pipe_stats
-
-    @property
-    def sync_mode(self) -> str:
-        """Dense-plane sync label for bench records: the fused tier is one
-        device, one program — no dense collective crosses any wire. Shares
-        the grad_sync mode vocabulary so fused/stream/hybrid rows compare."""
-        return "local"
-
-    def dense_wire_bytes_per_step(self) -> int:
-        """Per-replica dense collective bytes/step: 0 by construction (the
-        whole hybrid step is one single-device XLA program)."""
-        return 0
 
     def last_metrics(self) -> Optional[Dict]:
         if getattr(self, "_last", None) is None:

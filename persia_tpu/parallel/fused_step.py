@@ -39,7 +39,6 @@ from persia_tpu.ops.sparse_update import (
     sparse_update,
 )
 from persia_tpu.parallel.train_step import default_loss_fn
-from persia_tpu.tracing import stage_span
 
 
 @dataclass(frozen=True)
@@ -467,48 +466,6 @@ def build_fused_train_step(
     return jax.jit(step, donate_argnums=(0,) if donate else ())
 
 
-def build_fused_multi_step(
-    model,
-    dense_optimizer: optax.GradientTransformation,
-    sparse_cfg: OptimizerConfig,
-    specs: Dict[str, FusedSlotSpec],
-    k: int,
-    slot_order: Optional[Sequence[str]] = None,
-    loss_fn=default_loss_fn,
-    stack: bool = False,
-):
-    """K-step fused dispatch for the all-in-HBM path: ONE jitted program
-    advances ``k`` consecutive batches — ``multi(state, batches) -> (state,
-    (losses, preds_list))`` with ``batches`` a length-``k`` tuple of the
-    single-step batch dict. The per-dispatch Python/header overhead that
-    bounds small-step-time loops is paid once per K steps; the
-    math is the single-step program iterated, so parity with
-    ``build_fused_train_step`` is exact in program terms — but NOT bitwise:
-    XLA compiles the step subgraph differently inside the larger program
-    (cross-step/cluster fusion reorders float ops at the ~1 ulp level, and
-    ``optimization_barrier`` between steps does not recover the standalone
-    bits). Callers needing bit parity with the single-step loop must use
-    k=1. The cached tier's stream applies the same idea to its hazard-free
-    windows (hbm_cache/stream.py ``dispatch_k``) — there the K program IS
-    bit-exact (pinned by test_stream_kstep_packing_bitwise_parity)."""
-    if k < 1:
-        raise ValueError(f"k must be >= 1, got {k}")
-    raw = build_fused_train_step(
-        model, dense_optimizer, sparse_cfg, specs, slot_order,
-        loss_fn=loss_fn, jit=False, stack=stack,
-    )
-
-    def multi(state: FusedTrainState, batches):
-        losses, preds = [], []
-        for b in batches:
-            state, (loss, p) = raw(state, b)
-            losses.append(loss)
-            preds.append(p)
-        return state, (jnp.stack(losses), preds)
-
-    return jax.jit(multi, donate_argnums=(0,))
-
-
 def build_fused_eval_step(model, specs, slot_order=None, stack: bool = False):
     slot_order = list(slot_order or sorted(specs))
     groups = group_stacked_specs(specs, slot_order) if stack else None
@@ -576,160 +533,3 @@ def unpack_ids(flat_dev: jnp.ndarray, slot_order: Sequence[str], shapes) -> Dict
         out[name] = jax.lax.slice(flat_dev, (off,), (off + k,)).reshape(shape)
         off += k
     return out
-
-
-class FusedPipeline:
-    """Stage-pipelined driver for the fused tier: a feeder thread runs the
-    FEED stage (host batch conversion + h2d staging, double-buffered up to
-    ``depth`` in flight) while the caller's thread runs the DENSE stage
-    (the jitted single- or K-step program). Every table row is HBM-resident
-    and the sparse update is fused INTO the dense program, so there are no
-    feed/gradient hazards — a semaphore of ``depth`` permits only bounds
-    how many staged batches (and therefore how much staging HBM) ride
-    ahead of the dense stage. Batches enter the program in stream order,
-    so with ``k == 1`` the result is the sequential ``step`` loop's bit
-    for bit (pinned by test_stage_graph.py); ``k > 1`` packs the dense
-    stage via ``build_fused_multi_step``, whose parity is numerical, not
-    bitwise (see its docstring) — same trade as calling that program
-    directly.
-
-    ``run`` returns with every staged batch dispatched — callers may
-    checkpoint (``FusedTrainCtx.dump_checkpoint``) immediately after with
-    fence semantics. When the caller's dispatch raises, the feeder thread
-    gives up within its poll interval and ``run`` re-raises.
-    """
-
-    def __init__(self, step, multi=None, depth: int = 2, k: int = 1):
-        from persia_tpu.parallel.stage_graph import StageGraph
-
-        if depth < 1:
-            raise ValueError(f"depth must be >= 1, got {depth}")
-        if k > 1 and multi is None:
-            raise ValueError("k > 1 needs the multi-step program")
-        self._step = step
-        self._multi = multi
-        self.depth = int(depth)
-        # a full pack must fit in the window or feed and dense deadlock
-        # waiting on each other
-        self.k = max(1, min(int(k), self.depth))
-        self.graph = StageGraph()
-
-    def run(self, state, batches, stage=None):
-        """Drive ``batches`` (iterable of fused batch dicts — or anything
-        ``stage`` maps to one) through the pipeline. The iterable is
-        consumed by the FEED thread, so host-side conversion inside a
-        generator rides the feed lane too. Returns ``(state, losses)``
-        with ``losses`` the per-step device scalars in stream order;
-        :meth:`stats` reports overlap after the run."""
-        import queue as _queue
-        import threading
-        import time as _time
-
-        stage = jax.device_put if stage is None else stage
-        graph = self.graph
-        # at most ``depth`` batches between ``stage`` and their dispatch:
-        # the feeder takes a permit before staging, the dispatcher hands
-        # back a pack's permits once it went out. The queue itself is
-        # unbounded (the permits are its bound), so the feeder's only park
-        # is the permit wait, which polls ``abort``
-        window = threading.Semaphore(self.depth)
-        abort = threading.Event()
-        q: "_queue.Queue" = _queue.Queue()
-        errors: List[BaseException] = []
-        SENTINEL = object()
-
-        def admit() -> bool:
-            while not abort.is_set():
-                if window.acquire(timeout=0.05):
-                    return True
-            return False
-
-        def feeder():
-            try:
-                for seq, b in enumerate(batches):
-                    if not admit():
-                        break
-                    with graph.lane("feed"), stage_span("fused.stage", seq=seq):
-                        staged = stage(b)
-                    q.put((seq, staged))
-            except BaseException as e:  # noqa: BLE001 — reraised on the caller
-                errors.append(e)
-            finally:
-                q.put(SENTINEL)
-
-        t0 = _time.perf_counter()
-        th = threading.Thread(target=feeder, name="fused-pipe-feeder", daemon=True)
-        th.start()
-        losses: List[jnp.ndarray] = []
-        pack: List[Tuple[int, Dict]] = []
-        try:
-            def flush():
-                nonlocal state
-                if not pack:
-                    return
-                seq = pack[0][0]
-                if len(pack) > 1:
-                    with self.graph.lane("dense", k=len(pack)), stage_span(
-                        "fused.dispatch", seq=seq, k=len(pack)
-                    ):
-                        state, (ls, _preds) = self._multi(
-                            state, tuple(b for _, b in pack)
-                        )
-                    losses.extend(ls[i] for i in range(len(pack)))
-                else:
-                    with self.graph.lane("dense"), stage_span("fused.dispatch", seq=seq):
-                        state, (loss, _preds) = self._step(state, pack[0][1])
-                    losses.append(loss)
-                window.release(len(pack))
-                pack.clear()
-
-            while True:
-                item = q.get()
-                if item is SENTINEL:
-                    break
-                pack.append(item)
-                if len(pack) >= self.k:
-                    flush()
-            flush()
-            if errors:
-                raise errors[0]
-        finally:
-            abort.set()
-            th.join(timeout=5.0)
-        self._wall_s = _time.perf_counter() - t0
-        return state, losses
-
-    def stats(self) -> Dict:
-        """Lane stats of the last :meth:`run` (``StageGraph.stats``) plus
-        the run's wall seconds."""
-        out = self.graph.stats(getattr(self, "_wall_s", 0.0))
-        out["wall_s"] = round(getattr(self, "_wall_s", 0.0), 6)
-        return out
-
-
-def build_fused_pipeline(
-    model,
-    dense_optimizer: optax.GradientTransformation,
-    sparse_cfg: OptimizerConfig,
-    specs: Dict[str, FusedSlotSpec],
-    slot_order: Optional[Sequence[str]] = None,
-    loss_fn=default_loss_fn,
-    stack: bool = False,
-    depth: int = 2,
-    k: int = 1,
-) -> FusedPipeline:
-    """Convenience factory: builds the jitted single-step (and, when
-    ``k > 1``, the K-step) program and wraps them in a
-    :class:`FusedPipeline`. Reuse the returned pipeline across runs — each
-    factory call retraces."""
-    step = build_fused_train_step(
-        model, dense_optimizer, sparse_cfg, specs, slot_order,
-        loss_fn=loss_fn, stack=stack,
-    )
-    multi = None
-    if k > 1:
-        multi = build_fused_multi_step(
-            model, dense_optimizer, sparse_cfg, specs, min(k, depth),
-            slot_order, loss_fn=loss_fn, stack=stack,
-        )
-    return FusedPipeline(step, multi, depth=depth, k=k)

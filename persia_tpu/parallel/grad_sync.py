@@ -537,8 +537,8 @@ def ring_neighbor_average(params, sync_idx, axis: str, n: int):
 # ----------------------------------------------- dense sync modes / wiring
 #
 # The mode-string registry is the single vocabulary shared by TrainCtx's
-# ``dense_sync=`` knob, bench.py's records, WIRE_BENCH rows, and the README
-# mode table. "implicit-psum" / "local" are accounting-only labels for the
+# ``dense_sync=`` knob, the cached stream's ``stream_stats()``, and the
+# README mode table. "implicit-psum" / "local" are accounting-only labels for the
 # default XLA path and single-device tiers.
 
 

@@ -1,5 +1,5 @@
-"""Stage lanes and their time accounting for the two pipelined drivers
-(the cached tier's ``train_stream`` and the fused tier's ``FusedPipeline``).
+"""Stage lanes and their time accounting for the cached tier's pipelined
+``train_stream``.
 
 A step runs through three lanes: ``feed`` (host conversion + h2d staging),
 ``dense`` (the dispatch of the step program, single or K-step packed) and
@@ -8,8 +8,8 @@ returns). :class:`StageGraph` owns the driver's one
 :class:`~persia_tpu.tracing.StageAccumulator`, opens a ``stage.<lane>``
 span per lane occupancy, derives ``stage_wall_s`` / ``stage_overlap_frac``
 from them, and carries the fence-point rebuild hooks of
-``CachedTrainCtx.register_stage_rebuild``. It schedules nothing: both
-drivers dispatch in stream order.
+``CachedTrainCtx.register_stage_rebuild``. It schedules nothing: the
+stream dispatches in stream order.
 """
 
 from __future__ import annotations

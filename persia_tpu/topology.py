@@ -20,9 +20,7 @@ This module is that glue, in three layers:
   the fault hooks the chaos soak drives (kill/restart any component,
   per-replica delta-channel faults via ``chaos.DeltaChannelChaos``);
 - the ``persia-tpu-launcher local`` subcommand (persia_tpu/launcher.py)
-  wraps :class:`LocalTopology` for the README quickstart;
-  ``benchmarks/online_bench.py`` drives the same class under chaos for
-  the flagship artifact.
+  wraps :class:`LocalTopology` for the README quickstart.
 
 Everything is CPU-host friendly (``JAX_PLATFORMS=cpu`` is forced into
 children) — the point is the topology, not the chip.

@@ -1,5 +1,5 @@
 """Trainer-process entry for the trainer-SIGKILL/auto-resume chaos tests
-(tests/test_chaos.py) and ``bench.py --chaos`` — NOT a pytest module.
+(tests/test_chaos.py) — NOT a pytest module.
 
 One hybrid trainer: TrainCtx over an in-process EmbeddingWorker whose PS
 replicas are the parent's subprocess parameter servers (StoreClients).

@@ -328,10 +328,20 @@ def test_jax001_scope_is_hot_paths_only():
     assert [f.rule for f in findings] == []
 
 
-def test_jax004_scope_is_bench_files_only():
+@pytest.mark.parametrize(
+    "path, fires",
+    [
+        ("perf/harness.py", True),
+        ("perf/entries/b.py", True),
+        # a study script beside the benchmark times itself on purpose
+        ("perf/limits_one.py", False),
+        ("bench.py", False),
+    ],
+)
+def test_jax004_scope_is_the_drivers_timed_path(path, fires):
     src = read_text(_fixture("jax_unsynced_timer.py"))
-    findings = jax_lint.check_source(src, "persia_tpu/data_loader.py")
-    assert "JAX004" not in {f.rule for f in findings}
+    findings = jax_lint.check_source(src, path)
+    assert ("JAX004" in {f.rule for f in findings}) == fires, findings
 
 
 def test_jax_suppression_works():
@@ -346,7 +356,7 @@ def test_jax_suppression_works():
 
 def test_jax004_sees_imported_jit_through_registry():
     """The whole-program half: the jitted callee lives in another module;
-    the bench file only imports it."""
+    the benchmark's entry only imports it."""
     registry = {"somepkg.kernels.kernel": jax_lint._JitInfo(jitted=True, device=True)}
     src = (
         "import time\n"
@@ -356,7 +366,7 @@ def test_jax004_sees_imported_jit_through_registry():
         "    y = kernel(x)\n"
         "    return time.perf_counter() - t0\n"
     )
-    findings = jax_lint.check_source(src, "benchmarks/b.py", registry=registry)
+    findings = jax_lint.check_source(src, "perf/entries/b.py", registry=registry)
     assert [f.rule for f in findings] == ["JAX004"], findings
 
 

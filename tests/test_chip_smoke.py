@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)  # chip_smoke.py and bench.py live at the repo root
+sys.path.insert(0, REPO)  # chip_smoke.py and perf/ live at the repo root
 
 import chip_smoke as cs  # noqa: E402
 
@@ -40,21 +40,31 @@ def test_chip_smoke_refuses_cpu():
     assert '"ok"' not in r.stdout  # no result line
 
 
-def test_bench_refuses_cpu():
-    # BENCH_MODE=all: the parent stays off JAX, the first child refuses the
-    # CPU, and the suite exits non-zero instead of printing a record
-    r = _run("bench.py", BENCH_MODE="all")
-    assert r.returncode != 0
-    assert "measures a TPU" in r.stderr, r.stderr[-500:]
-    assert '"metric"' not in r.stdout
-
-
-def test_bench_unknown_device_kind_has_no_peak():
-    import bench
-
-    assert bench._peak_bf16_flops({"kind": "TPU v5 lite"}) == 197e12
-    with pytest.raises(SystemExit, match="no peak listed"):
-        bench._peak_bf16_flops({"kind": "cpu"})
+def test_batches_come_from_the_benchmarks_generator():
+    a = list(cs._batches(TINY, seed=3, steps=2, learnable=True))
+    b = list(cs._batches(TINY, seed=3, steps=2, learnable=True))
+    for x, y in zip(a, b):  # one seed, one stream
+        for fx, fy in zip(x.id_type_features, y.id_type_features):
+            assert fx.name == fy.name
+            np.testing.assert_array_equal(fx.flat_counts()[0], fy.flat_counts()[0])
+        np.testing.assert_array_equal(
+            x.non_id_type_features[0].data, y.non_id_type_features[0].data
+        )
+        np.testing.assert_array_equal(x.labels[0].data, y.labels[0].data)
+    for batch in a:
+        assert [f.name for f in batch.id_type_features] == [
+            f"cat_{i}" for i in range(TINY.n_slots)
+        ]
+        for f in batch.id_type_features:
+            ids, counts = f.flat_counts()
+            assert ids.shape == (TINY.batch,) and (counts == 1).all()
+            assert ids.min() >= 0 and ids.max() < TINY.vocab
+        dense = batch.non_id_type_features[0].data
+        assert dense.shape == (TINY.batch, 13)
+        np.testing.assert_array_equal(
+            batch.labels[0].data,
+            (dense.sum(1, keepdims=True) > 0).astype(np.float32),
+        )
 
 
 def test_cached_phase_tiny_evicts_and_packs():

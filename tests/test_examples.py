@@ -58,8 +58,7 @@ def test_taobao_din_smoke(capsys):
 
 def test_synthetic_100t_smoke(capsys, tmp_path):
     mod = _load("synthetic_100t/train.py")
-    # --out to a tmp file: the default path is the COMMITTED BENCH_100T.json
-    # artifact, which a smoke-config run must not overwrite
+    # --out to a tmp file: the default writes no file
     out_json = str(tmp_path / "bench_100t.json")
     rc = mod.main(["--batch-size", "32", "--steps", "2", "--num-slots", "4",
                    "--ids-per-sample", "2", "--ps-replicas", "8",

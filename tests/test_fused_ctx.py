@@ -155,3 +155,35 @@ def test_out_of_vocab_ids_rejected_or_folded():
     )
     m = ctx.train_step(batch)
     assert np.isfinite(m["loss"])
+
+
+def test_refused_batch_leaves_the_state():
+    """A batch ``train_step`` refuses at conversion (an out-of-vocab id,
+    folding off) raises that error and touches no leaf of the state; the
+    next good batch trains to what a context that never saw it reaches."""
+    import jax
+
+    def leaves(ctx):
+        return [np.asarray(x) for x in jax.tree_util.tree_leaves(ctx.state)]
+
+    good = _batch(1)
+    a = good.id_type_features[0].flat_counts()[0].copy()
+    a[3] = SPECS["a"].vocab  # one past the table
+    bad = PersiaBatch(
+        [IDTypeFeatureWithSingleID("a", a), good.id_type_features[1]],
+        non_id_type_features=good.non_id_type_features,
+        labels=good.labels,
+        requires_grad=True,
+    )
+    seen, clean = _ctx(), _ctx()
+    for ctx in (seen, clean):
+        ctx.train_step(_batch(0), fetch_metrics=False)
+    before = leaves(seen)
+    with pytest.raises(ValueError, match="outside"):
+        seen.train_step(bad)
+    for i, (x, y) in enumerate(zip(before, leaves(seen))):
+        np.testing.assert_array_equal(x, y, err_msg=f"leaf {i}")
+    for ctx in (seen, clean):
+        ctx.train_step(_batch(2), fetch_metrics=False)
+    for i, (x, y) in enumerate(zip(leaves(clean), leaves(seen))):
+        np.testing.assert_array_equal(x, y, err_msg=f"leaf {i}")

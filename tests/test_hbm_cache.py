@@ -1751,8 +1751,8 @@ def test_int8_ps_wire_trains_close_to_f32():
     """ps_wire_dtype='int8' (bytegrad-style absmax quantization of the
     gradient-return wire with a device-resident error-feedback residual)
     must really quantize (bit-different from f32) yet track the f32-wire
-    run closely on the same stream — the quality gate behind bench.py's
-    int8-by-default ps-stream config. Driven through the SYNC path so
+    run closely on the same stream — the quality gate of the int8
+    gradient-return wire. Driven through the SYNC path so
     every gradient lands before the next forward: the diff measured is
     pure wire quantization, not a timing-dependent staleness schedule."""
     import optax

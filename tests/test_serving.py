@@ -75,7 +75,7 @@ def test_served_auc_beats_chance(served):
         labels.append(batch.labels[0].data)
     auc = roc_auc(np.concatenate(labels), np.concatenate(preds))
     # this gate checks "the served model carries real learned signal", not a
-    # quality pin (BENCH_QUALITY.json owns exact AUCs): the 3-epoch synthetic
+    # quality pin of exact AUCs: the 3-epoch synthetic
     # run plateaus at ~0.79-0.80 (deterministic), so 0.75 is comfortably above
     # chance while robust to the plateau's exact landing point
     assert auc > 0.75

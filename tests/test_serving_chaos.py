@@ -3,9 +3,7 @@ all-replicas-stale degraded serving with the ``X-Staleness-Steps`` label,
 the server-side staleness header contract, and delta-channel damage repair
 through the rollover watcher.
 
-These are the FAST serving-chaos schedules (preflight step 1 runs them);
-the full zipfian soak with trainer/replica SIGKILLs is
-``benchmarks/online_bench.py`` → BENCH_ONLINE.json.
+These are the FAST serving-chaos schedules (preflight step 1 runs them).
 """
 
 import json

@@ -1,18 +1,17 @@
 """Stage lanes (parallel/stage_graph.py), the one dispatch order of the
-cached stream, and the staged driver of the fused tier (``FusedPipeline``).
+cached stream, and the one way the fused tier drives its step.
 
-- ``test_unit_*``: lane accounting, rebuild hooks and ``FusedPipeline``'s
-  staging bound in isolation — fast, no XLA dispatch; these ride the
-  preflight's step-1 subset (scripts/round_preflight.sh).
+- ``test_unit_*``: lane accounting and rebuild hooks in isolation — fast,
+  no XLA dispatch; these ride the preflight's step-1 subset
+  (scripts/round_preflight.sh).
 - the stream runs: ``pipeline_depth`` is a vestige that accepts 1 only, the
   stream carries nothing of the feed-hoisting regime PR 30 removed, and the
   rebuild hook fires once, at the migration fence, without changing a bit.
-- the fused runs: bit parity with the ``train_step`` loop, and a dispatch or
-  a stage that raises leaves no feeder thread behind.
+- the fused tier: ``FusedTrainCtx.train_step`` on the caller's thread, and
+  no staged twin beside it.
 """
 
 import threading
-import time
 
 import numpy as np
 import pytest
@@ -54,80 +53,6 @@ def test_unit_lane_overlap_stats():
     assert st["stage_overlap_frac"] == pytest.approx(2.0 / 8.0)
     serial = StageGraph(clock=lambda: now[0]).stats(wall_s=0.0)
     assert serial["stage_overlap_frac"] == 0.0
-
-
-# ------------------------------------- unit: FusedPipeline's staging bound
-
-
-def _fake_pipeline(depth, step_s=0.0, fail_at=None):
-    """A FusedPipeline over plain Python callables (no XLA): the state
-    counts dispatched batches, ``ahead`` those staged and not dispatched."""
-    from persia_tpu.parallel.fused_step import FusedPipeline
-
-    lock = threading.Lock()
-    seen = {"ahead": 0, "peak": 0, "staged": 0}
-
-    def stage(b):
-        with lock:
-            seen["staged"] += 1
-            seen["ahead"] += 1
-            seen["peak"] = max(seen["peak"], seen["ahead"])
-        return b
-
-    def step(state, b):
-        if fail_at is not None and state + 1 == fail_at:
-            raise RuntimeError("dispatch died")
-        time.sleep(step_s)  # the feeder runs ahead as far as it is let
-        with lock:
-            seen["ahead"] -= 1
-        return state + 1, (b, None)
-
-    return FusedPipeline(step, depth=depth), stage, seen
-
-
-def _feeder_threads():
-    return [t for t in threading.enumerate() if t.name == "fused-pipe-feeder"]
-
-
-@pytest.mark.parametrize("depth", [1, 2, 4])
-def test_unit_window_capacity_is_the_depth(depth):
-    """Never more than ``depth`` batches between ``stage`` and their
-    dispatch, and a slow dispatch lets the feeder get that far."""
-    pipe, stage, seen = _fake_pipeline(depth, step_s=0.01)
-    state, losses = pipe.run(0, range(12), stage=stage)
-    assert state == 12 and losses == list(range(12))  # stream order
-    assert seen["peak"] == depth
-    assert not _feeder_threads()
-
-
-def test_unit_dispatch_error_leaves_no_feeder_thread():
-    """The caller's dispatch raises at its third call with the feeder
-    parked on a full window over an endless stream: ``run`` re-raises
-    and the feeder thread is gone (ROADMAP D12's leak)."""
-    import itertools
-
-    pipe, stage, seen = _fake_pipeline(2, fail_at=3)
-    t0 = time.monotonic()
-    with pytest.raises(RuntimeError, match="dispatch died"):
-        pipe.run(0, itertools.count(), stage=stage)
-    assert time.monotonic() - t0 < 5.0
-    assert not _feeder_threads()
-    # the window held to the end: two dispatched, at most two more staged
-    assert seen["staged"] <= 4
-
-
-def test_unit_stage_error_is_the_one_raised():
-    from persia_tpu.parallel.fused_step import FusedPipeline
-
-    def stage(b):
-        if b == 2:
-            raise KeyError("stage died")
-        return b
-
-    pipe = FusedPipeline(lambda s, b: (s + 1, (b, None)), depth=2)
-    with pytest.raises(KeyError, match="stage died"):
-        pipe.run(0, range(6), stage=stage)
-    assert not _feeder_threads()
 
 
 # ------------------------------------------ cached stream: one dispatch order
@@ -240,68 +165,33 @@ def test_stream_migration_fence_fires_rebuild_hook_once(tmp_path):
     )
 
 
-# --------------------------------------------------- fused-tier pipeline
+# ----------------------------------------- fused tier: one way to its step
 
 
-def _fused_leaves(ctx):
-    import jax
-
-    return [np.asarray(x) for x in jax.tree_util.tree_leaves(ctx.state)]
-
-
-def test_fused_pipeline_bit_parity_and_drain():
-    """FusedTrainCtx.train_pipelined (depth 3, k=1): h2d staging overlaps
-    the jitted step, every staged batch is dispatched before return, and
-    every state leaf matches the sequential train_step loop bit for bit."""
+def test_fused_tier_drives_its_step_one_way(monkeypatch):
+    """The fused tier drives its step one way, ``train_step`` on the
+    caller's thread: no staged twin on the context or in the step module, and a few
+    steps start no thread."""
+    from persia_tpu.parallel import fused_step
+    from persia_tpu.parallel.fused_ctx import FusedTrainCtx
     from test_fused_ctx import _batch, _ctx
 
-    batches = [_batch(i) for i in range(16)]
-    seq = _ctx()
-    for b in batches:
-        seq.train_step(b, fetch_metrics=False)
+    for gone in ("train_pipelined", "pipeline_stats"):
+        assert not hasattr(FusedTrainCtx, gone), gone
+    for gone in ("FusedPipeline", "build_fused_pipeline", "build_fused_multi_step"):
+        assert not hasattr(fused_step, gone), gone
 
-    pipe = _ctx()
-    m = pipe.train_pipelined(batches, pipeline_depth=3, dispatch_k=1)
-    st = pipe.pipeline_stats()
-    assert st["stage_wall_s"]["feed"] > 0 and st["stage_wall_s"]["dense"] > 0
-    assert st["stage_wall_s"]["psgrad"] == 0  # no d2h lane in this tier
-    assert st["wall_s"] > 0
-    assert len(m["losses"]) == 16
-    assert not _feeder_threads()
-    for i, (x, y) in enumerate(zip(_fused_leaves(seq), _fused_leaves(pipe))):
-        np.testing.assert_array_equal(x, y, err_msg=f"leaf {i}")
+    started = []
+    real_start = threading.Thread.start
 
+    def start(self):
+        started.append(self.name)
+        real_start(self)
 
-def test_fused_pipeline_kstep_numerical_parity():
-    """k > 1 packs the dense stage via build_fused_multi_step, whose
-    parity with the single-step program is numerical, not bitwise (XLA
-    compiles the step subgraph differently in the K context — see its
-    docstring). Pin the ~1 ulp envelope so a real math divergence fails."""
-    from test_fused_ctx import _batch, _ctx
-
-    batches = [_batch(i) for i in range(16)]
-    seq = _ctx()
-    for b in batches:
-        seq.train_step(b, fetch_metrics=False)
-
-    pipe = _ctx()
-    pipe.train_pipelined(batches, pipeline_depth=4, dispatch_k=2)
-    for i, (x, y) in enumerate(zip(_fused_leaves(seq), _fused_leaves(pipe))):
-        np.testing.assert_allclose(
-            x, y, rtol=5e-3, atol=5e-5, err_msg=f"leaf {i}"
-        )
-
-
-def test_fused_pipeline_feed_error_propagates():
-    """An exception inside the feed thread (mid-conversion) must surface
-    from train_pipelined, not hang the dense loop."""
-    from test_fused_ctx import _batch, _ctx
-
-    def bad_stream():
-        yield _batch(0)
-        yield _batch(1)
-        raise RuntimeError("loader died")
-
-    pipe = _ctx()
-    with pytest.raises(RuntimeError, match="loader died"):
-        pipe.train_pipelined(bad_stream(), pipeline_depth=2)
+    ctx = _ctx()
+    monkeypatch.setattr(threading.Thread, "start", start)
+    for i in range(3):
+        m = ctx.train_step(_batch(i))
+        assert np.isfinite(m["loss"])
+    monkeypatch.undo()
+    assert started == [], started
