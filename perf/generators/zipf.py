@@ -2,10 +2,9 @@
 from a bounded power law (zipf exponent ``zipf_a``) over each table's rows,
 13 normal dense features, coin-flip labels. Everything comes from the seed.
 
-Copied in spirit from ``bench.py:_zipf_ids`` / ``_zipf_batch_maker`` (a fixed
-per-table rotation keeps each table's hot set stable and the tables
-decorrelated); here the law is bounded to the table (no wrap-around of an
-unbounded tail) and every size is the traffic file's.
+A fixed per-table rotation keeps each table's hot set stable and the tables
+decorrelated; the law is bounded to the table (no wrap-around of an
+unbounded tail), and every size is the traffic file's.
 """
 
 from __future__ import annotations

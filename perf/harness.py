@@ -245,7 +245,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         entry.build()
         # the compared steps: the same object, through the window's own call
         observed = compare.observe_program(entry, stream)
+        mark, t_warm, taken = meter.mark(), time.perf_counter(), stream.taken
         entry.warm_up(stream)
+        # the warm-up's time a step, its compiles left out: how long the traced slice runs
+        step_s = max(0.0, time.perf_counter() - t_warm - meter.since(mark)["compile_s"]) / max(
+            1, stream.taken - taken)
         if trace:
             entry.install_probes()
         mark = meter.mark()
@@ -254,7 +258,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         if trace and rehearsal is None:
             from perf import trace_reduce
 
-            tracer = trace_reduce.WindowTracer(os.path.join(root, ".perf_trace"), seconds)
+            tracer = trace_reduce.WindowTracer(os.path.join(root, ".perf_trace"), seconds, step_s)
+            print(f"trace slice {tracer.length:.3f} s, warm-up {step_s * 1e3:.2f} ms a step",
+                  file=sys.stderr)
             tracer.start()
         wait0, taken0 = stream.wait_s, stream.taken
         window = entry.run_window(stream, seconds)
@@ -291,6 +297,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         from perf import trace_reduce
 
         facts["trace"] = tracer.reduce(step_programs, chips, dump_trace)
+        print(f"trace whole steps {facts['trace']['steps']} over {facts['trace']['window_s']:.4f} s",
+              file=sys.stderr)
         dev_out["busy_s"] = facts["trace"]["busy_s"]
         dev_out["window_s"] = facts["trace"]["window_s"]
         breakdown = facts["trace"]["breakdown"]

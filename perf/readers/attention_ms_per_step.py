@@ -4,7 +4,8 @@ KERNELS = "block_diffusion_attention"  # the kernels' names start so (ops/flash_
 
 
 def kernel_seconds(facts, prefix):
-    """Busy seconds a step of the device ops whose label starts with ``prefix``; None if none ran."""
+    """Busy seconds a step of the device ops whose label starts with ``prefix``, over the trace's whole
+    steps (``perf/trace_reduce.py::reduce_events``); None if none ran or the slice held fewer than two."""
     tr = facts['trace']
     if not tr or not tr.get('steps') or not tr.get('op_s'):
         return None

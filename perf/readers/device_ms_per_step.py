@@ -1,4 +1,4 @@
-"""Device busy time of the traced window over the steps that ran in it."""
+"""Device busy time from the first whole step of the traced slice to the end of the last, over those steps."""
 
 
 def read(facts):

@@ -1,4 +1,4 @@
-"""Forward and backward FLOPs a sample x samples/s of the traced window over chips x peak bf16."""
+"""Forward and backward FLOPs a sample x samples/s of the traced whole steps over chips x peak bf16."""
 
 
 def read(facts):

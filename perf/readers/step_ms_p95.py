@@ -1,4 +1,4 @@
-"""95th percentile of the step program's start-to-start times in the device trace."""
+"""95th percentile of the step program's start-to-start times between the whole steps of the device trace."""
 
 
 def read(facts):

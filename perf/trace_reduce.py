@@ -1,7 +1,8 @@
 """From the profiler's trace to numbers: device busy time (union of the
 intervals in which an operation ran), idle share, time per operation, the
 step program's start-to-start times, and the longest idle gaps named by what
-the host was doing in them.
+the host was doing in them. All of them are read over whole steps inside the
+time the host tracer covered (``reduce_events``).
 
 The reduction works on a compact list of events
 ``(plane, line, name, start_ns, duration_ns)`` so that it can be checked on a
@@ -82,49 +83,91 @@ def _percentile(values: List[float], q: float) -> float:
     return v[lo] + (v[hi] - v[lo]) * (pos - lo)
 
 
-def _steps_in(found: List[Tuple[float, float, str]], step_programs: Dict[str, int]) -> float:
-    """Steps that ran inside the trace. The profiler cuts a program's event
-    at the trace's edges, so an event shorter than nine tenths of its
-    program's median counts for its share of that median: a K-step pack that
-    the trace's start cut in half is K/2 steps, not K."""
-    total = 0.0
-    for p, k in step_programs.items():
-        durs = sorted(d for _s, d, q in found if q == p)
-        if not durs:
-            continue
-        median = durs[len(durs) // 2]
-        total += sum(k * (1.0 if d >= 0.9 * median else d / median) for d in durs)
-    return total
+def host_interval(events: List[Event]) -> Optional[Tuple[float, float]]:
+    """(first host event's start, last host event's end): the time the host
+    tracer covered, None where it kept no event. Device ops go on after it
+    while ``stop_trace`` collects, with the host tracer off; what they do then
+    is the profiler's, not the program's."""
+    host = [(e[3], e[3] + e[4]) for e in events if not DEVICE_PLANE.match(e[0])]
+    if not host:
+        return None
+    return min(s for s, _e in host), max(e for _s, e in host)
+
+
+def leaf_ops(ops: List[Event]) -> List[Event]:
+    """The ops that hold no other op of their line: a ``while``, a
+    ``conditional`` or a ``call`` runs its body's ops inside its own event,
+    and its time is theirs. An op of no duration (a custom call's marker at
+    a fusion's start) is held by nothing."""
+    order = sorted(ops, key=lambda e: (e[3], -e[4]))
+    out = []
+    for i, e in enumerate(order):
+        end, j = e[3] + e[4], i + 1
+        while j < len(order) and order[j][3] < end:
+            if order[j][4] > 0 and order[j][3] + order[j][4] <= end:
+                break  # e holds order[j]
+            j += 1
+        else:
+            out.append(e)
+    return out
+
+
+def whole_steps(events: List[Event], plane: str, step_programs: Dict[str, int],
+                lo: float, hi: float) -> List[Tuple[float, float, int]]:
+    """(start, end, steps) of each step program's event on the plane's
+    modules line that lies entirely inside [lo, hi], by start. One that
+    starts at the first instant the plane recorded is left out: the profiler
+    cuts an event that began before its record (a first pack of 162 ms among
+    packs of 232 ms in a trace of the v5e). A K-step pack holds K steps; one
+    that is not whole holds none."""
+    mine = [e for e in events if e[0] == plane and e[1] in (OPS_LINE, MODULES_LINE)]
+    first = min(e[3] for e in mine)
+    return sorted((e[3], e[3] + e[4], k) for e in mine
+                  if e[1] == MODULES_LINE and first < e[3] and lo <= e[3] and e[3] + e[4] <= hi
+                  for p, k in step_programs.items() if e[2].startswith(p))
 
 
 def reduce_events(events: List[Event], step_programs: Dict[str, int], chips: int) -> Optional[dict]:
-    """The trace's numbers; None if no operation ran on a device."""
+    """The trace's numbers; None if no operation ran on a device inside the
+    time the host tracer covered.
+
+    Every device op is cut to that time first. Where it holds two whole steps
+    or more, everything is taken from the start of the first whole step to
+    the end of the last: ``steps`` counts those steps, and ``window_s``,
+    ``busy_s``, ``op_s`` and the gaps cover the same interval, ops of other
+    programs between the steps included. Where it holds fewer, they cover the
+    host tracer's time, and ``steps`` and every number taken a step or over
+    the steps is None."""
     planes = sorted({e[0] for e in events if DEVICE_PLANE.match(e[0])})[:chips]
     if not planes:
         return None
-    busy_s, window_s, per_op, all_gaps, step_dts, n_steps = 0.0, 0.0, {}, [], [], 0
+    covered = host_interval(events)
+    busy_s, window_s, per_op, all_gaps, step_dts, n_steps = 0.0, 0.0, {}, [], [], None
     for plane in planes:
         ops = [e for e in events if e[0] == plane and e[1] == OPS_LINE]
         if not ops:
             continue
-        t0 = min(e[3] for e in ops)
-        t1 = max(e[3] + e[4] for e in ops)
-        busy, gaps = busy_union((e[3], e[3] + e[4]) for e in ops)
+        lo, hi = covered or (min(e[3] for e in ops), max(e[3] + e[4] for e in ops))
+        steps = whole_steps(events, plane, step_programs, lo, hi)
+        n_whole = sum(k for _s, _e, k in steps)
+        if n_whole >= 2:
+            lo, hi = steps[0][0], steps[-1][1]
+        busy, gaps = busy_union((max(e[3], lo), min(e[3] + e[4], hi))
+                                for e in ops if e[3] < hi and e[3] + e[4] > lo)
         busy_s += busy * 1e-9
-        window_s += (t1 - t0) * 1e-9
-        for e in ops:
-            label = op_label(e[2])
-            per_op[label] = per_op.get(label, 0.0) + e[4] * 1e-9
+        window_s += (hi - lo) * 1e-9
+        for e in leaf_ops(ops):
+            s, t = max(e[3], lo), min(e[3] + e[4], hi)
+            if t > s:
+                label = op_label(e[2])
+                per_op[label] = per_op.get(label, 0.0) + (t - s) * 1e-9
         if plane == planes[0]:
             all_gaps = gaps
-            found = sorted((e[3], e[4], p) for e in events
-                           if e[0] == plane and e[1] == MODULES_LINE
-                           for p in step_programs if e[2].startswith(p))
-            mods = [(s0, step_programs[p]) for s0, _d, p in found]
-            n_steps = _steps_in(found, step_programs)
-            for (s0, k), (s1, _k1) in zip(mods, mods[1:]):
-                step_dts += [(s1 - s0) * 1e-6 / k] * k
-    if window_s <= 0.0:
+            if n_whole >= 2:
+                n_steps = n_whole
+                for (s0, _e0, k), (s1, _e1, _k1) in zip(steps, steps[1:]):
+                    step_dts += [(s1 - s0) * 1e-6 / k] * k
+    if busy_s <= 0.0:
         return None
     n = len(planes)
     busy_s, window_s = busy_s / n, window_s / n
@@ -140,25 +183,38 @@ def reduce_events(events: List[Event], step_programs: Dict[str, int], chips: int
     top_ops = sorted(per_op.items(), key=lambda kv: -kv[1])[:10]
     return {
         "busy_s": busy_s, "window_s": window_s,
-        "idle_share": 1.0 - busy_s / window_s,
+        "idle_share": 1.0 - busy_s / window_s if n_steps else None,
         "steps": n_steps,
         "step_ms_p95": _percentile(step_dts, 0.95) if len(step_dts) >= 20 else None,
         "device_ms_per_step": busy_s * 1e3 / n_steps if n_steps else None,
         "breakdown": {"device_ops": [[k, v / n] for k, v in top_ops], "idle_gaps": named_gaps},
-        # every op's busy seconds a chip by label, for a reader of one kernel's time
+        # every leaf op's busy seconds a chip by label, for a reader of one kernel's time
         "op_s": {k: v / n for k, v in per_op.items()},
     }
 
 
+WHOLE_STEPS = 4  # whole steps a traced slice holds at the least, at the warm-up's time a step
+MIN_SLICE_S = 4.0
+
+
+def slice_length(seconds: float, step_s: float) -> float:
+    """Seconds to trace of a window of ``seconds``: 4, or ``WHOLE_STEPS``
+    steps of ``step_s`` and one more cut at each edge where that is longer,
+    and never past the window's end."""
+    want = max(MIN_SLICE_S, (WHOLE_STEPS + 2) * step_s)
+    return max(0.5, min(want, seconds - WindowTracer.DELAY_S - 0.5))
+
+
 class WindowTracer:
     """Traces a slice of the measured window from a thread of its own: starts
-    a second into the window, stops after ``length`` seconds."""
+    a second into the window, stops after ``slice_length`` seconds, which
+    follows ``step_s``, the time a step took in the warm-up."""
 
     DELAY_S = 1.0
 
-    def __init__(self, directory: str, seconds: float, length: float = 4.0):
+    def __init__(self, directory: str, seconds: float, step_s: float = 0.0):
         self.dir = directory
-        self.length = max(0.5, min(length, seconds - self.DELAY_S - 0.5))
+        self.length = slice_length(seconds, step_s)
         self._thread = threading.Thread(target=self._run, name="perf-tracer", daemon=True)
         self._stopped = threading.Event()
         self.error: Optional[BaseException] = None
@@ -205,5 +261,5 @@ class WindowTracer:
         out = reduce_events(events, step_programs, chips)
         shutil.rmtree(self.dir, ignore_errors=True)  # little is left on disk
         if out is None:
-            raise RuntimeError("the trace holds no device operation")
+            raise RuntimeError("the trace holds no device operation inside the host tracer's time")
         return out

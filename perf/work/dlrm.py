@@ -1,8 +1,8 @@
 """Operations and bytes the DLRM training step needs, as functions of a
 configuration file and its traffic (``perf/counts.py`` hands over to the
-model's file here). Copied in spirit from
-``bench.py:_model_train_flops_per_sample`` (hard-wired there to the toy
-shape); here every size comes from the files.
+model's file here): FLOPs of the bottom and top MLPs and the dot
+interaction, bytes of the rows gathered, the sparse update and the dense
+optimizer's passes, with every size taken from the files.
 
 Conventions: a multiply-add is 2 FLOPs; backward costs twice the forward;
 gathers and the sparse update add bytes, not FLOPs (the usual model-FLOPs

@@ -24,3 +24,13 @@ REHEARSAL = {
 }
 
 CELL_OF_ENTRY = {"fused_pinned": "tb-pinned-share16", "cached_stream": "tb-cached-resident"}
+
+
+def host_tracer_over(events):
+    """A recorded trace's events with one host event that spans every device
+    event: the trace as a host tracer that covered the device's whole record
+    would have left it (the recorded fixtures keep a few host events, which
+    end before the device's record does)."""
+    dev = [e for e in events if e[0].startswith("/device:")]
+    t0, t1 = min(e[3] for e in dev), max(e[3] + e[4] for e in dev)
+    return list(events) + [("/host:CPU", "main", "host_tracer_on", t0, t1 - t0)]

@@ -71,7 +71,7 @@ def test_cell_rehearsed_through_run_cell(root, case):
                      "train_step_roofline", "device_ms_per_step"):
             assert name not in out["metrics"]
         listed = {m["name"] for m in harness.cell_metrics(harness.load_benchmark(), CELL, "per_layer")}
-        assert listed == {"gen_wait_share", "h2d_bytes_per_sample", "compiles_in_window", "device_ms_per_step",
+        assert listed >= {"gen_wait_share", "h2d_bytes_per_sample", "compiles_in_window", "device_ms_per_step",
                           "train_step_roofline", "mfu", "device_idle_share", "device_hbm_peak_gb", *NEW_METRICS}
     elif case == "half_batch":  # in a call of its own, as perf/limits_one.py runs it on the chip
         out = limits_one.fault(CELL, SEED, case, 0.3, rehearsal=presets.REHEARSAL, root=root)
@@ -332,11 +332,13 @@ def test_the_new_metrics_list_this_cell_alone(name):
 
 
 def test_the_benchmark_gained_entries_at_the_end_only():
+    """The configuration, the cell and the four metrics are in the benchmark,
+    wherever later entries put them, and the check of every cell still fits."""
     bench = harness.load_benchmark()
-    assert [c["name"] for c in bench["configs"]][-1] == CONFIG and len(bench["configs"]) == 5
-    assert [w["name"] for w in bench["workloads"]][-1] == CELL and len(bench["workloads"]) == 6
-    assert tuple(m["name"] for m in bench["per_layer"][-4:]) == NEW_METRICS
-    assert all(w["chips"] == 1 for w in bench["workloads"])
+    assert [c["name"] for c in bench["configs"]].count(CONFIG) == 1
+    (cell,) = [w for w in bench["workloads"] if w["name"] == CELL]
+    assert cell["config"] == CONFIG and cell["traffic"] == TRAFFIC and cell["chips"] == 1
+    assert set(NEW_METRICS) <= {m["name"] for m in bench["per_layer"]}
     cells = len(bench["workloads"])
     assert (2 + 14 * cells) * (bench["run_seconds"] + 60) + 2 * 90 * cells + 1200 <= 43200
 

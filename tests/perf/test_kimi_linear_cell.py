@@ -98,7 +98,7 @@ def test_cell_rehearsed_through_run_cell(root, case):
                      "latent_attention_roofline", "mfu", "train_step_roofline", "device_ms_per_step"):
             assert name not in out["metrics"]
         listed = {m["name"] for m in harness.cell_metrics(harness.load_benchmark(), CELL, "per_layer")}
-        assert listed == {"gen_wait_share", "h2d_bytes_per_sample", "compiles_in_window", "device_ms_per_step",
+        assert listed >= {"gen_wait_share", "h2d_bytes_per_sample", "compiles_in_window", "device_ms_per_step",
                           "train_step_roofline", "mfu", "device_idle_share", "device_hbm_peak_gb",
                           "kda_ms_per_step", "kda_roofline", "latent_attention_ms_per_step",
                           "latent_attention_roofline", "held_load_off_even"}

@@ -57,7 +57,6 @@ def test_the_rotation_metric_lists_this_cell_alone():
 
 
 def test_the_rotation_metric_is_the_last_entry():
-    """Appended at the end of ``per_layer``, and nothing else of the benchmark's lists moved."""
+    """In ``per_layer`` once, wherever later entries put it."""
     bench = harness.load_benchmark()
-    assert bench["per_layer"][-1]["name"] == NAME
     assert [m["name"] for m in bench["per_layer"]].count(NAME) == 1

@@ -16,7 +16,8 @@ from persia_tpu import tracing
 
 FUSED = ("fused_stage_ms_per_step", "fused_dispatch_ms_per_step", "caller_wait_share")
 NEW = FUSED + ("source_wait_ms_per_step",)
-FUSED_CELLS = ["tb-pinned-share16", "sdar-ep8-bd4-seq4k", "mellum2-ep4-pack16k"]
+FUSED_CELLS = ["tb-pinned-share16", "sdar-ep8-bd4-seq4k", "mellum2-ep4-pack16k", "kimi-linear-ep32-pack16k",
+               "joyai-flash-ep32-pack16k-mtp1"]  # every cell whose entry drives FusedTrainCtx.train_step
 
 # session_totals() after the traced slice of one tb-pinned-share16 run on the
 # v5e (my chip run, PR 38, seed 3800000101), whole
@@ -104,7 +105,7 @@ def test_the_four_are_listed_for_their_cells():
     bench = harness.load_benchmark()
     by_name = {m["name"]: m for m in bench["per_layer"]}
     for name in FUSED:
-        assert by_name[name]["workloads"] == FUSED_CELLS
+        assert set(FUSED_CELLS) <= set(by_name[name]["workloads"])
     assert by_name["source_wait_ms_per_step"]["workloads"] == ["tb-cached-resident"]
     for name in NEW:
         assert by_name[name]["moves"] == "samples_per_s_chip"
